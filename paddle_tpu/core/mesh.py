@@ -22,7 +22,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 __all__ = [
     "get_device", "set_device", "device_count", "is_compiled_with_tpu",
     "HYBRID_AXES", "make_mesh", "current_mesh", "use_mesh", "axis_size",
-    "HybridTopology",
+    "HybridTopology", "trimmed_spec",
 ]
 
 P = PartitionSpec
@@ -57,6 +57,20 @@ def set_device(device: str | jax.Device) -> jax.Device:
             device = jax.devices(device)[0]
     _current_device[0] = device
     return device
+
+
+def trimmed_spec(*entries) -> PartitionSpec:
+    """PartitionSpec with trailing Nones dropped — the way jax spells the
+    shardings a jitted program returns. jit's cache key compares specs
+    structurally: an input placed with ``P('mp', None)`` and the step
+    output carrying ``P('mp')`` are one layout but two keys, so a step fed
+    its own outputs would compile a second time. Place with this spelling
+    and the program count stays pinned (serving step programs, the meshed
+    train step)."""
+    entries = list(entries)
+    while entries and entries[-1] is None:
+        entries.pop()
+    return PartitionSpec(*entries)
 
 
 def make_mesh(
@@ -97,19 +111,21 @@ def current_mesh() -> "Mesh | jax.sharding.AbstractMesh | None":
         return _current_mesh[0]
     # fall back to the ambient jax mesh so callers that gate on an active
     # mesh (e.g. MoE sorted-dispatch fallback) see meshes activated without
-    # this library's use_mesh wrapper: the modern jax.sharding.set_mesh
-    # context first, then the legacy `with mesh:` thread resources (private
+    # this library's use_mesh wrapper: the jax.sharding.set_mesh context
+    # first, then the legacy `with mesh:` thread resources (private
     # import — the public pxla alias is deprecated; guarded so removal just
-    # disables the legacy bridge, never the set_mesh path). Both ambient
-    # getters go through core.compat, which papers over jax releases where
-    # jax.sharding.{get_abstract_mesh,get_mesh} don't exist yet.
-    from .compat import get_abstract_mesh, get_concrete_mesh
-    am = get_abstract_mesh()
-    if am is not None:
+    # disables the legacy bridge, never the set_mesh path).
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty:
         # while tracing under jit there is no concrete mesh on the trace
-        # context; callers only inspect .shape/.axis_names or feed
-        # shard_map, all of which accept the abstract mesh
-        return get_concrete_mesh() or am
+        # context (jax.sharding.get_mesh raises there); callers only
+        # inspect .shape/.axis_names or feed shard_map, all of which
+        # accept the abstract mesh
+        try:
+            cm = jax.sharding.get_mesh()
+        except ValueError:
+            return am
+        return cm if isinstance(cm, Mesh) and not cm.empty else am
     try:
         from jax._src.mesh import thread_resources
         pm = thread_resources.env.physical_mesh

@@ -15,6 +15,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ...core.mesh import trimmed_spec
 from ...nn.module import Layer
 
 __all__ = ["apply_hybrid_shardings", "fsdp_rules", "TensorParallel",
@@ -52,7 +53,9 @@ def apply_hybrid_shardings(model: Layer, mesh: Mesh, strategy=None) -> Layer:
     for k, v in params.items():
         spec = declared.get(k)
         pspec = PartitionSpec(*spec) if spec else fsdp.get(k, PartitionSpec())
-        new[k] = jax.device_put(v, NamedSharding(mesh, pspec))
+        # trimmed: else step 2 of a TrainStep, fed step 1's outputs,
+        # sees new spellings of the same shardings and compiles again
+        new[k] = jax.device_put(v, NamedSharding(mesh, trimmed_spec(*pspec)))
     model.set_state_dict(new)
     # buffers replicate
     bufs = model.buffer_dict()

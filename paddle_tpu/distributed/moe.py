@@ -672,7 +672,7 @@ class MoELayer(Layer):
         from functools import partial
 
         from jax.sharding import PartitionSpec as P
-        from ..core.compat import shard_map
+        from jax import shard_map
         from ..core import mesh as mesh_lib
 
         mesh = mesh_lib.current_mesh()

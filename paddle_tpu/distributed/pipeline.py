@@ -32,9 +32,9 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..core.compat import shard_map
+
 from ..core import mesh as mesh_lib
 from ..nn.module import Layer, functional_call
 

@@ -136,6 +136,19 @@ class TrainStep:
         grads = apply_param_grad_hooks(grads)
         return loss, aux, grads, new_buffers
 
+    def _step_args(self, batch):
+        """The compiled program's full argument tuple for ``batch`` at
+        the current host step."""
+        params = self.model.param_dict(trainable_only=True)
+        buffers = self.model.buffer_dict()
+        if self._opt_state is None:
+            self._opt_state = self.optimizer.init_state(params)
+        lr = jnp.asarray(float(self.optimizer.get_lr(self._host_step + 1)), jnp.float32)
+        key = jax.random.fold_in(self._base_key, self._host_step)
+        batch = tuple(jnp.asarray(b) if isinstance(b, (np.ndarray, np.number, int, float))
+                      else b for b in batch)
+        return (params, buffers, self._opt_state, lr, key, *batch)
+
     def __call__(self, *batch):
         # fault-injection site: advance the harness's step cursor and give
         # chaos tests a per-step hook (no-op unless a FaultPlan is armed)
@@ -149,19 +162,19 @@ class TrainStep:
             self._compiled = jax.jit(self._pure_step,
                                      donate_argnums=self._donate_argnums)
             self._hooks_version = param_grad_hooks_version()
-        params = self.model.param_dict(trainable_only=True)
-        buffers = self.model.buffer_dict()
-        if self._opt_state is None:
-            self._opt_state = self.optimizer.init_state(params)
-        lr = jnp.asarray(float(self.optimizer.get_lr(self._host_step + 1)), jnp.float32)
-        key = jax.random.fold_in(self._base_key, self._host_step)
-        batch = tuple(jnp.asarray(b) if isinstance(b, (np.ndarray, np.number, int, float))
-                      else b for b in batch)
         loss, aux, new_params, new_buffers, self._opt_state = self._compiled(
-            params, buffers, self._opt_state, lr, key, *batch)
+            *self._step_args(batch))
         self.model.set_state_dict({**new_params, **new_buffers})
         self._host_step += 1
         return (loss, aux) if self.has_aux else loss
+
+    def lower(self, *batch):
+        """jax AOT view of the program ``self(*batch)`` runs, without
+        running it: ``step.lower(x, y).compile()`` gives ``as_text()``
+        (is the Pallas kernel in the step: ``tpu_custom_call``) and
+        ``memory_analysis()`` (does the step fit the device). Nothing
+        is donated or updated."""
+        return self._compiled.lower(*self._step_args(batch))
 
     step = __call__
 

@@ -91,12 +91,8 @@ def _in_manual_trace() -> bool:
     detected from the abstract mesh's axis types, so every shard_map entry
     point (pipeline, sequence parallel, user code) is covered without
     per-call-site flags."""
-    try:
-        from ...core.compat import get_abstract_mesh
-        am = get_abstract_mesh()
-        return any("Manual" in str(t) for t in getattr(am, "axis_types", ()))
-    except Exception:
-        return False
+    am = jax.sharding.get_abstract_mesh()
+    return any("Manual" in str(t) for t in am.axis_types)
 
 
 @_functools.lru_cache(maxsize=64)
@@ -118,11 +114,16 @@ def _flash_sharded_fn(mesh, batch_axes, head_axes, is_causal, mask_mode,
     increment pattern) never collide with another shard's stream — unlike
     a plain ``offset + i`` fold, where user offsets closer together than
     the shard count would overlap a neighbour shard's stream."""
-    from ...core.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from ...ops.pallas.flash_attention import flash_attention as _fa
     spec = P(batch_axes or None, None, head_axes or None, None)
-    axes = frozenset([*batch_axes, *head_axes])
+    # manual over EVERY mesh axis, not just the batch/head ones: a Mosaic
+    # kernel cannot be auto-partitioned, so any sized axis left automatic
+    # inside the body (fsdp beside mp, say) is refused by the TPU
+    # lowering ("wrap the call in a shard_map"). q/k/v are replicated
+    # over the axes the spec does not name.
+    axes = frozenset(mesh.axis_names)
     shard_sizes = tuple(int(mesh.shape[a])
                         for a in (*batch_axes, *head_axes))
 

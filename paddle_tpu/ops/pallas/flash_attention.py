@@ -30,13 +30,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific memory spaces; absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention", "flash_attention_with_lse", "flash_attn_unpadded"]
 
@@ -259,6 +253,7 @@ def _fwd(q, k, v, scale, causal, seg=None, bias=None, dropout_p=0.0,
         compiler_params=None if _interpret() else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(*inputs)
     out = out[:, :sq].reshape(b, h, sq, d)
     lse = lse[:, :sq, 0].reshape(b, h, sq)
@@ -319,8 +314,6 @@ def _pad_segments(seg, bh, sq, sk, pq, pk):
 
 
 def _scratch(shape):
-    if _VMEM is None:  # pragma: no cover - pallas tpu module always ships
-        raise RuntimeError("pallas TPU memory spaces unavailable")
     return pltpu.VMEM(shape, jnp.float32)
 
 
@@ -567,6 +560,7 @@ def flash_block_grads(q, k, v, do, lse, delta, *, scale, causal, seg=None,
         out_shape=jax.ShapeDtypeStruct((b * h, SQ, d), q.dtype),
         scratch_shapes=[_scratch((bq, d))],
         interpret=_interpret(),
+        name="flash_attention_bwd_dq",
     )(*common_in)
     in_specs_kv = [
         pl.BlockSpec((1, bq, d), q_index_kv),
@@ -605,6 +599,7 @@ def flash_block_grads(q, k, v, do, lse, delta, *, scale, causal, seg=None,
         ],
         scratch_shapes=[_scratch((bk, d)), _scratch((bk, d))],
         interpret=_interpret(),
+        name="flash_attention_bwd_dkv",
     )(*common_in)
     dq = jnp.moveaxis(dq[:, :sq].reshape(b, h, sq, d), 1, 2)
     dk = jnp.moveaxis(dk[:, :sk].reshape(b, h, sk, d), 1, 2)

@@ -64,6 +64,7 @@ def _rms_fwd_pallas(x2, w, eps):
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x2.dtype),
         interpret=_interpret(),
+        name="fused_rms_norm",
     )(x2, w)
     return out[:n0] if pad else out
 
@@ -133,6 +134,7 @@ def _ln_fwd_pallas(x2, w, b, eps):
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x2.dtype),
         interpret=_interpret(),
+        name="fused_layer_norm",
     )(x2, w, b)
     return out[:n0] if pad else out
 

@@ -48,7 +48,7 @@ weights, and the three expert weight tensors carry gradients; the slot
 row-id map is integer (float0).
 
 Interpret mode (CPU tests): every mechanism used here — scalar-prefetch
-grid, ``pltpu.ANY`` HBM refs, ``make_async_copy`` row DMAs, semaphores —
+grid, ``pl.ANY`` HBM refs, ``make_async_copy`` row DMAs, semaphores —
 has an interpret-mode lowering, so the parity suite runs the real kernel
 logic on the CPU mesh.
 """
@@ -62,13 +62,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _interpret, _scratch
-
-try:  # TPU-specific pieces; absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
 
 __all__ = ["fused_grouped_moe", "fused_dispatch_applicable", "slot_maps"]
 
@@ -234,7 +230,7 @@ def _grid_spec(E, cpad, bc, nc, n_extra_in, out_specs, scratch):
     def _e0(e, ci, row_ref):
         return (e, 0, 0)
 
-    in_specs = [pl.BlockSpec(memory_space=pltpu.ANY)]  # x
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)]  # x
     in_specs += [pl.BlockSpec((1, bc), lambda e, ci, row_ref: (e, ci))]  # gw
     in_specs += [pl.BlockSpec((1, None, None), _e0)] * n_extra_in  # weights
     return pltpu.PrefetchScalarGridSpec(
@@ -257,7 +253,7 @@ def _fwd_call(x, row_id, gate_w, w_in, w_gate, w_out, act_name):
     tpad = (T // bc + 1) * bc  # >= T+1: row T is the sentinel trash row
     has_gate = w_gate is not None
     weights = [w_in] + ([w_gate] if has_gate else []) + [w_out]
-    in_specs = ([pl.BlockSpec(memory_space=pltpu.ANY),
+    in_specs = ([pl.BlockSpec(memory_space=pl.ANY),
                  pl.BlockSpec((1, bc), lambda e, ci, row_ref: (e, ci))]
                 + _weight_specs([w.shape for w in weights]))
     out = pl.pallas_call(
@@ -265,7 +261,7 @@ def _fwd_call(x, row_id, gate_w, w_in, w_gate, w_out, act_name):
                           has_gate=has_gate, act_name=act_name),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(E, nc), in_specs=in_specs,
-            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
                 pltpu.VMEM((bc, D), x.dtype), _scratch((bc, D)),
                 pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA]),
@@ -364,16 +360,16 @@ def _dx_call(x, row_id, gate_w, w_in, w_gate, w_out, g, act_name):
     tpad = (T // bc + 1) * bc
     has_gate = w_gate is not None
     weights = [w_in] + ([w_gate] if has_gate else []) + [w_out]
-    in_specs = ([pl.BlockSpec(memory_space=pltpu.ANY),
+    in_specs = ([pl.BlockSpec(memory_space=pl.ANY),
                  pl.BlockSpec((1, bc), lambda e, ci, row_ref: (e, ci)),
-                 pl.BlockSpec(memory_space=pltpu.ANY)]
+                 pl.BlockSpec(memory_space=pl.ANY)]
                 + _weight_specs([w.shape for w in weights]))
     dx, dgw = pl.pallas_call(
         functools.partial(_dx_kernel, T=T, tpad=tpad, bc=bc, nc=nc,
                           has_gate=has_gate, act_name=act_name),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(E, nc), in_specs=in_specs,
-            out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
+            out_specs=(pl.BlockSpec(memory_space=pl.ANY),
                        pl.BlockSpec((1, bc),
                                     lambda e, ci, row_ref: (e, ci))),
             scratch_shapes=[
@@ -465,9 +461,9 @@ def _dw_call(x, row_id, gate_w, w_in, w_gate, w_out, g, act_name):
     nc = cpad // bc
     has_gate = w_gate is not None
     weights = [w_in] + ([w_gate] if has_gate else []) + [w_out]
-    in_specs = ([pl.BlockSpec(memory_space=pltpu.ANY),
+    in_specs = ([pl.BlockSpec(memory_space=pl.ANY),
                  pl.BlockSpec((1, bc), lambda e, ci, row_ref: (e, ci)),
-                 pl.BlockSpec(memory_space=pltpu.ANY)]
+                 pl.BlockSpec(memory_space=pl.ANY)]
                 + _weight_specs([w.shape for w in weights]))
 
     def _acc_spec(d0, d1):

@@ -155,6 +155,7 @@ def _run_kernel(logits, u, random_keep2):
         scratch_shapes=[_scratch((1, E)), _scratch((1, E)),
                         _scratch((1, E))],
         interpret=_interpret(),
+        name="moe_top2_routing",
     )(logits.astype(jnp.float32), uin.reshape(blocks, _BT))
     flat = tuple(o.reshape(T) for o in outs[:7])
     return flat + (outs[7].reshape(E), outs[8].reshape(E))

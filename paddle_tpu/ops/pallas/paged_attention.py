@@ -9,13 +9,20 @@ nn/functional/attention.py materializes the gathered cache
 never does — pages stream HBM→VMEM directly by block-table lookup.
 
 TPU mapping:
-- grid (slots, kv_heads, pages), pages innermost: the page id for step
-  (s, n, j) comes from the scalar-prefetched block table in SMEM via the
-  BlockSpec index map, so the K/V page DMA is issued ahead of compute
-  (the Pallas analogue of the CUDA kernel's per-block table fetch).
-- online softmax over pages: fp32 accumulators (acc, m, l) persist in
-  VMEM scratch across the page dimension — same stored-stats scheme as
-  the flash kernel.
+- grid (slots, pages), pages innermost: the page id for step (s, j)
+  comes from the scalar-prefetched block table in SMEM via the BlockSpec
+  index map, so the K/V page DMA is issued ahead of compute (the Pallas
+  analogue of the CUDA kernel's per-block table fetch).
+- one WHOLE page per grid step, all its kv heads: the block is
+  (1, page_size, kvh, d), whose last two dims are the pool array's own —
+  the only block of this layout the TPU lowering accepts (a one-head
+  block (1, page_size, 1, d) is refused: the last two block dims must be
+  the array's or multiples of (8, 128)). The kernel walks the kv heads
+  in a static loop, reading head n's [page_size, d] rows out of the page
+  block.
+- online softmax over pages: fp32 accumulators (acc, m, l), one row set
+  per kv head, persist in VMEM scratch across the page dimension — same
+  stored-stats scheme as the flash kernel.
 - dead pages (j past the slot's last live page, seq_lens[s] // page_size)
   skip compute via pl.when AND their DMAs: the index map clamps dead j to
   the last live page id, and Mosaic elides the repeated copy.
@@ -26,8 +33,8 @@ Masking matches the XLA path exactly: position <= seq_lens[s] keeps a
 score, others take -1e30 (finite, so a fully-padded tail underflows to
 exactly 0 probability in fp32).
 
-The kernel is HEAD-LOCAL: every (slot, kv_head, page) grid step touches
-only its own head's slice, so under tensor parallelism
+The kernel is HEAD-LOCAL: heads never mix inside a grid step, so under
+tensor parallelism
 (serving/parallel.py) each shard runs this same kernel unchanged on its
 ``kvh/tp`` heads of the sharded pool — head counts are derived from the
 array shapes, and no collective ever appears inside attention.
@@ -41,15 +48,14 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific pieces; absent on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
-
-__all__ = ["paged_attention_tpu", "kernel_applicable"]
+__all__ = ["paged_attention_tpu", "kernel_applicable", "KERNEL_NAME"]
 
 _LANES = 128
+# the pallas_call's name: how a compiled program's text (and a profiler
+# trace) shows that this kernel, and not the XLA gather path, is in it
+KERNEL_NAME = "paged_attention_decode"
 
 
 def _interpret() -> bool:
@@ -67,7 +73,7 @@ def kernel_applicable(q_shape, pool_shape) -> bool:
 
 
 def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
-                   page_size, n_pages, scale, quant):
+                   page_size, n_pages, kv_heads, scale, quant):
     # quant mode rides two extra inputs (the per-row fp32 absmax scales,
     # DMA'd by the SAME block-table index map as their pages) between the
     # K/V refs and the output ref
@@ -76,7 +82,7 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
     s = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -89,36 +95,37 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j <= live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)            # [g, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # [page_size, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quant:
-            # dequantize inside the page loop: int8 codes stream from
-            # HBM, the fp32 page materializes only in VMEM
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        sc = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [g, page_size]
         pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
-        sc = jnp.where(pos <= seq_len, sc, jnp.float32(-1e30))
-        # every computed page holds >= 1 live position (j <= live), so the
-        # running max is finite and -1e30 pads underflow to exact 0
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new)                        # [g, page_size]
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)  # [g, d]
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        for n in range(kv_heads):
+            q = q_ref[0, n].astype(jnp.float32)            # [g, d]
+            k = k_ref[0, :, n, :].astype(jnp.float32)      # [page_size, d]
+            v = v_ref[0, :, n, :].astype(jnp.float32)
+            if quant:
+                # dequantize inside the page loop: int8 codes stream from
+                # HBM, the fp32 page materializes only in VMEM
+                k = k * ks_ref[0, :, n:n + 1]
+                v = v * vs_ref[0, :, n:n + 1]
+            sc = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [g, page_size]
+            sc = jnp.where(pos <= seq_len, sc, jnp.float32(-1e30))
+            # every computed page holds >= 1 live position (j <= live), so
+            # the running max is finite and -1e30 pads underflow to exact 0
+            m_prev = m_ref[n, :, 0:1]
+            l_prev = l_ref[n, :, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(sc - m_new)                        # [g, page_size]
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[n] = acc_ref[n] * alpha + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32)  # [g, d]
+            m_ref[n] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[n] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(j == n_pages - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / l_ref[:, 0:1]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_ref[:, :, 0:1]).astype(o_ref.dtype)
 
 
 def paged_attention_tpu(q, pool_k, pool_v, block_tables, seq_lens,
@@ -145,50 +152,46 @@ def paged_attention_tpu(q, pool_k, pool_v, block_tables, seq_lens,
     tables = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(seq_lens, jnp.int32)
 
-    def q_index(s_, n, j, tables_ref, lens_ref):
-        return (s_, n, 0, 0)
+    def q_index(s_, j, tables_ref, lens_ref):
+        return (s_, 0, 0, 0)
 
-    def kv_index(s_, n, j, tables_ref, lens_ref):
+    def kv_index(s_, j, tables_ref, lens_ref):
         # clamp dead page steps to the last live page: the repeated block
         # index lets Mosaic elide the DMA (flash-kernel dead-block idiom)
         jj = jnp.minimum(j, lens_ref[s_] // ps)
-        return (tables_ref[s_, jj], 0, n, 0)
+        return (tables_ref[s_, jj], 0, 0, 0)
 
-    def scale_index(s_, n, j, tables_ref, lens_ref):
-        jj = jnp.minimum(j, lens_ref[s_] // ps)
-        return (tables_ref[s_, jj], 0, n)
+    def scale_index(s_, j, tables_ref, lens_ref):
+        return kv_index(s_, j, tables_ref, lens_ref)[:3]
 
     kernel = functools.partial(_decode_kernel, page_size=ps, n_pages=M,
-                               scale=scale, quant=quant)
-    grid = (b, kvh, M)
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU support unavailable; use the XLA "
-                           "gather path (nn.functional.paged_attention_decode)")
+                               kv_heads=kvh, scale=scale, quant=quant)
     in_specs = [
-        pl.BlockSpec((1, 1, g, d), q_index),
-        pl.BlockSpec((1, ps, 1, d), kv_index),
-        pl.BlockSpec((1, ps, 1, d), kv_index),
+        pl.BlockSpec((1, kvh, g, d), q_index),
+        pl.BlockSpec((1, ps, kvh, d), kv_index),
+        pl.BlockSpec((1, ps, kvh, d), kv_index),
     ]
     operands = [tables, lens, q4, pool_k, pool_v]
     if quant:
-        in_specs += [pl.BlockSpec((1, ps, 1), scale_index),
-                     pl.BlockSpec((1, ps, 1), scale_index)]
+        in_specs += [pl.BlockSpec((1, ps, kvh), scale_index),
+                     pl.BlockSpec((1, ps, kvh), scale_index)]
         operands += [jnp.asarray(k_scale, jnp.float32),
                      jnp.asarray(v_scale, jnp.float32)]
-    scratch = [pltpu.VMEM((g, d), jnp.float32),
-               pltpu.VMEM((g, _LANES), jnp.float32),
-               pltpu.VMEM((g, _LANES), jnp.float32)]
+    scratch = [pltpu.VMEM((kvh, g, d), jnp.float32),
+               pltpu.VMEM((kvh, g, _LANES), jnp.float32),
+               pltpu.VMEM((kvh, g, _LANES), jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(b, M),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, g, d), q_index),
+            out_specs=pl.BlockSpec((1, kvh, g, d), q_index),
             scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((b, kvh, g, d), q.dtype),
         compiler_params=None if _interpret() else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=_interpret(),
+        name=KERNEL_NAME,
     )(*operands)
     return out.reshape(b, 1, h, d)

@@ -71,9 +71,23 @@ class Optimizer:
 
     def init_state(self, params: dict[str, jax.Array]) -> dict[str, Any]:
         state = {"step": jnp.zeros((), jnp.int32)}
+        sharding = getattr(next(iter(jax.tree.leaves(params)), None),
+                           "sharding", None)
+        if isinstance(sharding, jax.sharding.NamedSharding):
+            # under a mesh the counter comes back from the first step
+            # replicated over it; born anywhere else, step 2 would see
+            # a new input sharding and compile the whole step again
+            state["step"] = jax.device_put(
+                state["step"], jax.sharding.NamedSharding(
+                    sharding.mesh, jax.sharding.PartitionSpec()))
         for slot in self.slots:
+            # zeros_like, not zeros(shape): a slot is born with its
+            # parameter's sharding. Plain zeros all land on the default
+            # device until the first step reshards them — under a mesh
+            # that is every moment of the model on device 0 (7.7 GB of a
+            # 13.5 GB state on one v5e chip of four, PERF.md PR 23)
             state[slot] = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params)
+                lambda p: jnp.zeros_like(p, dtype=jnp.float32), params)
         if self.multi_precision:
             state["master"] = jax.tree.map(
                 lambda p: p.astype(jnp.float32)

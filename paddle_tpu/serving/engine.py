@@ -1191,25 +1191,41 @@ class ServingEngine:
         disagg prefill specialist warms with ``decode=False`` so the
         phase-split contract (``{"decode": 0, "mixed": 1}``, SERVING.md
         "Disaggregated serving") survives warming."""
+        if decode:
+            _, _, pools = self._decode_step(*self._warm_args("decode"))
+            self.pool.pools = pools
+        if mixed:
+            _, _, _, pools = self._mixed_step(*self._warm_args("mixed"))
+            self.pool.pools = pools
+        self._note_retraces()
+
+    def _warm_args(self, program: str) -> tuple:
+        """All-inactive arguments of one step program (every row targets
+        the reserved scratch page 0): the shapes and dtypes every real
+        dispatch has, writing and registering nothing."""
         S, M, K = self.max_slots, self.max_pages_per_slot, self._chunk
         zi = jnp.zeros((S,), jnp.int32)
         zb = jnp.zeros((S,), bool)
         ones = jnp.ones((S,), jnp.float32)
         gt = jnp.ones((S,), bool)
         tables = jnp.zeros((S, M), jnp.int32)
-        if decode:
-            _, _, pools = self._decode_step(
-                self._state, self.pool.pools, zi, tables, zi, zb,
-                ones, ones, gt, zi, zi, *self._lora_args())
-            self.pool.pools = pools
-        if mixed:
-            _, _, _, pools = self._mixed_step(
-                self._state, self.pool.pools,
+        if program == "decode":
+            return (self._state, self.pool.pools, zi, tables, zi, zb,
+                    ones, ones, gt, zi, zi, *self._lora_args())
+        return (self._state, self.pool.pools,
                 jnp.zeros((S, K), jnp.int32),
                 tables, zi, zb, zi, zb, ones, ones, gt, zi, zi,
                 *self._lora_args())
-            self.pool.pools = pools
-        self._note_retraces()
+
+    def lower_step_programs(self) -> dict:
+        """jax AOT view of the two step programs at the shapes every
+        dispatch uses, without running them:
+        ``{"decode": Lowered, "mixed": Lowered}``. ``.compile()`` gives
+        ``as_text()`` (is the paged-attention kernel in the decode step)
+        and ``memory_analysis()`` (does the step fit beside the pool).
+        ``step_program_counts()`` is not changed by it."""
+        return {"decode": self._decode_step.lower(*self._warm_args("decode")),
+                "mixed": self._mixed_step.lower(*self._warm_args("mixed"))}
 
     def pipeline_bubble_frac(self, waves: int | None = None) -> float:
         """Idle-stage fraction of the pipelined mixed step: a ring of

@@ -57,10 +57,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core import mesh as mesh_lib
-from ..core.compat import shard_map
+from ..core.mesh import trimmed_spec as _trim
 from ..distributed.fleet.mp_layers import manual_mp_region
 from ..quantization.serving import QuantizedKV
 from .errors import TPConfigError
@@ -129,20 +130,6 @@ def partition_devices(n_groups: int, pp: int, tp: int | None = None,
             f"have {len(devs)} (CPU: set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need})")
     return [devs[i * group:(i + 1) * group] for i in range(n_groups)]
-
-
-def _trim(*entries) -> P:
-    """PartitionSpec with trailing Nones dropped. jax normalizes shard_map
-    output shardings this way, and jit's cache key compares specs
-    structurally — an input placed with ``P(None, None, 'mp', None)`` vs a
-    step output carrying ``P(None, None, 'mp')`` would retrace the step on
-    its second call even though the layouts are identical. Trimming at the
-    source keeps every pool array's sharding bit-stable across calls, so
-    ``step_program_counts()`` stays pinned."""
-    entries = list(entries)
-    while entries and entries[-1] is None:
-        entries.pop()
-    return P(*entries)
 
 
 def _stack_entry(arr, j):

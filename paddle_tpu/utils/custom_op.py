@@ -49,8 +49,9 @@ import functools
 from typing import Callable
 
 import jax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P  # noqa: F401  (docstring example)
-from ..core.compat import shard_map
+
 from ..core.registry import register_contract
 from ..core import mesh as mesh_lib
 

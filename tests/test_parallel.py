@@ -261,7 +261,7 @@ def test_moe_capacity_drops_tokens():
 
 def test_collectives_inside_shard_map(sep_mesh):
     from paddle_tpu import distributed as dist
-    from paddle_tpu.core.compat import shard_map
+    from jax import shard_map
 
     x = jnp.arange(8.0)
 
@@ -311,7 +311,7 @@ def test_global_scatter_gather_roundtrip(sep_mesh):
     """Explicit EP all-to-all dispatch (parity: moe_utils.py
     global_scatter/global_gather): tokens routed to expert ranks, processed,
     and returned must equal applying each expert directly."""
-    from paddle_tpu.core.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from paddle_tpu.distributed.moe import global_gather, global_scatter
     mesh = mesh_lib.current_mesh()
@@ -418,7 +418,7 @@ def test_current_mesh_inside_jit_under_set_mesh():
         seen["quiet"] = no_mesh_active()
         return x * 2
 
-    from paddle_tpu.core.compat import set_mesh
+    from jax.sharding import set_mesh
     with set_mesh(mesh):
         out = fwd(jnp.ones((4, 4)))
     assert seen["shape"] == {"dp": 2, "mp": 4}
@@ -436,8 +436,40 @@ def test_moe_sorted_dispatch_jitted_under_set_mesh():
     x = jnp.asarray(RNG.standard_normal((8, 16)), jnp.float32)
     mesh = mesh_lib.make_mesh({"dp": 2, "mp": 4})
 
-    from paddle_tpu.core.compat import set_mesh
+    from jax.sharding import set_mesh
     fwd = jax.jit(lambda t: layer(t))
     with set_mesh(mesh):
         out = fwd(x)
     assert np.isfinite(np.asarray(out)).all()
+
+
+def test_meshed_train_step_state_is_born_sharded_and_compiles_once():
+    """Under a mesh the optimizer's slots take their parameter's sharding
+    at init (plain zeros would all sit on device 0 until the first step),
+    and what step 1 returns has the shardings step 1 was given — spelled
+    the same way, step counter included — so step 2 reuses the program."""
+    from paddle_tpu.distributed.fleet.meta_parallel import \
+        apply_hybrid_shardings
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+
+    mesh = mesh_lib.make_mesh({"fsdp": 2, "mp": 2})
+    with mesh_lib.use_mesh(mesh):
+        pt.seed(0)
+        model = LlamaForCausalLM(llama_tiny())
+        apply_hybrid_shardings(model, mesh)
+        opt = pt.optimizer.AdamW(learning_rate=1e-3, parameters=model)
+        params = model.param_dict(trainable_only=True)
+        state = opt.init_state(params)
+        for slot in opt.slots:
+            for k, p in params.items():
+                assert state[slot][k].sharding.is_equivalent_to(
+                    p.sharding, p.ndim), (slot, k)
+        key = "model.layers.0.mlp.gate_proj.weight"
+        assert len(state[opt.slots[0]][key].sharding.device_set) == 4
+        assert len(state["step"].sharding.device_set) == 4
+        step = pt.jit.TrainStep(model, opt,
+                                lambda lg, lb: model.loss(lg, lb))
+        ids = jnp.asarray(RNG.integers(0, 512, (2, 32)), jnp.int32)
+        losses = [float(step(ids, ids)) for _ in range(3)]
+    assert losses[-1] < losses[0]
+    assert step._compiled._cache_size() == 1

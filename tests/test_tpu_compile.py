@@ -1,0 +1,205 @@
+"""The main path's Pallas kernels, compiled for a TPU v5e chip that is
+described and not attached (libtpu's compiler runs on the CPU sandbox).
+
+Interpret mode checks a kernel's arithmetic and none of the TPU
+lowering's rules: the paged-attention kernel passed every interpret test
+while no serving decode step could compile for a TPU (its K/V block
+``(1, page, 1, d)`` broke the last-two-block-dims rule), and the flash
+kernel under an fsdp x mp mesh was refused as "cannot be automatically
+partitioned". These compiles, at Llama-3-8B and serving-bench widths,
+keep both repaired and guard every later PR at no chip time. Nothing
+runs, so they say nothing about results or speed — on-chip parity is
+tests/test_paged_attention_tpu.py, run by tools/run_tpu_checks.py.
+
+All of it lives in ONE file and the topology is described inside a
+fixture: only one process may load the TPU library, so no import, skipif
+or parametrize argument may touch it (each xdist worker imports every
+test file; only the worker that is handed this file loads libtpu).
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import (flash_attention, fused_norm, moe_routing,
+                                   paged_attention)
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described device can be written to the persistent
+    cache but not read back without a chip; keep it off around these."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch, no_persistent_cache):
+    """Steer every kernel module off interpret mode IN THE TEST (the
+    default backend here is the CPU; each module binds its own name)."""
+    for mod in (flash_attention, paged_attention, fused_norm, moe_routing):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _compile(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _kernels(text: str, name: str) -> int:
+    """Mosaic custom calls of the pallas_call named ``name`` (its scope in
+    op_name, bare or inside jvp()/transpose() wrappers)."""
+    scope = re.compile(rf"\b{name}\)*/pallas_call")
+    return sum(1 for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and scope.search(line))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("heads,kv_heads", [(32, 8), (16, 8)],
+                         ids=["llama3_8b", "serving_bench"])
+def test_paged_attention_compiles(mosaic, one_chip, heads, kv_heads, quant):
+    slots, page, d, pages, table = 8, 16, 128, 512, 32
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q = S((slots, 1, heads, d), BF16)
+    tables, lens = S((slots, table), I32), S((slots,), I32)
+    assert paged_attention.kernel_applicable(q.shape,
+                                             (pages, page, kv_heads, d))
+    if quant:
+        codes = S((pages, page, kv_heads, d), I8)
+        scales = S((pages, page, kv_heads), F32)
+        text = _compile(
+            lambda q, k, v, t, n, ks, vs: paged_attention.paged_attention_tpu(
+                q, k, v, t, n, k_scale=ks, v_scale=vs),
+            q, codes, codes, tables, lens, scales, scales)
+    else:
+        pool = S((pages, page, kv_heads, d), BF16)
+        text = _compile(paged_attention.paged_attention_tpu,
+                        q, pool, pool, tables, lens)
+    assert _kernels(text, paged_attention.KERNEL_NAME) == 1
+
+
+@pytest.mark.parametrize("shape", [(1, 4096, 32, 128), (4, 2048, 16, 128)],
+                         ids=["llama3_8b_seq4096", "bench_seq2048"])
+def test_flash_attention_forward_compiles(mosaic, one_chip, shape):
+    x = jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+    text = _compile(
+        lambda q, k, v: flash_attention.flash_attention(q, k, v, causal=True),
+        x, x, x)
+    assert _kernels(text, "flash_attention_fwd") == 1
+
+
+@pytest.mark.parametrize("shape", [(1, 4096, 32, 128), (4, 2048, 16, 128)],
+                         ids=["llama3_8b_seq4096", "bench_seq2048"])
+def test_flash_attention_backward_compiles(mosaic, one_chip, shape):
+    x = jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention.flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(F32))
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert _kernels(text, "flash_attention_fwd") == 1
+    assert _kernels(text, "flash_attention_bwd_dq") == 1
+    assert _kernels(text, "flash_attention_bwd_dkv") == 1
+
+
+@pytest.mark.parametrize("shape", [(1, 4096, 4096), (8, 1, 4096),
+                                   (8, 64, 4096)],
+                         ids=["prefill_rows", "decode_rows", "chunk_rows"])
+def test_fused_rms_norm_compiles(mosaic, one_chip, shape):
+    x = jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct(shape[-1:], BF16, sharding=one_chip)
+
+    def fwd_and_grads(x, w):
+        y, vjp = jax.vjp(fused_norm.fused_rms_norm, x, w)
+        return y, vjp(y)
+
+    assert _kernels(_compile(fwd_and_grads, x, w), "fused_rms_norm") == 1
+
+
+def test_moe_top2_routing_compiles(mosaic, one_chip):
+    tokens, experts, capacity = 8192, 16, 1280     # the qwen2_moe bench
+    assert moe_routing.fused_routing_applicable(tokens, experts)
+    logits = jax.ShapeDtypeStruct((tokens, experts), F32, sharding=one_chip)
+    text = _compile(
+        lambda lg: moe_routing.fused_top2_routing(lg, None, capacity,
+                                                  False, 0.01), logits)
+    assert _kernels(text, "moe_top2_routing") == 1
+
+
+def test_flash_attention_under_fsdp_mp_mesh_compiles(mosaic, topo,
+                                                     monkeypatch):
+    """The SPMD rule under a real four-chip mesh whose sized axes are not
+    all batch/head axes: the kernel's shard_map must be manual over every
+    axis (a Mosaic kernel cannot be auto-partitioned over fsdp)."""
+    import paddle_tpu as pt
+    from paddle_tpu.nn.functional import attention
+
+    monkeypatch.setattr(attention, "_flash_backend_ok", lambda: True)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("fsdp", "mp"))
+    x = jax.ShapeDtypeStruct((1, 2048, 32, 128), BF16,
+                             sharding=NamedSharding(mesh,
+                                                    P(None, None, "mp")))
+
+    def loss(q, k, v):
+        out = attention.scaled_dot_product_attention(q, k, v, is_causal=True)
+        return jnp.sum(out.astype(F32))
+
+    with pt.use_mesh(mesh):
+        text = _compile(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    assert _kernels(text, "flash_attention_fwd") >= 1
+    assert _kernels(text, "flash_attention_bwd_dkv") == 1
+
+
+def test_paged_attention_in_tp_shard_map_compiles(mosaic, topo):
+    """The decode kernel as ServingEngine(tp=4) runs it: inside a
+    shard_map over mp, on kvh/4 heads of a pool sharded on its head dim."""
+    mesh = Mesh(np.array(topo.devices), ("mp",))
+    slots, page, d, pages, table = 8, 16, 128, 512, 32
+
+    def S(shape, dt, spec):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, spec))
+
+    heads_spec = P(None, None, "mp", None)
+    q = S((slots, 1, 32, d), BF16, heads_spec)
+    pool = S((pages, page, 8, d), BF16, heads_spec)
+    tables, lens = S((slots, table), I32, P()), S((slots,), I32, P())
+    step = jax.shard_map(
+        paged_attention.paged_attention_tpu, mesh=mesh,
+        in_specs=(heads_spec, heads_spec, heads_spec, P(), P()),
+        out_specs=heads_spec, check_vma=False)
+    text = _compile(step, q, pool, pool, tables, lens)
+    assert _kernels(text, paged_attention.KERNEL_NAME) == 1

@@ -9,7 +9,7 @@ expert weights.
 
 Protocol (PROFILE_qwen2_moe.md): fwd+bwd per iteration — `jax.vjp`
 inside a `lax.scan` with a carry data-dependency, cotangent = output —
-with DELTA timing, t(scan 40) minus t(scan 10) over 30, so relay sync
+with DELTA timing, t(scan 40) minus t(scan 10) over 30, so per-sync
 and program-entry fixed costs cancel. Like the other component
 profiles, the functions close over weights (activation-gradient
 backward, no weight-gradient GEMMs); the fused path's dW kernels are

@@ -1,20 +1,28 @@
-"""Run the TPU-only test files on the real chip (PADDLE_TPU_REAL_CHIP=1
-disables the conftest's CPU-mesh pinning). The normal suite runs these
-files too but they skip without a TPU backend.
+"""Run the TPU-only test files on the chip: on-chip parity of the Pallas
+kernels that the CPU suite can only interpret or compile.
 
-Usage: python tools/run_tpu_checks.py
+This launcher never touches JAX itself (a chip belongs to one process):
+it starts ONE pytest child with PADDLE_TPU_REAL_CHIP=1, which makes
+tests/conftest.py leave the platform to JAX and turn the persistent
+compile cache on. The normal suite collects these files too; there every
+test in them skips from a fixture.
+
+Usage (on a machine with a TPU): python tools/run_tpu_checks.py
 """
 
 import os
 import subprocess
 import sys
 
-TPU_ONLY = ["tests/test_flash_dropout_tpu.py"]
+TPU_ONLY = ["tests/test_flash_dropout_tpu.py",
+            "tests/test_paged_attention_tpu.py"]
 
 if __name__ == "__main__":
     env = dict(os.environ)
     env["PADDLE_TPU_REAL_CHIP"] = "1"
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    rc = subprocess.run([sys.executable, "-m", "pytest", "-q", *TPU_ONLY],
+    # -s: the paged-attention file prints its host-clock reading
+    rc = subprocess.run([sys.executable, "-m", "pytest", "-q", "-s",
+                         "-p", "no:cacheprovider", *TPU_ONLY],
                         cwd=repo, env=env).returncode
     sys.exit(rc)
