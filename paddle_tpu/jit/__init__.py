@@ -25,6 +25,7 @@ import numpy as np
 
 from ..core import rng as _rng
 from ..nn.module import Layer, functional_call
+from ..observability.trace import PROFILE_TRACER
 from ..optimizer.optimizer import Optimizer
 
 __all__ = ["to_static", "TrainStep", "EvalStep", "PipelineTrainStep",
@@ -87,6 +88,12 @@ class TrainStep:
     The compiled program: forward + vjp backward + clip + optimizer + buffer
     writeback, all fused by XLA; params/opt-state buffers are donated so
     updates are in-place in HBM.
+
+    While a JAX profiler session is on, each call records a ``step`` span
+    with its phases ``step_args``, ``dispatch`` and ``writeback`` on the
+    ``train`` track of ``observability.PROFILE_TRACER`` (``train.step``
+    ... in the profiler's trace), and a ``compile`` instant with the
+    ``compiles`` counter when the call compiled (OBSERVABILITY.md).
     """
 
     def __init__(self, model: Layer, optimizer: Optimizer, loss_fn: Callable,
@@ -103,14 +110,16 @@ class TrainStep:
         def pure_step(params, buffers, opt_state, lr, key, *batch):
             loss, aux, grads, new_buffers = self._loss_and_grads(
                 params, buffers, key, *batch)
-            new_params, new_opt_state = self.optimizer.update(
-                params, grads, opt_state, lr=lr)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt_state = self.optimizer.update(
+                    params, grads, opt_state, lr=lr)
             return loss, aux, new_params, new_buffers, new_opt_state
 
         donate_argnums = (0, 1, 2) if donate else ()
         self._pure_step = pure_step
         self._donate_argnums = donate_argnums
         self._compiled = jax.jit(pure_step, donate_argnums=donate_argnums)
+        self._programs_seen = 0
         from ..autograd import param_grad_hooks_version
         self._hooks_version = param_grad_hooks_version()
 
@@ -122,7 +131,8 @@ class TrainStep:
         def loss_of(p):
             out, new_buffers = functional_call(
                 self.model, {**buffers, **p}, *inputs, rngs=key, training=True)
-            loss_out = self.loss_fn(out, *labels)
+            with jax.named_scope("loss"):
+                loss_out = self.loss_fn(out, *labels)
             if self.has_aux:
                 loss, aux = loss_out
                 return loss, (aux, new_buffers)
@@ -158,14 +168,29 @@ class TrainStep:
         # grad hooks are baked into the traced program; retrace when the
         # registry changed after compilation
         from ..autograd import param_grad_hooks_version
-        if param_grad_hooks_version() != self._hooks_version:
-            self._compiled = jax.jit(self._pure_step,
-                                     donate_argnums=self._donate_argnums)
-            self._hooks_version = param_grad_hooks_version()
-        loss, aux, new_params, new_buffers, self._opt_state = self._compiled(
-            *self._step_args(batch))
-        self.model.set_state_dict({**new_params, **new_buffers})
-        self._host_step += 1
+        tr = PROFILE_TRACER
+        with tr.span("step", track="train", step=self._host_step):
+            if param_grad_hooks_version() != self._hooks_version:
+                self._compiled = jax.jit(self._pure_step,
+                                         donate_argnums=self._donate_argnums)
+                self._programs_seen = 0
+                self._hooks_version = param_grad_hooks_version()
+            with tr.span("step_args", track="train"):
+                args = self._step_args(batch)
+            with tr.span("dispatch", track="train"):
+                (loss, aux, new_params, new_buffers,
+                 self._opt_state) = self._compiled(*args)
+                del args    # the donated buffers are gone
+            n = int(self._compiled._cache_size())
+            if n != self._programs_seen:
+                # the training twin of the engine's retrace sentinel: the
+                # count is followed always, reported while the tracer is on
+                self._programs_seen = n
+                tr.instant("compile", track="train", programs=n)
+                tr.bump("compiles", track="train")
+            with tr.span("writeback", track="train"):
+                self.model.set_state_dict({**new_params, **new_buffers})
+            self._host_step += 1
         return (loss, aux) if self.has_aux else loss
 
     def lower(self, *batch):

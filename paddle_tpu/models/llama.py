@@ -264,7 +264,8 @@ class LlamaAttention(Layer):
             else:
                 pk = pk.at[page, off].set(k.astype(pk.dtype))
                 pv = pv.at[page, off].set(v.astype(pv.dtype))
-            out = F.paged_attention_decode(q, pk, pv, tables, seq_lens)
+            with jax.named_scope("core"):
+                out = F.paged_attention_decode(q, pk, pv, tables, seq_lens)
             return _out_proj(out.reshape(b, s, h * d)), (pk, pv)
         # sequence parallelism: when tracing inside a manual-sep shard_map
         # region (the pipelined train step), x is the LOCAL seq shard —
@@ -284,7 +285,9 @@ class LlamaAttention(Layer):
             k = apply_rotary_pos_emb(k, cos, sin, pos)
             # GQA k/v stay at kvh heads — ring_attention_manual repeats
             # per-step so rotating buffers are h/kvh smaller
-            out = _sp.ring_attention_manual(q, k, v, axis=sep, causal=True)
+            with jax.named_scope("core"):
+                out = _sp.ring_attention_manual(q, k, v, axis=sep,
+                                                causal=True)
             return _out_proj(out.reshape(b, s, h * d))
         static_zero = not isinstance(position_offset, jax.Array) and position_offset == 0
         if static_zero:
@@ -307,8 +310,9 @@ class LlamaAttention(Layer):
             # (parity: incubate masked_multihead_attention decode kernel)
             from ..incubate.nn import functional as FF
             seq_lens = jnp.broadcast_to(jnp.asarray(position_offset), (b,))
-            out, ck, cv = FF.masked_multihead_attention(
-                q, k, v, kv_cache[0], kv_cache[1], seq_lens)
+            with jax.named_scope("core"):
+                out, ck, cv = FF.masked_multihead_attention(
+                    q, k, v, kv_cache[0], kv_cache[1], seq_lens)
             return _out_proj(out.reshape(b, s, h * d)), (ck, cv)
         if kv_cache is not None:
             ck, cv = kv_cache
@@ -348,8 +352,9 @@ class LlamaAttention(Layer):
                 # boundaries. QuantizedKV caches pass through undequantized;
                 # the core dequantizes them itself.
                 seq_lens = jnp.broadcast_to(jnp.asarray(position_offset), (b,))
-                out = F.cached_prefill_attention(q, new_cache[0],
-                                                 new_cache[1], seq_lens)
+                with jax.named_scope("core"):
+                    out = F.cached_prefill_attention(q, new_cache[0],
+                                                     new_cache[1], seq_lens)
                 return _out_proj(out.reshape(b, s, h * d)), new_cache
         if kvh != h:  # GQA: repeat kv heads
             rep = h // kvh
@@ -365,9 +370,10 @@ class LlamaAttention(Layer):
             causal = False
         else:
             causal = True
-        out = F.scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
-                                             is_causal=causal,
-                                             training=self.training)
+        with jax.named_scope("core"):
+            out = F.scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
+                                                 is_causal=causal,
+                                                 training=self.training)
         out = _out_proj(out.reshape(b, s, h * d))
         return (out, new_cache) if kv_cache is not None else out
 
@@ -408,17 +414,26 @@ class LlamaDecoderLayer(Layer):
 
     def forward(self, x, cos, sin, attn_mask=None, kv_cache=None, position_offset=0,
                 paged=None, lora=None):
+        # the scopes name the layer part in every operation's metadata
+        # (``op_name``), with no layer index: a trace's reduction merges
+        # the same part of every layer
         res = x
-        h = self.input_layernorm(x)
-        if kv_cache is not None:
-            h, new_cache = self.self_attn(h, cos, sin, attn_mask, kv_cache,
-                                          position_offset, paged, lora)
-        else:
-            h = self.self_attn(h, cos, sin, attn_mask, lora=lora)
-            new_cache = None
+        with jax.named_scope("norm"):
+            h = self.input_layernorm(x)
+        with jax.named_scope("attn"):
+            if kv_cache is not None:
+                h, new_cache = self.self_attn(h, cos, sin, attn_mask,
+                                              kv_cache, position_offset,
+                                              paged, lora)
+            else:
+                h = self.self_attn(h, cos, sin, attn_mask, lora=lora)
+                new_cache = None
         x = res + h
         res = x
-        x = res + self.mlp(self.post_attention_layernorm(x), lora=lora)
+        with jax.named_scope("norm"):
+            h = self.post_attention_layernorm(x)
+        with jax.named_scope("mlp"):
+            x = res + self.mlp(h, lora=lora)
         return (x, new_cache) if kv_cache is not None else x
 
 
@@ -452,7 +467,8 @@ class LlamaModel(Layer):
 
     def forward(self, input_ids, attn_mask=None, kv_caches=None, position_offset=0,
                 paged=None, lora=None):
-        x = self._embed(input_ids)
+        with jax.named_scope("embed"):
+            x = self._embed(input_ids)
         cos, sin = self.rope_cos, self.rope_sin
         new_caches = []
         for i, layer in enumerate(self.layers):
@@ -473,7 +489,8 @@ class LlamaModel(Layer):
                         lambda x, layer=layer: layer(x, cos, sin, attn_mask))(x)
             else:
                 x = layer(x, cos, sin, attn_mask)
-        x = self.norm(x)
+        with jax.named_scope("norm"):
+            x = self.norm(x)
         return (x, new_caches) if kv_caches is not None else x
 
 
@@ -496,11 +513,12 @@ class LlamaForCausalLM(Layer):
             hidden, new_caches = out
         else:
             hidden = out
-        if self.config.tie_word_embeddings:
-            logits = hidden @ self.model.embed_tokens.weight.T
-        else:
-            logits = self.lm_head(hidden)
-        logits = _mp_gather_logits(logits, self.config.mp_axis)
+        with jax.named_scope("lm_head"):
+            if self.config.tie_word_embeddings:
+                logits = hidden @ self.model.embed_tokens.weight.T
+            else:
+                logits = self.lm_head(hidden)
+            logits = _mp_gather_logits(logits, self.config.mp_axis)
         return (logits, new_caches) if kv_caches is not None else logits
 
     def pp_parts(self):
@@ -516,17 +534,21 @@ class LlamaForCausalLM(Layer):
         cfg = self.config
 
         def embed(state, input_ids):
-            return _vocab_parallel_embed(
-                state["model.embed_tokens.weight"], input_ids, cfg.mp_axis)
+            with jax.named_scope("embed"):
+                return _vocab_parallel_embed(
+                    state["model.embed_tokens.weight"], input_ids,
+                    cfg.mp_axis)
 
         def head(state, hidden):
-            hidden = F.rms_norm(hidden, state["model.norm.weight"],
-                                cfg.rms_norm_eps)
-            if cfg.tie_word_embeddings:
-                logits = hidden @ state["model.embed_tokens.weight"].T
-            else:
-                logits = F.linear(hidden, state["lm_head.weight"])
-            return _mp_gather_logits(logits, cfg.mp_axis)
+            with jax.named_scope("norm"):
+                hidden = F.rms_norm(hidden, state["model.norm.weight"],
+                                    cfg.rms_norm_eps)
+            with jax.named_scope("lm_head"):
+                if cfg.tie_word_embeddings:
+                    logits = hidden @ state["model.embed_tokens.weight"].T
+                else:
+                    logits = F.linear(hidden, state["lm_head.weight"])
+                return _mp_gather_logits(logits, cfg.mp_axis)
 
         return {
             "layer_prefix": "model.layers.",

@@ -22,6 +22,8 @@ from typing import Iterable
 
 import jax
 
+from ..observability.trace import profiler_annotation
+
 __all__ = ["Profiler", "RecordEvent", "ProfilerTarget", "ProfilerState",
            "make_scheduler", "export_chrome_tracing", "benchmark", "Timer",
            "load_profiler_result"]
@@ -56,11 +58,12 @@ class RecordEvent:
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._ta = jax.profiler.TraceAnnotation(name)
         self._ns = jax.named_scope(name)
         self._t0 = None
 
     def __enter__(self):
+        # the same way into the profiler's trace as observability.Tracer
+        self._ta = profiler_annotation(self.name)
         self._ta.__enter__()
         self._ns.__enter__()
         self._t0 = time.perf_counter()

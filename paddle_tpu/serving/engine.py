@@ -63,6 +63,7 @@ of it deterministically chaos-testable.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 from dataclasses import dataclass
 
@@ -71,7 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..distributed import fault as _fault
-from ..observability.trace import NULL_TRACER
+from ..observability.trace import PROFILE_TRACER
 from .errors import (AdmissionShedError, EngineDrainingError, QueueFullError,
                      RequestTooLargeError, SchedulerStalledError)
 from .kv_cache import KVCachePool
@@ -324,20 +325,22 @@ class ServingEngine:
         self.metrics.set_lora(self.adapters is not None)
         # observability (OBSERVABILITY.md): the tracer is shared with
         # the scheduler (request-lifecycle spans) and the pool
-        # (eviction/COW/quarantine events); construct it on the same
-        # clock as the metrics so spans and percentiles line up. The
-        # flight recorder subscribes to the event stream and is
-        # auto-dumped at terminal conditions (stall, nonfinite, drain,
-        # watchdog timeout).
-        self.tracer = tracer if tracer is not None else NULL_TRACER
+        # (eviction/COW/quarantine events). Without one the engine holds
+        # the process-wide PROFILE_TRACER, which records exactly while a
+        # JAX profiler session is on; a tracer passed here is on from its
+        # construction (build it on the metrics' clock so spans and
+        # percentiles line up). The flight recorder subscribes to a
+        # passed tracer's event stream and is auto-dumped at terminal
+        # conditions (stall, nonfinite, drain, watchdog timeout).
+        self.tracer = tracer if tracer is not None else PROFILE_TRACER
         self.scheduler.tracer = self.tracer
         self.pool.tracer = self.tracer
         self.flight_recorder = flight_recorder
-        if flight_recorder is not None:
-            self.tracer.add_sink(flight_recorder.record)
-        # retrace detection (tracing on): last-seen compiled-program
-        # count PER STEP SHAPE ("decode", "mixed") — every shape is a
-        # first-class program with its own sentinel
+        if flight_recorder is not None and tracer is not None:
+            tracer.add_sink(flight_recorder.record)
+        # retrace detection: last-seen compiled-program count PER STEP
+        # SHAPE ("decode", "mixed") — every shape is a first-class
+        # program with its own sentinel
         self._step_traces: dict[str, int] = {}
         self._wd_hooked: set[int] = set()
         self.step_timeout_s = step_timeout_s
@@ -410,62 +413,63 @@ class ServingEngine:
         HERE with AdapterUnavailableError, and the stream is bitwise
         identical to ``generate()`` with that adapter merged into the
         base weights."""
-        if self._draining:
-            raise EngineDrainingError(
-                "engine is draining (preempted or shut down); retry on "
-                "another replica (serving.fleet.FleetRouter skips "
-                "draining replicas at placement time)")
-        prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
-        if not prompt:
-            raise ValueError("prompt must be non-empty")
-        adapter_hex = ""
-        if adapter is not None and adapter != "":
-            from .lora import AdapterUnavailableError
-            if self.adapters is None:
-                raise AdapterUnavailableError(
-                    "engine was built without lora=...; pass "
-                    "lora=True (or an AdapterPool) to serve adapters")
-            adapter_hex = self.adapters.resolve(adapter).hex()
-        try:
-            self.admission_check(len(prompt), max_new_tokens)
-        except RequestTooLargeError:
-            self.metrics.on_reject("too_large")
-            raise
-        rid = rid if rid is not None else f"req-{next(self._rid_counter)}"
-        old = self._requests.get(rid)
-        if old is not None:
-            if not old.done:
-                raise ValueError(f"duplicate request id {rid!r}")
-            # a FINISHED record is safe to supersede — the disagg
-            # router legitimately re-admits a rid after its prefill
-            # phase finished here with reason "handoff" (fallback
-            # recompute landing back on the warm prefill replica)
-            del self._requests[rid]
-        # chaos site: an injected admission fault models a crash in the
-        # overload-control path itself — typed, keyed by rid
-        _fault.trip("serving.admission", step=self._steps, path=rid)
-        self._check_overload_gates(len(prompt), max_new_tokens,
-                                   int(tenant), int(priority), deadline_s)
-        req = Request(rid=rid, prompt=prompt, max_new_tokens=max_new_tokens,
-                      sampling=sampling or SamplingParams(),
-                      eos_token_id=eos_token_id,
-                      deadline_s=deadline_s,
-                      max_queue_wait_s=max_queue_wait_s,
-                      arrival_t=self.metrics.now(),
-                      tenant=int(tenant), priority=int(priority),
-                      handoff=bool(prefill_only), adapter=adapter_hex)
-        try:
-            self.scheduler.add(req, self.pool)
-        except QueueFullError:
-            self.metrics.on_reject("queue_full")
-            raise
-        except RequestTooLargeError:
-            self.metrics.on_reject("too_large")
-            raise
-        self._requests[rid] = req
-        self.metrics.on_arrival(rid, tenant=int(tenant),
-                                priority=int(priority))
-        return rid
+        with self.tracer.span("add_request", step=self._steps):
+            if self._draining:
+                raise EngineDrainingError(
+                    "engine is draining (preempted or shut down); retry on "
+                    "another replica (serving.fleet.FleetRouter skips "
+                    "draining replicas at placement time)")
+            prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
+            if not prompt:
+                raise ValueError("prompt must be non-empty")
+            adapter_hex = ""
+            if adapter is not None and adapter != "":
+                from .lora import AdapterUnavailableError
+                if self.adapters is None:
+                    raise AdapterUnavailableError(
+                        "engine was built without lora=...; pass "
+                        "lora=True (or an AdapterPool) to serve adapters")
+                adapter_hex = self.adapters.resolve(adapter).hex()
+            try:
+                self.admission_check(len(prompt), max_new_tokens)
+            except RequestTooLargeError:
+                self.metrics.on_reject("too_large")
+                raise
+            rid = rid if rid is not None else f"req-{next(self._rid_counter)}"
+            old = self._requests.get(rid)
+            if old is not None:
+                if not old.done:
+                    raise ValueError(f"duplicate request id {rid!r}")
+                # a FINISHED record is safe to supersede — the disagg
+                # router legitimately re-admits a rid after its prefill
+                # phase finished here with reason "handoff" (fallback
+                # recompute landing back on the warm prefill replica)
+                del self._requests[rid]
+            # chaos site: an injected admission fault models a crash in the
+            # overload-control path itself — typed, keyed by rid
+            _fault.trip("serving.admission", step=self._steps, path=rid)
+            self._check_overload_gates(len(prompt), max_new_tokens,
+                                       int(tenant), int(priority), deadline_s)
+            req = Request(rid=rid, prompt=prompt, max_new_tokens=max_new_tokens,
+                          sampling=sampling or SamplingParams(),
+                          eos_token_id=eos_token_id,
+                          deadline_s=deadline_s,
+                          max_queue_wait_s=max_queue_wait_s,
+                          arrival_t=self.metrics.now(),
+                          tenant=int(tenant), priority=int(priority),
+                          handoff=bool(prefill_only), adapter=adapter_hex)
+            try:
+                self.scheduler.add(req, self.pool)
+            except QueueFullError:
+                self.metrics.on_reject("queue_full")
+                raise
+            except RequestTooLargeError:
+                self.metrics.on_reject("too_large")
+                raise
+            self._requests[rid] = req
+            self.metrics.on_arrival(rid, tenant=int(tenant),
+                                    priority=int(priority))
+            return rid
 
     def register_adapter(self, adapter) -> str:
         """Register a :class:`serving.lora.LoRAAdapter` with this
@@ -605,6 +609,12 @@ class ServingEngine:
         letting ``run_to_completion`` busy-loop."""
         if not self.scheduler.has_work():
             return []
+        # one parent span per call, its children covering the whole of
+        # the step (OBSERVABILITY.md "Engine step phases")
+        with self.tracer.span("step", step=self._steps) as span:
+            return self._step(span)
+
+    def _step(self, span) -> list[dict]:
         # key this step's serving.alloc fault draws by the ENGINE step
         # (not the process-global training cursor) so probabilistic
         # storms vary over the engine's lifetime deterministically
@@ -615,14 +625,77 @@ class ServingEngine:
         events: list[dict] = []
         with tr.span("deadline_sweep", queue=self.scheduler.queue_depth):
             self._expire_deadlines(events)
-        if self._draining:
-            self._flush_waiting(events)
-        elif self._brownout is not None:
+            if self._draining:
+                self._flush_waiting(events)
+        if not self._draining and self._brownout is not None:
             # one hysteresis tick of the brownout ladder BEFORE the
             # budget is computed, so a fresh transition takes effect
             # this very step (level-3 queue sheds land in `events`)
             with tr.span("brownout", level=self._brownout_level):
                 self._update_brownout(events)
+        with tr.span("admission"):
+            budget = self._admit(events)
+        # drafts are proposed BEFORE the page guarantee so
+        # ensure_decode_pages covers the speculative writes too
+        if self._spec is not None and self.scheduler.running:
+            if self._brownout_level >= 2:
+                # brownout level 2: suspend speculation — the drafter is
+                # pure host code, so "off" is just empty draft lanes;
+                # the mixed program's row count never moves
+                for req in self.scheduler.running.values():
+                    req.draft_tokens = []
+            else:
+                self._propose_drafts()
+        with tr.span("ensure_pages"):
+            preempted = self.scheduler.ensure_decode_pages(self.pool)
+            for victim in preempted:
+                self.metrics.on_preemption()
+                if victim.state == FINISHED:  # hit the max_preemptions cap
+                    self.metrics.on_outcome("preempted_limit")
+                    self.metrics.on_finish(victim.rid, "preempted_limit")
+                    self._trace_finish(victim, "preempted_limit")
+                    events.append({"rid": victim.rid, "token": None,
+                                   "finished": True,
+                                   "finish_reason": "preempted_limit"})
+        chunk_tokens, program = 0, "none"
+        slots = len(self.scheduler.running)
+        if slots:
+            chunk_tokens, program = self._run_batch(events, max(budget, 0))
+        if span is not None:
+            span.args.update(program=program, slots=slots,
+                             chunk_tokens=chunk_tokens)
+        with tr.span("bookkeeping"):
+            self._note_retraces()
+            self.metrics.on_prefix_counters(self.pool.counters)
+            if self.pool.host_tier is not None:
+                self.metrics.on_tier_stats(self.pool.host_tier.stats())
+            if self.adapters is not None:
+                self.metrics.on_lora_stats(self.adapters.stats())
+            self.metrics.on_step(self.scheduler.queue_depth,
+                                 self.pool.utilization())
+            self._steps += 1
+            self._check_progress(events, chunk_tokens)
+        if (self.snapshot_store is not None
+                and self._steps % self.snapshot_interval == 0):
+            # capture at the step boundary: pages hold exactly
+            # context_len tokens, positions beyond are zeros (rejected
+            # rows were zeroed in-program) or unreached stale content —
+            # the tail page is sanitized host-side at export
+            with tr.span("snapshot_capture"):
+                self._capture_snapshots()
+        # feed the step-duration EMA (metrics clock) the retry_after_s /
+        # infeasibility estimators divide by; a zero-dt step (virtual
+        # clock not advanced) contributes nothing
+        dt = self.metrics.now() - t_step0
+        if dt > 0.0:
+            self._step_dt_ema = (dt if self._step_dt_ema is None
+                                 else 0.8 * self._step_dt_ema + 0.2 * dt)
+        return events
+
+    def _admit(self, events: list[dict]) -> int:
+        """The step's admissions; returns the prefill/chunk token budget
+        that is left for the dispatch."""
+        tr = self.tracer
         # the verify/chunk rows and any admission prefill share ONE
         # per-step token-work bound: the (brownout-effective) prefill
         # budget, minus the (spec_k - 1) verify rows each decoding slot
@@ -639,9 +712,8 @@ class ServingEngine:
             # chunk.
             first = True
             while True:
-                with tr.span("admission"):
-                    batch = self.scheduler.admit(self.pool, limit=1,
-                                                 budget=budget, first=first)
+                batch = self.scheduler.admit(self.pool, limit=1,
+                                             budget=budget, first=first)
                 if not batch:
                     break
                 req = batch[0]
@@ -672,90 +744,46 @@ class ServingEngine:
         for req in self.scheduler.admit_failures:
             self._finish_abnormal(req, "adapter_unavailable", events)
         self.scheduler.admit_failures.clear()
-        # drafts are proposed BEFORE the page guarantee so
-        # ensure_decode_pages covers the speculative writes too
-        if self._spec is not None and self.scheduler.running:
-            if self._brownout_level >= 2:
-                # brownout level 2: suspend speculation — the drafter is
-                # pure host code, so "off" is just empty draft lanes;
-                # the mixed program's row count never moves
-                for req in self.scheduler.running.values():
-                    req.draft_tokens = []
-            else:
-                self._propose_drafts()
-        with tr.span("ensure_pages"):
-            preempted = self.scheduler.ensure_decode_pages(self.pool)
-        for victim in preempted:
-            self.metrics.on_preemption()
-            if victim.state == FINISHED:  # hit the max_preemptions cap
-                self.metrics.on_outcome("preempted_limit")
-                self.metrics.on_finish(victim.rid, "preempted_limit")
-                self._trace_finish(victim, "preempted_limit")
-                events.append({"rid": victim.rid, "token": None,
-                               "finished": True,
-                               "finish_reason": "preempted_limit"})
-        chunk_tokens = 0
-        if self.scheduler.running:
-            chunk_tokens = self._run_batch(events, max(budget, 0))
-        self.metrics.on_prefix_counters(self.pool.counters)
-        if self.pool.host_tier is not None:
-            self.metrics.on_tier_stats(self.pool.host_tier.stats())
-        if self.adapters is not None:
-            self.metrics.on_lora_stats(self.adapters.stats())
-        self.metrics.on_step(self.scheduler.queue_depth,
-                             self.pool.utilization())
-        self._steps += 1
-        if (self.snapshot_store is not None
-                and self._steps % self.snapshot_interval == 0):
-            # capture at the step boundary: pages hold exactly
-            # context_len tokens, positions beyond are zeros (rejected
-            # rows were zeroed in-program) or unreached stale content —
-            # the tail page is sanitized host-side at export
-            with tr.span("snapshot_capture"):
-                self._capture_snapshots()
+        return budget
+
+    def _check_progress(self, events: list[dict], chunk_tokens: int) -> None:
+        """The stall backstop at the end of a step."""
         if events or chunk_tokens or not self.scheduler.waiting:
             # chunk tokens are progress even before any emission: a
             # long prompt legitimately spends several steps mid-prefill
             self._idle_steps = 0
-        else:
-            # work is pending but nothing was admitted, decoded or
-            # finished (the preempt-self livelock / un-admittable-head
-            # shape). A deterministic livelock repeats this identically
-            # every step — after _STALL_PATIENCE of them, surface the
-            # evidence instead of letting run_to_completion busy-loop.
-            self._idle_steps += 1
-            if self._idle_steps >= _STALL_PATIENCE:
-                head = self.scheduler.waiting[0]
-                snapshot = {
-                    "step": self._steps,
-                    "idle_steps": self._idle_steps,
-                    "queue_depth": self.scheduler.queue_depth,
-                    "head_rid": head.rid,
-                    "head_needs_pages": self.pool.pages_for(
-                        max(head.recompute_len, 1)),
-                    "free_pages": self.pool.num_free,
-                    "capacity": self.pool.capacity,
-                    "running": len(self.scheduler.running),
-                }
-                tr.instant("stall", idle_steps=self._idle_steps,
-                           queue=self.scheduler.queue_depth)
-                dump = self._dump_flight("scheduler_stalled", snapshot)
-                if dump is not None:
-                    snapshot["flight_recorder"] = dump
-                raise SchedulerStalledError(
-                    f"{snapshot['idle_steps']} zero-progress steps with "
-                    f"{snapshot['queue_depth']} request(s) pending: head "
-                    f"{head.rid!r} needs {snapshot['head_needs_pages']} "
-                    f"pages, {snapshot['free_pages']} free "
-                    f"(capacity {snapshot['capacity']})", snapshot)
-        # feed the step-duration EMA (metrics clock) the retry_after_s /
-        # infeasibility estimators divide by; a zero-dt step (virtual
-        # clock not advanced) contributes nothing
-        dt = self.metrics.now() - t_step0
-        if dt > 0.0:
-            self._step_dt_ema = (dt if self._step_dt_ema is None
-                                 else 0.8 * self._step_dt_ema + 0.2 * dt)
-        return events
+            return
+        # work is pending but nothing was admitted, decoded or finished
+        # (the preempt-self livelock / un-admittable-head shape). A
+        # deterministic livelock repeats this identically every step —
+        # after _STALL_PATIENCE of them, surface the evidence instead of
+        # letting run_to_completion busy-loop.
+        self._idle_steps += 1
+        if self._idle_steps < _STALL_PATIENCE:
+            return
+        head = self.scheduler.waiting[0]
+        snapshot = {
+            "step": self._steps,
+            "idle_steps": self._idle_steps,
+            "queue_depth": self.scheduler.queue_depth,
+            "head_rid": head.rid,
+            "head_needs_pages": self.pool.pages_for(
+                max(head.recompute_len, 1)),
+            "free_pages": self.pool.num_free,
+            "capacity": self.pool.capacity,
+            "running": len(self.scheduler.running),
+        }
+        self.tracer.instant("stall", idle_steps=self._idle_steps,
+                            queue=self.scheduler.queue_depth)
+        dump = self._dump_flight("scheduler_stalled", snapshot)
+        if dump is not None:
+            snapshot["flight_recorder"] = dump
+        raise SchedulerStalledError(
+            f"{snapshot['idle_steps']} zero-progress steps with "
+            f"{snapshot['queue_depth']} request(s) pending: head "
+            f"{head.rid!r} needs {snapshot['head_needs_pages']} "
+            f"pages, {snapshot['free_pages']} free "
+            f"(capacity {snapshot['capacity']})", snapshot)
 
     def stream(self):
         """Drive the engine to completion, yielding events as they are
@@ -1503,7 +1531,8 @@ class ServingEngine:
             # per-slot poison sentinel: rows are independent, so a
             # non-finite row indicts exactly one slot
             ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)), axis=-1)
-            nt = _sample_rows(last, temps, top_ps, greedy, seeds, counts)
+            with jax.named_scope("sampler"):
+                nt = _sample_rows(last, temps, top_ps, greedy, seeds, counts)
             return nt, ok, pools
 
         if self._tp is None:
@@ -1525,8 +1554,9 @@ class ServingEngine:
                 last = logits[:, -1]
                 ok = jnp.all(jnp.isfinite(last.astype(jnp.float32)),
                              axis=-1)
-                nt = _sample_rows(last, temps, top_ps, greedy, seeds,
-                                  counts)
+                with jax.named_scope("sampler"):
+                    nt = _sample_rows(last, temps, top_ps, greedy, seeds,
+                                      counts)
                 return nt, ok, pools
             return tp.compile_step(decode_step_pp, self._state,
                                    self.pool.pools, n_lanes=9, n_lead=2)
@@ -1590,12 +1620,13 @@ class ServingEngine:
             # sample all S*K rows with the row's own token index —
             # logits stay in the model dtype so argmax/softmax see the
             # same bits the 1-token decode step would
-            samp = _sample_rows(
-                logits.reshape(S * K, V),
-                jnp.repeat(temps, K), jnp.repeat(top_ps, K),
-                jnp.repeat(greedy, K), jnp.repeat(seeds, K),
-                (counts[:, None] + rows[None, :]).reshape(-1),
-            ).reshape(S, K)
+            with jax.named_scope("sampler"):
+                samp = _sample_rows(
+                    logits.reshape(S * K, V),
+                    jnp.repeat(temps, K), jnp.repeat(top_ps, K),
+                    jnp.repeat(greedy, K), jnp.repeat(seeds, K),
+                    (counts[:, None] + rows[None, :]).reshape(-1),
+                ).reshape(S, K)
             # accepted count m: a forced (chunk) slot accepts all its
             # rows — its tokens are the prompt, not guesses; a verify
             # slot accepts the longest prefix of live draft rows
@@ -1611,14 +1642,16 @@ class ServingEngine:
             # partials), so the zeroing can never hit foreign KV. A
             # forced slot has no rejected rows (rows > n_live - 1 are
             # not live), so chunk writes always survive.
-            pos = seq_lens[:, None] + rows[None, :]           # [S, K]
-            rej = live & (rows[None, :] > m[:, None]) & active[:, None]
-            page = jnp.take_along_axis(tables, pos // ps, axis=1)
-            page = jnp.where(rej, page, 0)
-            off = jnp.where(rej, pos % ps, 0)
-            pools = [(KVCachePool._pos_zero(pk, page, off),
-                      KVCachePool._pos_zero(pv, page, off))
-                     for pk, pv in pools]
+            with jax.named_scope("rollback"):
+                pos = seq_lens[:, None] + rows[None, :]       # [S, K]
+                rej = (live & (rows[None, :] > m[:, None])
+                       & active[:, None])
+                page = jnp.take_along_axis(tables, pos // ps, axis=1)
+                page = jnp.where(rej, page, 0)
+                off = jnp.where(rej, pos % ps, 0)
+                pools = [(KVCachePool._pos_zero(pk, page, off),
+                          KVCachePool._pos_zero(pv, page, off))
+                         for pk, pv in pools]
             return samp, m, ok, pools
 
         if self._tp is None:
@@ -1646,24 +1679,27 @@ class ServingEngine:
                     live[..., None],
                     jnp.isfinite(logits.astype(jnp.float32)),
                     True), axis=(1, 2))
-                samp = _sample_rows(
-                    logits.reshape(S * K, V),
-                    jnp.repeat(temps, K), jnp.repeat(top_ps, K),
-                    jnp.repeat(greedy, K), jnp.repeat(seeds, K),
-                    (counts[:, None] + rows[None, :]).reshape(-1),
-                ).reshape(S, K)
+                with jax.named_scope("sampler"):
+                    samp = _sample_rows(
+                        logits.reshape(S * K, V),
+                        jnp.repeat(temps, K), jnp.repeat(top_ps, K),
+                        jnp.repeat(greedy, K), jnp.repeat(seeds, K),
+                        (counts[:, None] + rows[None, :]).reshape(-1),
+                    ).reshape(S, K)
                 match = (toks[:, 1:] == samp[:, :-1]) & live[:, 1:]
                 m = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1),
                             axis=1)
                 m = jnp.where(forced, n_live - 1, m)
-                pos = seq_lens[:, None] + rows[None, :]
-                rej = live & (rows[None, :] > m[:, None]) & active[:, None]
-                page = jnp.take_along_axis(tables, pos // ps, axis=1)
-                page = jnp.where(rej, page, 0)
-                off = jnp.where(rej, pos % ps, 0)
-                pools = [(KVCachePool._pos_zero(pk, page, off, True),
-                          KVCachePool._pos_zero(pv, page, off, True))
-                         for pk, pv in pools]
+                with jax.named_scope("rollback"):
+                    pos = seq_lens[:, None] + rows[None, :]
+                    rej = (live & (rows[None, :] > m[:, None])
+                           & active[:, None])
+                    page = jnp.take_along_axis(tables, pos // ps, axis=1)
+                    page = jnp.where(rej, page, 0)
+                    off = jnp.where(rej, pos % ps, 0)
+                    pools = [(KVCachePool._pos_zero(pk, page, off, True),
+                              KVCachePool._pos_zero(pv, page, off, True))
+                             for pk, pv in pools]
                 return samp, m, ok, pools
             return tp.compile_step(mixed_step_pp, self._state,
                                    self.pool.pools, n_lanes=11, n_lead=3)
@@ -1739,6 +1775,7 @@ class ServingEngine:
                 counts[slot] = len(req.tokens) - (n - 1)
                 atable = np.zeros((S,), np.int32)
                 atable[slot] = req.adapter_slot
+                tr.bump("rows_sampled", S * K)
                 samp, _, ok, new_pools = self._mixed_step(
                     self._state, self.pool.pools, jnp.asarray(toks),
                     jnp.asarray(tables), jnp.asarray(seq_lens),
@@ -1754,7 +1791,6 @@ class ServingEngine:
                 if not bool(ok[slot]):
                     ok_all = False
                     break  # NaN cache rows only propagate — stop early
-        self._note_retraces()
         if self.kv_quant:
             # quantize-at-scatter observability: error-stat gauge (per-
             # element error <= scale/2) + one trace instant per prefill
@@ -1839,38 +1875,40 @@ class ServingEngine:
             budget -= n
         return plan
 
-    def _run_batch(self, events: list[dict], budget: int) -> int:
+    def _run_batch(self, events: list[dict], budget: int) -> tuple[int, str]:
         """Dispatch this step's model work: plan prefill chunks under
         the remaining token budget, then route — any chunk or draft
         rows go through the ONE mixed program (decode slots ride along
         in the same dispatch); a pure-decode step keeps the cheap
         ``[max_slots]`` decode program. Returns the number of prefill
         chunk tokens dispatched (progress accounting for the stall
-        detector)."""
-        if _fault.active_plan() is not None:
-            for req in list(self.scheduler.running.values()):
-                if req.prefilling:
-                    continue  # serving.prefill trips at chunk dispatch
-                try:
-                    _fault.trip("serving.decode", step=self._steps,
-                                path=req.rid,
-                                poison=lambda r=req: self._poison_pages(r))
-                except _fault.FaultInjected:
-                    self._finish_abnormal(req, "injected", events)
-            if not self.scheduler.running:
-                return 0
-        plan = self._plan_chunks(budget)
-        has_drafts = self._spec is not None and any(
-            req.draft_tokens for req in self.scheduler.running.values())
+        detector) and which program ran (``decode`` | ``mixed`` |
+        ``none``)."""
+        with self.tracer.span("plan"):
+            if _fault.active_plan() is not None:
+                for req in list(self.scheduler.running.values()):
+                    if req.prefilling:
+                        continue  # serving.prefill trips at chunk dispatch
+                    try:
+                        _fault.trip(
+                            "serving.decode", step=self._steps, path=req.rid,
+                            poison=lambda r=req: self._poison_pages(r))
+                    except _fault.FaultInjected:
+                        self._finish_abnormal(req, "injected", events)
+                if not self.scheduler.running:
+                    return 0, "none"
+            plan = self._plan_chunks(budget)
+            has_drafts = self._spec is not None and any(
+                req.draft_tokens for req in self.scheduler.running.values())
         if plan or has_drafts:
-            return self._run_mixed(events, plan)
+            return self._run_mixed(events, plan), "mixed"
         self._run_decode(events)
-        return 0
+        return 0, "decode"
 
     def _run_decode(self, events: list[dict]) -> None:
         tr = self.tracer
         S, M = self.max_slots, self.max_pages_per_slot
-        with tr.span("decode_dispatch", slots=len(self.scheduler.running)):
+        with tr.span("build_inputs"):
             tok = np.zeros((S,), np.int32)
             tables = np.zeros((S, M), np.int32)
             seq_lens = np.zeros((S,), np.int32)
@@ -1890,15 +1928,30 @@ class ServingEngine:
                 greedy[slot] = not req.sampling.do_sample
                 seeds[slot] = req.sampling.seed
                 counts[slot] = len(req.tokens)
+            lanes = (jnp.asarray(tok), jnp.asarray(tables),
+                     jnp.asarray(seq_lens), jnp.asarray(active),
+                     jnp.asarray(temps), jnp.asarray(top_ps),
+                     jnp.asarray(greedy), jnp.asarray(seeds),
+                     jnp.asarray(counts),
+                     *self._lora_args(self._slot_atable()))
+            if tr.enabled:
+                # the sampler's and the attention core's padding, counted
+                # where the work is handed over: rows the program samples
+                # against tokens emitted, block-table entries the kernel's
+                # grid walks against the pages that hold the keys each
+                # decoding slot attends (its context and the new token)
+                ps = self.page_size
+                tr.bump("decode_steps")
+                tr.bump("rows_sampled", S)
+                tr.bump("table_entries_dispatched",
+                        M * len(self.scheduler.running))
+                tr.bump("table_entries_live",
+                        sum(req.context_len // ps + 1
+                            for req in self.scheduler.running.values()))
+        with tr.span("decode_dispatch", slots=len(self.scheduler.running)):
             nt, ok, new_pools = self._decode_step(
-                self._state, self.pool.pools, jnp.asarray(tok),
-                jnp.asarray(tables), jnp.asarray(seq_lens),
-                jnp.asarray(active), jnp.asarray(temps),
-                jnp.asarray(top_ps), jnp.asarray(greedy),
-                jnp.asarray(seeds), jnp.asarray(counts),
-                *self._lora_args(self._slot_atable()))
+                self._state, self.pool.pools, *lanes)
             self.pool.pools = new_pools
-        self._note_retraces()
         nt, ok = self._watched_sync(nt, ok)
         with tr.span("sample_emit"):
             for slot, req in list(self.scheduler.running.items()):
@@ -1929,6 +1982,32 @@ class ServingEngine:
         # ran in between) — keep only slots that still owe chunks
         plan = {slot: n for slot, n in plan.items()
                 if slot in sched.running and sched.running[slot].prefilling}
+        with tr.span("build_inputs"):
+            lanes, n_drafted, chunk_tokens = self._mixed_lanes(plan)
+            self.metrics.on_mixed_step(
+                chunk_tokens, len(n_drafted), len(plan),
+                sum(1 for r in sched.running.values() if r.prefilling))
+            if tr.enabled:
+                # rows the program samples, against tokens emitted
+                tr.bump("mixed_steps")
+                tr.bump("rows_sampled", S * K)
+        with tr.span("mixed_dispatch", slots=len(plan) + len(n_drafted),
+                     chunk_tokens=chunk_tokens,
+                     drafts=sum(n_drafted.values())):
+            samp, acc, ok, new_pools = self._mixed_step(
+                self._state, self.pool.pools, *lanes)
+            self.pool.pools = new_pools
+        samp, acc, ok = self._watched_sync(samp, acc, ok)
+        with tr.span("sample_emit"):
+            self._mixed_emit(events, plan, n_drafted, samp, acc, ok)
+        return chunk_tokens
+
+    def _mixed_lanes(self, plan: dict[int, int]):
+        """The mixed program's host-built lanes for this step, as device
+        arrays; the drafts per verify slot and the chunk tokens planned."""
+        tr = self.tracer
+        sched = self.scheduler
+        S, M, K = self.max_slots, self.max_pages_per_slot, self._chunk
         toks = np.zeros((S, K), np.int32)
         tables = np.zeros((S, M), np.int32)
         seq_lens = np.zeros((S,), np.int32)
@@ -1976,30 +2055,20 @@ class ServingEngine:
                 n_live[slot] = 1 + len(d)
                 n_drafted[slot] = len(d)
                 counts[slot] = len(req.tokens)
-        self.metrics.on_mixed_step(
-            chunk_tokens, len(n_drafted), len(plan),
-            sum(1 for r in sched.running.values() if r.prefilling))
-        if tr.enabled and self._pp_waves > 1:
-            # stage waves run inside the one compiled mixed program, so
-            # the per-wave instants are logical markers emitted at
-            # dispatch (the device timeline can't be split from host)
-            for w in range(self._pp_waves):
-                tr.instant("pp_wave", wave=w, width=K // self._pp_waves,
-                           pp=self.pp)
-        with tr.span("mixed_dispatch", slots=len(plan) + len(n_drafted),
-                     chunk_tokens=chunk_tokens,
-                     drafts=sum(n_drafted.values())):
-            samp, acc, ok, new_pools = self._mixed_step(
-                self._state, self.pool.pools, jnp.asarray(toks),
-                jnp.asarray(tables), jnp.asarray(seq_lens),
-                jnp.asarray(active), jnp.asarray(n_live),
-                jnp.asarray(forced), jnp.asarray(temps),
-                jnp.asarray(top_ps), jnp.asarray(greedy),
-                jnp.asarray(seeds), jnp.asarray(counts),
-                *self._lora_args(self._slot_atable()))
-            self.pool.pools = new_pools
-        self._note_retraces()
-        samp, acc, ok = self._watched_sync(samp, acc, ok)
+        lanes = (jnp.asarray(toks), jnp.asarray(tables),
+                 jnp.asarray(seq_lens), jnp.asarray(active),
+                 jnp.asarray(n_live), jnp.asarray(forced),
+                 jnp.asarray(temps), jnp.asarray(top_ps),
+                 jnp.asarray(greedy), jnp.asarray(seeds),
+                 jnp.asarray(counts), *self._lora_args(self._slot_atable()))
+        return lanes, n_drafted, chunk_tokens
+
+    def _mixed_emit(self, events, plan, n_drafted, samp, acc, ok) -> None:
+        """What the mixed program returned, taken slot by slot: chunk
+        slots advance (and emit on their final chunk), verify/decode
+        slots emit their accepted prefix plus the bonus sample."""
+        tr = self.tracer
+        sched = self.scheduler
         # serving.prefill fault trips for the chunk slots, mirroring
         # the legacy prefill site: after the write, before the ok check
         # and before any registration — an injected chunk failure can
@@ -2016,93 +2085,91 @@ class ServingEngine:
                 except _fault.FaultInjected:
                     req.context_len += plan.pop(slot)
                     self._finish_abnormal(req, "injected", events)
-        with tr.span("sample_emit"):
-            participants = ([s for s in plan if s in sched.running]
-                            + [s for s in n_drafted if s in sched.running])
-            for slot in participants:
-                req = sched.running.get(slot)
-                if req is None:
+        participants = ([s for s in plan if s in sched.running]
+                        + [s for s in n_drafted if s in sched.running])
+        for slot in participants:
+            req = sched.running.get(slot)
+            if req is None:
+                continue
+            if slot in plan:
+                n = plan[slot]
+                req.context_len += n
+                if not ok[slot]:
+                    # the prompt chunk produced non-finite logits —
+                    # quarantine before it ever joins the decode
+                    # batch (and before any registration)
+                    self._finish_abnormal(req, "nonfinite", events)
                     continue
-                if slot in plan:
-                    n = plan[slot]
-                    req.context_len += n
-                    if not ok[slot]:
-                        # the prompt chunk produced non-finite logits —
-                        # quarantine before it ever joins the decode
-                        # batch (and before any registration)
-                        self._finish_abnormal(req, "nonfinite", events)
-                        continue
-                    if req.prefilling:
-                        continue  # mid-prompt: more chunks owed
-                    # FINAL chunk: commit the prompt's full pages to
-                    # the prefix index now (first-writer-wins in the
-                    # pool; the trailing partial page keeps filling
-                    # during decode and is registered at release)
-                    seq = req.prompt + req.tokens[:-1]
-                    self.pool.register_prefix(seq[:req.prefill_target],
-                                              req.pages,
-                                              include_partial=False,
-                                              namespace=req.adapter_ns)
-                    if self.kv_quant:
-                        qs = self._qscale_max(req.pages)
-                        self.metrics.on_kv_quant_scale(qs)
-                        tr.instant("kv_quantize", track=req.rid,
-                                   scale_max=round(qs, 6), suffix=n)
-                    if req.tokens:
-                        continue  # recompute after preemption: cache
-                                  # rebuilt, the stored last token is
-                                  # the next decode input
-                    if req.handoff:
-                        # disaggregated serving: publish the finished
-                        # KV instead of emitting — the decode replica
-                        # recomputes this same final row and emits the
-                        # bitwise-identical first token itself
-                        self._handoff_finish(req, events)
-                        continue
-                    self._emit(req, int(samp[slot, n - 1]), events)
-                else:
-                    n_draft = n_drafted[slot]
-                    req.draft_tokens = []
-                    C0 = req.context_len
-                    if not ok[slot]:
-                        # poison quarantine, same as the decode path:
-                        # only this slot finishes (rows are per-slot
-                        # independent)
-                        req.context_len += 1
-                        self._finish_abnormal(req, "nonfinite", events)
-                        continue
-                    m = int(acc[slot])
-                    if n_draft:
-                        self.metrics.on_spec_verify(n_draft, m)
-                        self._drafter.observe(req, n_draft, m)
-                    # the emitted tokens are the engine's own samples
-                    # for rows 0..m — exactly what m + 1 sequential
-                    # decode steps would have drawn. A stop (eos)
-                    # inside the accept window truncates the emission.
-                    emit: list[int] = []
-                    for j in range(m + 1):
-                        t = int(samp[slot, j])
-                        emit.append(t)
-                        if ((req.eos_token_id is not None
-                             and t == req.eos_token_id)
-                                or len(req.tokens) + len(emit)
-                                >= req.max_new_tokens):
-                            break
-                    req.context_len = C0 + len(emit)
-                    if len(emit) < m + 1:
-                        # accepted-but-unused tail beyond an in-window
-                        # stop: rewind those positions to zero before
-                        # the pages can be released/registered (token-
-                        # granular masked-garbage-is-zero)
-                        self.pool.rewind(req.pages, C0 + len(emit),
-                                         C0 + m + 1)
-                    if tr.enabled and n_draft > m:
-                        tr.instant("rollback", track=req.rid,
-                                   rejected=n_draft - m, accepted=m)
-                        tr.bump("spec_rejected_tokens", n_draft - m)
-                    for t in emit:
-                        self._emit(req, t, events)
-        return chunk_tokens
+                if req.prefilling:
+                    continue  # mid-prompt: more chunks owed
+                # FINAL chunk: commit the prompt's full pages to
+                # the prefix index now (first-writer-wins in the
+                # pool; the trailing partial page keeps filling
+                # during decode and is registered at release)
+                seq = req.prompt + req.tokens[:-1]
+                self.pool.register_prefix(seq[:req.prefill_target],
+                                          req.pages,
+                                          include_partial=False,
+                                          namespace=req.adapter_ns)
+                if self.kv_quant:
+                    qs = self._qscale_max(req.pages)
+                    self.metrics.on_kv_quant_scale(qs)
+                    tr.instant("kv_quantize", track=req.rid,
+                               scale_max=round(qs, 6), suffix=n)
+                if req.tokens:
+                    continue  # recompute after preemption: cache
+                              # rebuilt, the stored last token is
+                              # the next decode input
+                if req.handoff:
+                    # disaggregated serving: publish the finished
+                    # KV instead of emitting — the decode replica
+                    # recomputes this same final row and emits the
+                    # bitwise-identical first token itself
+                    self._handoff_finish(req, events)
+                    continue
+                self._emit(req, int(samp[slot, n - 1]), events)
+            else:
+                n_draft = n_drafted[slot]
+                req.draft_tokens = []
+                C0 = req.context_len
+                if not ok[slot]:
+                    # poison quarantine, same as the decode path:
+                    # only this slot finishes (rows are per-slot
+                    # independent)
+                    req.context_len += 1
+                    self._finish_abnormal(req, "nonfinite", events)
+                    continue
+                m = int(acc[slot])
+                if n_draft:
+                    self.metrics.on_spec_verify(n_draft, m)
+                    self._drafter.observe(req, n_draft, m)
+                # the emitted tokens are the engine's own samples
+                # for rows 0..m — exactly what m + 1 sequential
+                # decode steps would have drawn. A stop (eos)
+                # inside the accept window truncates the emission.
+                emit: list[int] = []
+                for j in range(m + 1):
+                    t = int(samp[slot, j])
+                    emit.append(t)
+                    if ((req.eos_token_id is not None
+                         and t == req.eos_token_id)
+                            or len(req.tokens) + len(emit)
+                            >= req.max_new_tokens):
+                        break
+                req.context_len = C0 + len(emit)
+                if len(emit) < m + 1:
+                    # accepted-but-unused tail beyond an in-window
+                    # stop: rewind those positions to zero before
+                    # the pages can be released/registered (token-
+                    # granular masked-garbage-is-zero)
+                    self.pool.rewind(req.pages, C0 + len(emit),
+                                     C0 + m + 1)
+                if tr.enabled and n_draft > m:
+                    tr.instant("rollback", track=req.rid,
+                               rejected=n_draft - m, accepted=m)
+                    tr.bump("spec_rejected_tokens", n_draft - m)
+                for t in emit:
+                    self._emit(req, t, events)
 
     def _note_retraces(self) -> None:
         """Retrace sentinel, one per step shape ("decode", "mixed"):
@@ -2111,16 +2178,17 @@ class ServingEngine:
         compile bar + counter bump in the trace right where the
         regression happened."""
         tr = self.tracer
-        if not tr.enabled:
-            return
         for name, n in self.step_program_counts().items():
             seen = self._step_traces.get(name, 0)
             if n != seen:
+                # the count is followed whether or not the tracer is on,
+                # so that a tracer that comes on later (a profiler
+                # session) reports only what compiled while it was on
+                self._step_traces[name] = n
                 tr.instant("compile", program=name, programs=n)
                 tr.bump("compiles", n - seen)
                 if seen:
                     tr.bump("decode_retraces", n - seen)
-                self._step_traces[name] = n
 
     def _watched_sync(self, *arrays):
         """The engine's blocking device sync (np.asarray) under the
@@ -2141,9 +2209,15 @@ class ServingEngine:
                     "meta": {k: repr(v) for k, v in task_rec.meta.items()}})
 
             wd.post_mortem_hooks.append(_post_mortem)
-        with wd.task("serving.step", timeout=self.step_timeout_s,
-                     step=self._steps, slots=len(self.scheduler.running)):
-            with self.tracer.span("device_sync"):
+        tr = self.tracer
+        with contextlib.ExitStack() as watched:
+            # arming the watchdog (it starts a monitor thread) is host
+            # work of its own, not part of the wait for the device
+            with tr.span("watchdog_arm"):
+                watched.enter_context(wd.task(
+                    "serving.step", timeout=self.step_timeout_s,
+                    step=self._steps, slots=len(self.scheduler.running)))
+            with tr.span("device_sync"):
                 return tuple(np.asarray(a) for a in arrays)
 
     # ------------------------------------------------------------------

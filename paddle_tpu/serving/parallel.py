@@ -355,7 +355,11 @@ class TPContext:
         sliced = {rel: state[self._stack_key(rel)]
                   for rel in self._pp_rel_keys}
 
+        @jax.named_scope("pp_tick")
         def tick(carry, t):
+            # one scope for every tick: the waves run inside this scanned
+            # body, so the device trace has their operations under
+            # ``pp_tick/...`` (the host cannot see a wave start or end)
             h, pk, pv, outs = carry
             w = t - r
             valid = (w >= 0) & (w < W)

@@ -24,8 +24,9 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu.distributed import fault
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
-from paddle_tpu.observability import (NULL_TRACER, FlightRecorder,
-                                      MetricsServer, Tracer, parse_prometheus)
+from paddle_tpu.observability import (NULL_TRACER, PROFILE_TRACER,
+                                      FlightRecorder, MetricsServer, Tracer,
+                                      parse_prometheus)
 from paddle_tpu.observability.recorder import SCHEMA
 from paddle_tpu.serving import (SchedulerStalledError, ServingEngine,
                                 ServingMetrics)
@@ -224,8 +225,10 @@ class TestFlightRecorder:
 
 class TestEngineTracing:
     def test_tracing_off_by_default(self, model):
+        # the default tracer follows the profiler: off outside a session
         eng = ServingEngine(model, num_pages=16, page_size=4, max_slots=2)
-        assert eng.tracer is NULL_TRACER
+        assert eng.tracer is PROFILE_TRACER
+        assert eng.scheduler.tracer is eng.pool.tracer is PROFILE_TRACER
         assert eng.stats()["tracing"] is False
 
     def test_tracing_on_bitwise_parity_single_decode_program(self, model):
