@@ -27,7 +27,9 @@ attends causally up to itself. Speculative verify is the degenerate
 mixed step whose new tokens are draft rows instead of prompt rows: row
 j is ACCEPTED iff it equals the row j-1 sample (Leviathan), rejected
 rows are zeroed in-program, and a ``forced`` slot accepts all its rows
-by construction. ``step_program_counts()`` reports both step shapes
+by construction. Only the rows whose sample can be emitted are sampled:
+``spec_k`` a slot, a chunk's last live row or a verify slot's first
+``spec_k``. ``step_program_counts()`` reports both step shapes
 and each stays pinned at 1 (O(1) programs, not O(prompt-length) or
 O(accept-pattern)).
 
@@ -1587,19 +1589,23 @@ class ServingEngine:
           the drafter's guesses; draft row j is ACCEPTED iff it equals
           the row j-1 sample, the Leviathan accept/reject rule.
 
-        Every row is sampled under the engine's standard contract —
-        ``fold_in(PRNGKey(seed), counts + j)``, the exact key the
-        sequential engine would use for that token index — so emitted
-        streams are bitwise identical to sequential decode (greedy and
-        sampled) no matter how prompts chunk or what the drafter
-        proposed. Rejected live rows are zeroed IN-PROGRAM (fixed-shape
-        scatter: rejected rows target their real (page, offset),
-        everything else targets scratch (0, 0)) so no garbage outlives
-        the step — chunk sizes and accept patterns are data, never
-        shapes."""
+        Only the rows whose sample can be emitted are sampled
+        (``_mixed_tail``): ``spec_k`` rows a slot, a chunk slot's last
+        live row in column 0 and a verify slot's rows ``0..spec_k-1``,
+        so ``samp`` is ``[max_slots, spec_k]``. Each keeps the engine's
+        standard contract — ``fold_in(PRNGKey(seed), counts + j)`` with
+        the row's own index j, the exact key the sequential engine
+        would use for that token index — so emitted streams are bitwise
+        identical to sequential decode (greedy and sampled) no matter
+        how prompts chunk or what the drafter proposed. Rejected live
+        rows are zeroed IN-PROGRAM (fixed-shape scatter: rejected rows
+        target their real (page, offset), everything else targets
+        scratch (0, 0)) so no garbage outlives the step — chunk sizes
+        and accept patterns are data, never shapes."""
         from ..nn.module import functional_call
         model = self.model
         ps = self.page_size
+        R = self.scheduler.spec_k
 
         def mixed_step(state, pools, toks, tables, seq_lens, active,
                        n_live, forced, temps, top_ps, greedy, seeds,
@@ -1609,50 +1615,9 @@ class ServingEngine:
                 model, state, toks, None, pools, 0,
                 (tables, seq_lens, active, n_live), lora=lora,
                 training=False)
-            S, K, V = logits.shape
-            rows = jnp.arange(K)
-            live = rows[None, :] < n_live[:, None]            # [S, K]
-            # per-slot poison sentinel over the LIVE rows only (padded
-            # rows read scratch and may be anything)
-            ok = jnp.all(jnp.where(live[..., None],
-                                   jnp.isfinite(logits.astype(jnp.float32)),
-                                   True), axis=(1, 2))
-            # sample all S*K rows with the row's own token index —
-            # logits stay in the model dtype so argmax/softmax see the
-            # same bits the 1-token decode step would
-            with jax.named_scope("sampler"):
-                samp = _sample_rows(
-                    logits.reshape(S * K, V),
-                    jnp.repeat(temps, K), jnp.repeat(top_ps, K),
-                    jnp.repeat(greedy, K), jnp.repeat(seeds, K),
-                    (counts[:, None] + rows[None, :]).reshape(-1),
-                ).reshape(S, K)
-            # accepted count m: a forced (chunk) slot accepts all its
-            # rows — its tokens are the prompt, not guesses; a verify
-            # slot accepts the longest prefix of live draft rows
-            # matching the previous row's sample
-            match = (toks[:, 1:] == samp[:, :-1]) & live[:, 1:]
-            m = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1),
-                        axis=1)                               # [S]
-            m = jnp.where(forced, n_live - 1, m)
-            # in-program rollback: zero the rejected live rows at their
-            # real (page, offset); all other rows target scratch (0, 0).
-            # Speculatively-written pages are always private to their
-            # request (shared full pages are immutable, COW copies
-            # partials), so the zeroing can never hit foreign KV. A
-            # forced slot has no rejected rows (rows > n_live - 1 are
-            # not live), so chunk writes always survive.
-            with jax.named_scope("rollback"):
-                pos = seq_lens[:, None] + rows[None, :]       # [S, K]
-                rej = (live & (rows[None, :] > m[:, None])
-                       & active[:, None])
-                page = jnp.take_along_axis(tables, pos // ps, axis=1)
-                page = jnp.where(rej, page, 0)
-                off = jnp.where(rej, pos % ps, 0)
-                pools = [(KVCachePool._pos_zero(pk, page, off),
-                          KVCachePool._pos_zero(pv, page, off))
-                         for pk, pv in pools]
-            return samp, m, ok, pools
+            return _mixed_tail(logits, pools, toks, tables, seq_lens,
+                               active, n_live, forced, temps, top_ps,
+                               greedy, seeds, counts, R, ps, False)
 
         if self._tp is None:
             return jax.jit(mixed_step)
@@ -1660,9 +1625,8 @@ class ServingEngine:
         if tp.pp > 1:
             # pp>1: the forward is the microbatched pipeline ring — the
             # chunk splits into waves that overlap across stages — and
-            # everything after the logits (finite sentinel, Leviathan
-            # accept, in-program rollback) repeats the tp body verbatim
-            # on the replicated values, except the rollback scatter
+            # everything after the logits is the tp body's own tail on
+            # the replicated values, except the rollback scatter
             # addresses the stacked [L, pages, ...] pool layout
             waves = self._pp_waves
 
@@ -1672,35 +1636,9 @@ class ServingEngine:
                 logits, pools = tp.staged_forward(
                     state, pools, toks, tables, seq_lens, active, n_live,
                     waves=waves)
-                S, K, V = logits.shape
-                rows = jnp.arange(K)
-                live = rows[None, :] < n_live[:, None]        # [S, K]
-                ok = jnp.all(jnp.where(
-                    live[..., None],
-                    jnp.isfinite(logits.astype(jnp.float32)),
-                    True), axis=(1, 2))
-                with jax.named_scope("sampler"):
-                    samp = _sample_rows(
-                        logits.reshape(S * K, V),
-                        jnp.repeat(temps, K), jnp.repeat(top_ps, K),
-                        jnp.repeat(greedy, K), jnp.repeat(seeds, K),
-                        (counts[:, None] + rows[None, :]).reshape(-1),
-                    ).reshape(S, K)
-                match = (toks[:, 1:] == samp[:, :-1]) & live[:, 1:]
-                m = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1),
-                            axis=1)
-                m = jnp.where(forced, n_live - 1, m)
-                with jax.named_scope("rollback"):
-                    pos = seq_lens[:, None] + rows[None, :]
-                    rej = (live & (rows[None, :] > m[:, None])
-                           & active[:, None])
-                    page = jnp.take_along_axis(tables, pos // ps, axis=1)
-                    page = jnp.where(rej, page, 0)
-                    off = jnp.where(rej, pos % ps, 0)
-                    pools = [(KVCachePool._pos_zero(pk, page, off, True),
-                              KVCachePool._pos_zero(pv, page, off, True))
-                             for pk, pv in pools]
-                return samp, m, ok, pools
+                return _mixed_tail(logits, pools, toks, tables, seq_lens,
+                                   active, n_live, forced, temps, top_ps,
+                                   greedy, seeds, counts, R, ps, True)
             return tp.compile_step(mixed_step_pp, self._state,
                                    self.pool.pools, n_lanes=11, n_lead=3)
         # tp>1: same body, ONE shard_map program (the rollback scatter is
@@ -1770,12 +1708,12 @@ class ServingEngine:
                 seeds[slot] = sp.seed
                 counts = np.zeros((S,), np.int32)
                 # row j samples with counts + j: anchor the LAST row of
-                # the pass on this request's next token index (earlier
-                # rows sample at stale indices and are discarded)
+                # the pass, the one the program samples for a forced
+                # slot, on this request's next token index
                 counts[slot] = len(req.tokens) - (n - 1)
                 atable = np.zeros((S,), np.int32)
                 atable[slot] = req.adapter_slot
-                tr.bump("rows_sampled", S * K)
+                tr.bump("rows_sampled", S * self.scheduler.spec_k)
                 samp, _, ok, new_pools = self._mixed_step(
                     self._state, self.pool.pools, jnp.asarray(toks),
                     jnp.asarray(tables), jnp.asarray(seq_lens),
@@ -1787,7 +1725,7 @@ class ServingEngine:
                 self.pool.pools = new_pools
                 samp, ok = self._watched_sync(samp, ok)
                 start += n
-                tok = int(samp[slot, n - 1])
+                tok = int(samp[slot, 0])  # the pass's last live row
                 if not bool(ok[slot]):
                     ok_all = False
                     break  # NaN cache rows only propagate — stop early
@@ -1977,7 +1915,6 @@ class ServingEngine:
         tokens sequential decode would have produced."""
         tr = self.tracer
         sched = self.scheduler
-        S, M, K = self.max_slots, self.max_pages_per_slot, self._chunk
         # the plan may be stale by one preemption (ensure_decode_pages
         # ran in between) — keep only slots that still owe chunks
         plan = {slot: n for slot, n in plan.items()
@@ -1990,7 +1927,7 @@ class ServingEngine:
             if tr.enabled:
                 # rows the program samples, against tokens emitted
                 tr.bump("mixed_steps")
-                tr.bump("rows_sampled", S * K)
+                tr.bump("rows_sampled", self.max_slots * sched.spec_k)
         with tr.span("mixed_dispatch", slots=len(plan) + len(n_drafted),
                      chunk_tokens=chunk_tokens,
                      drafts=sum(n_drafted.values())):
@@ -2039,8 +1976,8 @@ class ServingEngine:
                 n_live[slot] = n
                 forced[slot] = True
                 # row j samples with counts + j: anchor the LAST chunk
-                # row on this request's next token index (mid-chunk
-                # rows sample at stale indices and are discarded)
+                # row, the one the program samples for a forced slot,
+                # on this request's next token index
                 counts[slot] = len(req.tokens) - (n - 1)
                 chunk_tokens += n
                 if tr.enabled:
@@ -2127,7 +2064,8 @@ class ServingEngine:
                     # bitwise-identical first token itself
                     self._handoff_finish(req, events)
                     continue
-                self._emit(req, int(samp[slot, n - 1]), events)
+                # a chunk slot's last live row is column 0 of samp
+                self._emit(req, int(samp[slot, 0]), events)
             else:
                 n_draft = n_drafted[slot]
                 req.draft_tokens = []
@@ -2267,6 +2205,65 @@ class ServingEngine:
         events.append({"rid": req.rid, "token": token,
                        "finished": reason is not None,
                        "finish_reason": reason})
+
+
+def _mixed_tail(logits, pools, toks, tables, seq_lens, active, n_live,
+                forced, temps, top_ps, greedy, seeds, counts, R, ps,
+                stacked):
+    """Everything the mixed program does after the logits ``[S, K, V]``:
+    the finite sentinel, the samples ``[S, R]``, the accepted count and
+    the in-program rollback. ``R`` is the engine's ``spec_k``;
+    ``stacked`` addresses the pipeline pool's ``[L, pages, ...]``
+    layout."""
+    S, K, V = logits.shape
+    rows = jnp.arange(K)
+    live = rows[None, :] < n_live[:, None]                    # [S, K]
+    # per-slot poison sentinel over the LIVE rows only (padded rows read
+    # scratch and may be anything)
+    ok = jnp.all(jnp.where(live[..., None],
+                           jnp.isfinite(logits.astype(jnp.float32)),
+                           True), axis=(1, 2))
+    with jax.named_scope("sampler"):
+        # the host can emit, per slot, only a chunk's last live row (on
+        # its final chunk) or a verify slot's rows 0..m, m <= R - 1
+        # (drafts are capped at spec_k - 1): gather those R rows, the
+        # chunk's in column 0, and sample them alone. Each keeps its
+        # ORIGINAL row index in its key; logits stay in the model dtype
+        # so argmax/softmax see the same bits the 1-token decode step
+        # would
+        sel = jnp.clip(jnp.where(forced, n_live - 1, 0)[:, None]
+                       + jnp.arange(R)[None, :], 0, K - 1)    # [S, R]
+        picked = jnp.take_along_axis(logits, sel[:, :, None], axis=1)
+        samp = _sample_rows(
+            picked.reshape(S * R, V),
+            jnp.repeat(temps, R), jnp.repeat(top_ps, R),
+            jnp.repeat(greedy, R), jnp.repeat(seeds, R),
+            (counts[:, None] + sel).reshape(-1),
+        ).reshape(S, R)
+    # accepted count m: a forced (chunk) slot accepts all its rows — its
+    # tokens are the prompt, not guesses; a verify slot accepts the
+    # longest prefix of live draft rows matching the previous row's
+    # sample (rows at or beyond R were never live drafts)
+    match = (toks[:, 1:R] == samp[:, :R - 1]) & live[:, 1:R]
+    m = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=1), axis=1)
+    m = jnp.where(forced, n_live - 1, m)                      # [S]
+    # in-program rollback: zero the rejected live rows at their real
+    # (page, offset); all other rows target scratch (0, 0).
+    # Speculatively-written pages are always private to their request
+    # (shared full pages are immutable, COW copies partials), so the
+    # zeroing can never hit foreign KV. A forced slot has no rejected
+    # rows (rows > n_live - 1 are not live), so chunk writes always
+    # survive.
+    with jax.named_scope("rollback"):
+        pos = seq_lens[:, None] + rows[None, :]               # [S, K]
+        rej = live & (rows[None, :] > m[:, None]) & active[:, None]
+        page = jnp.take_along_axis(tables, pos // ps, axis=1)
+        page = jnp.where(rej, page, 0)
+        off = jnp.where(rej, pos % ps, 0)
+        pools = [(KVCachePool._pos_zero(pk, page, off, stacked),
+                  KVCachePool._pos_zero(pv, page, off, stacked))
+                 for pk, pv in pools]
+    return samp, m, ok, pools
 
 
 def _sample_rows(logits, temps, top_ps, greedy, seeds, counts):

@@ -5,7 +5,8 @@ The speculative contracts (SERVING.md "Speculative decoding"):
 1. BITWISE PARITY — the emitted stream with speculation on is bitwise
    identical to the non-speculative engine (greedy AND sampled), which
    is itself bitwise identical to standalone ``generate()``. The verify
-   step samples every position under the engine's standard
+   step samples every position a draft can occupy (``spec_k`` rows a
+   slot, never the chunk's padding) under the engine's standard
    ``fold_in(PRNGKey(seed), token_index)`` contract and emits its OWN
    samples — drafts only decide how many tokens a step emits, never
    which. Holds across churn, preemption, prefix-cache hits and int8 KV.
@@ -34,6 +35,7 @@ churn assertion: ``step_program_counts()`` must still be exactly
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as pt
@@ -460,6 +462,136 @@ class TestSpecRollback:
         s = eng.metrics.summary()
         assert s["spec_accept_rate"] == 0.0
         assert s["spec_draft_tokens_total"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the mixed program samples only the rows whose sample can be emitted
+# ---------------------------------------------------------------------------
+
+_RNG_ROWS = np.random.default_rng(29)
+P29, P7 = (_RNG_ROWS.integers(0, 512, n).tolist() for n in (29, 7))
+SP29 = dict(do_sample=True, top_p=0.9, temperature=0.8, seed=17)
+SP7 = dict(do_sample=True, top_p=0.7, temperature=1.3, seed=5)
+ROWS_CHUNK, ROWS_SLOTS = 8, 3
+
+
+def _sequential_sampled(model, prompt, n, sp):
+    """The contract itself, with no engine in it: ``generate()``'s own
+    prefill and one-token step programs over a contiguous cache, token
+    ``i`` drawn with ``fold_in(PRNGKey(seed), i)``."""
+    s0 = len(prompt)
+    prefill, _, step = model.decode_programs(
+        1, s0, n, s0 + n, True, sp["top_p"], sp["temperature"], None, None)
+    state = model.state_dict(include_non_persistable_buffer=True)
+    caches = model.init_kv_caches(1, s0 + n)
+
+    def key(i):
+        return jax.random.fold_in(jax.random.PRNGKey(sp["seed"]), i)
+
+    tok, caches = prefill(state, jnp.asarray([prompt]), caches, key(0), None)
+    out = [int(tok[0])]
+    for i in range(1, n):
+        tok, caches = step(state, tok, caches, s0 + i - 1, key(i), None)
+        out.append(int(tok[0]))
+    return out
+
+
+@pytest.fixture(scope="module")
+def sampled_refs(model, refs):
+    return {"c": _sequential_sampled(model, P29, MAX_NEW, SP29),
+            "v": _sequential_sampled(model, P7, 24, SP7),
+            "g": refs[5]}
+
+
+@pytest.fixture(scope="module")
+def row_engines(model):
+    """One engine per ``spec_k``, built on first use: 8-row chunks, so
+    the 29-token prompt takes four of them."""
+    engines = {}
+
+    def get(k):
+        if k not in engines:
+            engines[k] = _spec_engine(
+                model, spec=SpeculativeConfig(k=k) if k > 1 else None,
+                max_slots=ROWS_SLOTS, prefill_chunk=ROWS_CHUNK)
+        eng = engines[k]
+        eng.metrics = ServingMetrics()
+        eng.metrics.set_spec(k > 1)
+        return eng
+    return get
+
+
+def _rows_shape(eng, k, refs, monkeypatch):
+    samp, m, ok, _ = eng._mixed_step(*eng._warm_args("mixed"))
+    assert samp.shape == (ROWS_SLOTS, k)
+    assert m.shape == ok.shape == (ROWS_SLOTS,)
+
+
+def _rows_sampled_chunked_drafter(eng, k, refs, monkeypatch):
+    """Sampled requests with a nucleus cut, a prompt of four chunks, a
+    drafter whose guesses are mostly rejected: the streams are what
+    sequential decode draws."""
+    if k > 1:
+        eng._drafter = RepeatDrafter()
+    rids = [eng.add_request(P29, MAX_NEW, sampling=SamplingParams(**SP29)),
+            eng.add_request(P5, MAX_NEW)]
+    eng.step()
+    rids.append(eng.add_request(P7, MAX_NEW, sampling=SamplingParams(**SP7)))
+    res = eng.run_to_completion(max_steps=200)
+    assert [res[r] for r in rids] == [refs["c"], refs["g"],
+                                      refs["v"][:MAX_NEW]]
+    if k > 1:
+        assert eng.metrics.summary()["spec_draft_tokens_total"] > 0
+
+
+def _rows_chunk_and_verify_in_one_step(eng, k, refs, monkeypatch):
+    """A prompt's final chunk and a verify slot whose drafts all accept
+    share a step: the chunk slot's first token is column 0 of its row
+    of ``samp``, the verify slot's tokens columns ``0..m`` of its own."""
+    if k > 1:
+        eng._drafter = OracleDrafter({"v": refs["v"]})
+    calls = []
+    inner = eng._mixed_step
+
+    def spy(*args):
+        out = inner(*args)
+        calls.append(tuple(np.asarray(a) for a in (*args[2:13], *out[:3])))
+        return out
+    spy._cache_size = inner._cache_size
+    monkeypatch.setattr(eng, "_mixed_step", spy)
+    eng.add_request(P7, 24, sampling=SamplingParams(**SP7), rid="v")
+    eng.step()                       # "v" prefills and starts decoding
+    eng.add_request(P29, MAX_NEW, sampling=SamplingParams(**SP29), rid="c")
+    sv = eng.request("v").slot
+    res = eng.run_to_completion(max_steps=200)
+    assert res["v"] == refs["v"] and res["c"] == refs["c"]
+    shared = []
+    for (toks, tables, seq_lens, active, n_live, forced, temps, top_ps,
+         greedy, seeds, counts, samp, m, ok) in calls:
+        final = forced & (seq_lens + n_live == len(P29))
+        if final.any() and active[sv] and not forced[sv]:
+            shared.append((int(np.argmax(final)), counts, samp, m))
+    assert len(shared) == 1
+    sc, counts, samp, m = shared[0]
+    assert samp.shape == (ROWS_SLOTS, k)
+    assert int(samp[sc, 0]) == refs["c"][0]
+    assert int(m[sv]) == k - 1       # the oracle's drafts all accept
+    at = int(counts[sv])
+    assert samp[sv, :k].tolist() == refs["v"][at:at + k]
+
+
+@pytest.mark.parametrize("k", [1, 2, 4])
+@pytest.mark.parametrize("case", [_rows_shape, _rows_sampled_chunked_drafter,
+                                  _rows_chunk_and_verify_in_one_step],
+                         ids=lambda f: f.__name__.lstrip("_"))
+def test_mixed_program_samples_spec_k_rows_a_slot(
+        row_engines, sampled_refs, monkeypatch, case, k):
+    eng = row_engines(k)
+    case(eng, k, sampled_refs, monkeypatch)
+    monkeypatch.undo()
+    assert eng.step_program_counts()["mixed"] == 1
+    if case is not _rows_shape:
+        assert eng.step_program_counts() == {"decode": 1, "mixed": 1}
 
 
 # ---------------------------------------------------------------------------
