@@ -31,8 +31,9 @@ from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.module import Layer, Parameter
 
-__all__ = ["MoELayer", "TopKGate", "SwitchGate", "GShardGate", "ExpertFFN",
-           "moe_dispatch_combine", "moe_ragged_compute", "moe_grouped_compute",
+__all__ = ["MoELayer", "HeldExpertsMoE", "TopKGate", "SwitchGate",
+           "GShardGate", "ExpertFFN", "relu2", "moe_held_dense_compute",
+           "moe_held_tiles_compute", "moe_dispatch_combine", "moe_ragged_compute", "moe_grouped_compute",
            "moe_fused_compute", "global_scatter", "global_gather"]
 
 
@@ -736,3 +737,191 @@ class MoELayer(Layer):
                         axis_names={axis})
         y, aux = shmap(fn)(*args)
         return y, aux
+
+
+# ---------------------------------------------------------------------------
+# a chip's share of a routed-expert layer (serving)
+# ---------------------------------------------------------------------------
+
+def relu2(x):
+    """Squared ReLU (``mlp_hidden_act = "relu2"``)."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def _held_assignments(idx, live, first, n_held):
+    """Which of the [T, k] assignments land on a held expert of a live
+    row, and that expert's local index (``n_held`` where they do not)."""
+    local = idx - first
+    held = (local >= 0) & (local < n_held) & live[:, None]
+    return jnp.where(held, local, n_held), held
+
+
+def moe_held_dense_compute(x, local, w, w_in, w_gate, w_out, activation):
+    """Held experts over a FEW rows, dropless by a capacity equal to the
+    row count: every held expert computes every row (one batched matmul
+    that streams each expert's weights once, which is all a decode step
+    can do when nearly every expert has a row), and a row's combine
+    weight is zero where it was not routed.
+
+    x [T, D]; local, w [T, k] (local index ``n_held`` = not held);
+    weights [n_held, ...]. Returns float32 [T, D]."""
+    n_held = w_in.shape[0]
+    comb = jnp.sum(jax.nn.one_hot(local, n_held, dtype=jnp.float32)
+                   * w[..., None].astype(jnp.float32), axis=1)   # [T, E]
+    h = jnp.einsum("td,edh->eth", x, w_in,
+                   preferred_element_type=jnp.float32)
+    if w_gate is not None:
+        h = activation(jnp.einsum("td,edh->eth", x, w_gate,
+                                  preferred_element_type=jnp.float32)) * h
+    else:
+        h = activation(h)
+    h = (h * comb.T[:, :, None]).astype(x.dtype)
+    return jnp.einsum("eth,ehd->td", h, w_out,
+                      preferred_element_type=jnp.float32)
+
+
+def moe_held_tiles_compute(x, local, w, w_in, w_gate, w_out, activation,
+                           tile: int = 64):
+    """Held experts over MANY rows, dropless by sorted rows
+    (``moe_ragged_compute``'s way, without ``ragged_dot``, which the TPU
+    compiler expands to one dense product per group): the held
+    assignments are sorted by expert, each expert's run is cut into
+    tiles of ``tile`` rows, and a loop over the tiles multiplies each by
+    its expert's matrices and adds the weighted result to its rows.
+    Every held expert has one tile, empty or not, and a second only past
+    ``tile`` rows: the trip count is the number of held experts whenever
+    no expert has more than a tile of rows, so a step's time does not
+    follow its routing or its live rows (steps of several durations
+    under one latency percentile made it jump between runs), and an
+    expert's weights stream once.
+
+    Arguments as ``moe_held_dense_compute``. Returns float32 [T, D]."""
+    T, D = x.shape
+    K = local.shape[1]
+    n_held = w_in.shape[0]
+    key = local.reshape(-1)
+    order = jnp.argsort(key).astype(jnp.int32)       # stable: expert-major
+    sizes = jnp.bincount(key, length=n_held + 1)[:n_held].astype(jnp.int32)
+    starts = jnp.cumsum(sizes) - sizes               # of each expert's run
+    tiles = jnp.maximum(-(-sizes // tile), 1)
+    tile_ends = jnp.cumsum(tiles)
+    w_flat = w.reshape(-1).astype(jnp.float32)
+    lane = jnp.arange(tile, dtype=jnp.int32)
+
+    def body(t, out):
+        e = jnp.searchsorted(tile_ends, t, side="right").astype(jnp.int32)
+        j = t - (tile_ends[e] - tiles[e])            # tile within the run
+        left = sizes[e] - j * tile
+        pos = jnp.minimum(starts[e] + j * tile + lane, T * K - 1)
+        a = order[pos]                               # assignment ids
+        tok = a // K
+        xs = jnp.take(x, tok, axis=0)
+        h = jnp.dot(xs, w_in[e], preferred_element_type=jnp.float32)
+        if w_gate is not None:
+            h = activation(jnp.dot(xs, w_gate[e],
+                                   preferred_element_type=jnp.float32)) * h
+        else:
+            h = activation(h)
+        y = jnp.dot(h.astype(x.dtype), w_out[e],
+                    preferred_element_type=jnp.float32)
+        wt = jnp.where(lane < left, w_flat[a], 0.0)  # the run's tail: 0
+        return out.at[tok].add(y * wt[:, None])
+
+    return jax.lax.fori_loop(0, tile_ends[-1], body,
+                             jnp.zeros((T, D), jnp.float32))
+
+
+class HeldExpertsMoE(Layer):
+    """One chip's share of a routed-expert layer with a latent expert
+    width (LatentMoE) and a shared expert, for SERVING: it is told which
+    experts it holds (``experts_held = (first, count)``), keeps ``w_in``
+    / ``w_out`` for those only, routes every row over ALL
+    ``num_experts`` (sigmoid scores, a correction bias that chooses but
+    does not weigh, top-k, weights normalised over the chosen and
+    scaled), and returns the held experts' part of the routed sum plus
+    the shared expert. What the absent experts would add is left out:
+    summed over the chips that share the layer, the parts are the whole
+    routed sum. No row is ever dropped (no capacity).
+
+    ``forward(x, live)`` -> ``(out, counts)``; ``live`` [rows] marks the
+    rows that count (dead rows get the shared expert only), ``counts``
+    is int32 [3]: assignments routed (live rows x top_k), assignments
+    that landed on a held expert, held experts with at least one row."""
+
+    # a row count up to which every held expert computes every row
+    DENSE_ROWS = 256
+
+    def __init__(self, d_model, d_latent, d_hidden, num_experts, top_k,
+                 experts_held=None, d_shared=0, activation=relu2,
+                 gated=False, norm_topk_prob=True,
+                 routed_scaling_factor=1.0, tile_rows: int = 64):
+        super().__init__()
+        first, count = experts_held or (0, num_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= num_experts):
+            raise ValueError(f"experts_held={experts_held!r} is not a "
+                             f"range of the {num_experts} experts")
+        self.num_experts, self.top_k = num_experts, top_k
+        self.experts_held = (int(first), int(count))
+        self.norm_topk_prob = norm_topk_prob
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.activation = activation
+        self.tile_rows = int(tile_rows)
+        self.gate = nn.Linear(d_model, num_experts, bias_attr=False)
+        self.e_score_correction_bias = Parameter(
+            I.Constant(0.0)((num_experts,), jnp.float32), trainable=False)
+        self.fc1_latent_proj = nn.Linear(d_model, d_latent, bias_attr=False)
+        self.fc2_latent_proj = nn.Linear(d_latent, d_model, bias_attr=False)
+        self.experts = ExpertFFN(count, d_latent, d_hidden,
+                                 activation=activation, ep_axis=None,
+                                 gated=gated)
+        self.shared_up = self.shared_down = None
+        if d_shared:
+            self.shared_up = nn.Linear(d_model, d_shared, bias_attr=False)
+            self.shared_down = nn.Linear(d_shared, d_model, bias_attr=False)
+
+    def route(self, x):
+        """x [T, d_model] -> (idx [T, k] int32, weights [T, k] float32),
+        in float32 whatever the model's dtype."""
+        logits = jnp.dot(x.astype(jnp.float32),
+                         self.gate.weight.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(
+            s + self.e_score_correction_bias.astype(jnp.float32), self.top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if self.norm_topk_prob:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        return idx.astype(jnp.int32), w * self.routed_scaling_factor
+
+    def forward(self, x, live=None):
+        shape = x.shape
+        t = x.reshape(-1, shape[-1])
+        T = t.shape[0]
+        live = (jnp.ones((T,), bool) if live is None
+                else live.reshape(-1))
+        first, n_held = self.experts_held
+        with jax.named_scope("router"):
+            idx, w = self.route(t)
+            local, held = _held_assignments(idx, live, first, n_held)
+        with jax.named_scope("latent"):
+            lat = self.fc1_latent_proj(t)
+        ex = self.experts
+        with jax.named_scope("experts"):
+            compute = (moe_held_dense_compute if T <= self.DENSE_ROWS
+                       else functools.partial(moe_held_tiles_compute,
+                                              tile=self.tile_rows))
+            routed = compute(lat, local, w, ex.w_in,
+                             ex.w_gate if ex.gated else None, ex.w_out,
+                             self.activation)
+            touched = jnp.sum(jnp.bincount(
+                local.reshape(-1), length=n_held + 1)[:n_held] > 0)
+        with jax.named_scope("latent"):
+            out = self.fc2_latent_proj(routed.astype(t.dtype))
+        if self.shared_up is not None:
+            with jax.named_scope("shared"):
+                out = out + self.shared_down(
+                    self.activation(self.shared_up(t)))
+        counts = jnp.stack([jnp.sum(live) * self.top_k, jnp.sum(held),
+                            touched]).astype(jnp.int32)
+        return out.reshape(shape), counts

@@ -243,29 +243,8 @@ class LlamaAttention(Layer):
                                    (b, s))
             q = apply_rotary_pos_emb(q, cos, sin, pos)
             k = apply_rotary_pos_emb(k, cos, sin, pos)
-            pk, pv = kv_cache
-            ps = pk.shape[1]
-            live = active[:, None] & (jnp.arange(s)[None, :]
-                                      < (n_live[:, None] if n_live is not None
-                                         else s))
-            page = jnp.take_along_axis(tables, pos // ps, axis=1)
-            page = jnp.where(live, page, 0)
-            off = jnp.where(live, pos % ps, 0)
-            from ..quantization.serving import QuantizedKV, kv_quantize
-            if isinstance(pk, QuantizedKV):
-                # int8 pool: quantize the step tokens at write time (codes
-                # + per-row absmax scale); the read side dequantizes
-                # inside the one shared decode core
-                kq, vq = kv_quantize(k), kv_quantize(v)
-                pk = QuantizedKV(pk.q.at[page, off].set(kq.q),
-                                 pk.scale.at[page, off].set(kq.scale))
-                pv = QuantizedKV(pv.q.at[page, off].set(vq.q),
-                                 pv.scale.at[page, off].set(vq.scale))
-            else:
-                pk = pk.at[page, off].set(k.astype(pk.dtype))
-                pv = pv.at[page, off].set(v.astype(pv.dtype))
-            with jax.named_scope("core"):
-                out = F.paged_attention_decode(q, pk, pv, tables, seq_lens)
+            out, (pk, pv) = F.paged_attention_write_attend(
+                q, k, v, kv_cache, tables, seq_lens, pos, active, n_live)
             return _out_proj(out.reshape(b, s, h * d)), (pk, pv)
         # sequence parallelism: when tracing inside a manual-sep shard_map
         # region (the pipelined train step), x is the LOCAL seq shard —

@@ -10,6 +10,7 @@ from .conv import *  # noqa: F401,F403
 from .loss import *  # noqa: F401,F403
 from .norm import *  # noqa: F401,F403
 from .pooling import *  # noqa: F401,F403
+from .ssm import *  # noqa: F401,F403
 from .extras import *  # noqa: F401,F403
 from ..layer.rnn import birnn, rnn  # noqa: F401  (functional recurrence entry points)
 from ...ops.pallas.flash_attention import flash_attn_unpadded  # noqa: F401

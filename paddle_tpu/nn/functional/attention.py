@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["scaled_dot_product_attention", "flash_attention", "sdp_kernel",
-           "paged_attention_decode", "cached_prefill_attention"]
+           "paged_attention_decode", "cached_prefill_attention",
+           "paged_attention_write_attend"]
 
 # sdp_kernel override; None -> read FLAGS_flash_min_seq (default 256). The
 # Pallas kernel's block logic covers seq >= 256 (blocks halve to divide the
@@ -519,6 +520,48 @@ def paged_attention_decode(q, pool_k, pool_v, block_tables, seq_lens,
         kg = pool_k[block_tables].reshape(b, -1, kvh, d)
         vg = pool_v[block_tables].reshape(b, -1, kvh, d)
     return _grouped_decode_attn(q, kg, vg, seq_lens, scale)
+
+
+def paged_attention_write_attend(q, k, v, kv_cache, block_tables, seq_lens,
+                                 pos, active, n_live=None, scale=None):
+    """A paged attention layer's step: write this step's K/V rows into
+    the layer's page pair, then attend over the pool
+    (``paged_attention_decode``). One body for every model the serving
+    engine steps.
+
+    q [b, s, h, d], k/v [b, s, kvh, d] (already rotated where the model
+    rotates); ``kv_cache`` the layer's ``(pool_k, pool_v)``
+    [num_pages, page_size, kvh, d] (fp arrays or ``QuantizedKV``);
+    ``pos`` [b, s] the pool position of each row (``seq_lens + j``).
+    Rows ``j >= n_live`` and the rows of inactive slots write the
+    reserved scratch page 0. Returns the attention output [b, s, h, d]
+    and the new page pair."""
+    s = q.shape[1]
+    pk, pv = kv_cache
+    ps = pk.shape[1]
+    live = active[:, None] & (jnp.arange(s)[None, :]
+                              < (n_live[:, None] if n_live is not None
+                                 else s))
+    page = jnp.take_along_axis(block_tables, pos // ps, axis=1)
+    page = jnp.where(live, page, 0)
+    off = jnp.where(live, pos % ps, 0)
+    from ...quantization.serving import QuantizedKV, kv_quantize
+    if isinstance(pk, QuantizedKV):
+        # int8 pool: quantize the step tokens at write time (codes
+        # + per-row absmax scale); the read side dequantizes
+        # inside the one shared decode core
+        kq, vq = kv_quantize(k), kv_quantize(v)
+        pk = QuantizedKV(pk.q.at[page, off].set(kq.q),
+                         pk.scale.at[page, off].set(kq.scale))
+        pv = QuantizedKV(pv.q.at[page, off].set(vq.q),
+                         pv.scale.at[page, off].set(vq.scale))
+    else:
+        pk = pk.at[page, off].set(k.astype(pk.dtype))
+        pv = pv.at[page, off].set(v.astype(pv.dtype))
+    with jax.named_scope("core"):
+        out = paged_attention_decode(q, pk, pv, block_tables, seq_lens,
+                                     scale=scale)
+    return out, (pk, pv)
 
 
 class sdp_kernel:

@@ -22,14 +22,16 @@ streams.
 from .engine import BrownoutConfig, ServingEngine
 from .errors import (AdmissionShedError, EngineDrainingError,
                      FleetOverloadedError, QueueFullError,
-                     ReplicaSpawnError, RequestTooLargeError,
+                     RecurrentStateError, ReplicaSpawnError,
+                     RequestTooLargeError,
                      SchedulerStalledError, ServingError, StaleEpochError,
                      TPConfigError, TransportError)
 from .fleet import FleetRequest, FleetRouter
 from .transport import (ChaosTransport, EngineServer, LoopbackTransport,
                         Message, Transport, deterministic_jitter)
 from .transport_socket import FrameChaos, FrameDecoder, SocketTransport
-from .kv_cache import KVCachePool, PoolExhaustedError, PrefixMatch
+from .kv_cache import (HybridCache, KVCachePool, PoolExhaustedError,
+                       PrefixMatch)
 from .lora import (AdapterExhaustedError, AdapterPool,
                    AdapterUnavailableError, LoRAAdapter)
 from .metrics import FleetMetrics, ServingMetrics, percentile
@@ -48,7 +50,7 @@ from .workload import (Workload, WorkloadRequest, WorkloadSpec,
 
 __all__ = [
     "ServingEngine", "BrownoutConfig",
-    "KVCachePool", "PoolExhaustedError", "PrefixMatch",
+    "KVCachePool", "PoolExhaustedError", "PrefixMatch", "HybridCache",
     "ServingMetrics", "FleetMetrics",
     "FleetRouter", "FleetRequest",
     "percentile", "Request", "SamplingParams", "Scheduler",
@@ -64,7 +66,7 @@ __all__ = [
     "long_prompt_workload", "make_workload", "overload_workload",
     "ServingError", "QueueFullError", "RequestTooLargeError",
     "SchedulerStalledError", "EngineDrainingError", "FleetOverloadedError",
-    "TPConfigError", "AdmissionShedError",
+    "TPConfigError", "AdmissionShedError", "RecurrentStateError",
     "TransportError", "StaleEpochError", "ReplicaSpawnError",
     "Transport", "LoopbackTransport", "ChaosTransport", "EngineServer",
     "Message", "deterministic_jitter",

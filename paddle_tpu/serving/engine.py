@@ -67,6 +67,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import logging
 from dataclasses import dataclass
 
 import jax
@@ -76,8 +77,9 @@ import numpy as np
 from ..distributed import fault as _fault
 from ..observability.trace import PROFILE_TRACER
 from .errors import (AdmissionShedError, EngineDrainingError, QueueFullError,
-                     RequestTooLargeError, SchedulerStalledError)
-from .kv_cache import KVCachePool
+                     RecurrentStateError, RequestTooLargeError,
+                     SchedulerStalledError)
+from .kv_cache import HybridCache, KVCachePool, declared_cache_layers
 from .metrics import ServingMetrics
 from .scheduler import FINISHED, Request, SamplingParams, Scheduler
 from .snapshot import (RequestSnapshot, load_engine_snapshot,
@@ -90,6 +92,8 @@ __all__ = ["ServingEngine", "BrownoutConfig"]
 # head) repeats identically every step, while a transient injected alloc
 # storm recovers as soon as its fault spec stops matching — so > 1, small
 _STALL_PATIENCE = 3
+
+_log = logging.getLogger(__name__)
 
 
 @dataclass
@@ -164,7 +168,30 @@ class ServingEngine:
         self.max_pages_per_slot = (max_pages_per_slot
                                    if max_pages_per_slot is not None
                                    else (num_pages - 1))
+        # a model with per-slot recurrent state (its config declares it:
+        # ``cache_layers()``; SERVING.md "Models with recurrent state").
+        # Whatever assumes that a request's whole state is its pages is
+        # switched off where it is a default (the prefix cache) and
+        # refused where it is asked for.
+        self._recurrent = bool(declared_cache_layers(cfg)[1])
+        if self._recurrent:
+            int8_kv = kv_quant or (kv_dtype is not None
+                                   and jnp.dtype(kv_dtype) == jnp.int8)
+            for asked, what in (
+                    (speculative, "speculative decoding"),
+                    (host_tier, "the host tier"),
+                    (snapshot_store, "a snapshot store"), (lora, "LoRA"),
+                    (int8_kv, "an int8 KV pool")):
+                if asked:
+                    self._refuse_recurrent(what)
+            if prefix_cache:
+                prefix_cache = False
+                _log.warning(
+                    "%s keeps per-slot recurrent state: the prefix cache "
+                    "is off (a cached page holds K/V, not the state at "
+                    "its boundary)", type(cfg).__name__)
         self.prefix_cache = prefix_cache
+        self._step_counts = None    # the last step's HybridCache.counts
         # tensor parallelism (serving/parallel.py; SERVING.md
         # "Tensor-parallel serving"): tp=N spans this engine over N
         # devices (tp_devices, default the first N visible) — the KV
@@ -208,7 +235,7 @@ class ServingEngine:
             cache_enabled=prefix_cache, quantized=kv_quant,
             host_tier=host_tier if prefix_cache else None,
             sharding=(self._tp.kv_shardings() if self._tp else None),
-            tp_degree=self.tp, pp_degree=self.pp)
+            tp_degree=self.tp, pp_degree=self.pp, max_slots=max_slots)
         # every (re-)admission must fit the slot's block table and the
         # rope table — admission_check guards the window up front
         self._ctx_pages = min(self.max_pages_per_slot,
@@ -424,6 +451,8 @@ class ServingEngine:
             prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
             if not prompt:
                 raise ValueError("prompt must be non-empty")
+            if prefill_only and self._recurrent:
+                self._refuse_recurrent("a prefill-only hand-off")
             adapter_hex = ""
             if adapter is not None and adapter != "":
                 from .lora import AdapterUnavailableError
@@ -882,6 +911,13 @@ class ServingEngine:
     # crash-consistent snapshots (serving/snapshot.py)
     # ------------------------------------------------------------------
 
+    def _refuse_recurrent(self, what: str) -> None:
+        raise RecurrentStateError(
+            f"{type(self.model.config).__name__} keeps per-slot recurrent "
+            f"state: the engine cannot honour {what}, which takes a "
+            f"request's pages for its whole state (SERVING.md \"Models "
+            f"with recurrent state\")")
+
     def save_snapshot(self, path: str) -> str:
         """Durable warm-restart snapshot: capture every live request's
         resumable state NOW and persist it through the checkpoint
@@ -889,6 +925,8 @@ class ServingEngine:
         rename — RESILIENCE.md). A crash mid-save leaves a torn staging
         dir that :meth:`restore` rejects; the previous committed
         snapshot at ``path`` is replaced only by the atomic rename."""
+        if self._recurrent:
+            self._refuse_recurrent("save_snapshot")
         snaps = self._capture_requests()
         # "tp"/"pp" are informational: payloads are full logical pages
         # (the capture device_get gathers shards, and the stacked pp
@@ -911,6 +949,8 @@ class ServingEngine:
         sample; the injected KV only saves recompute). Raises
         :class:`CheckpointCorruptionError` on a torn or unverifiable
         snapshot dir. Returns the restored rids."""
+        if self._recurrent:
+            self._refuse_recurrent("restore")
         snaps, _meta = load_engine_snapshot(path)
         return [self.restore_request(s) for s in snaps]
 
@@ -929,6 +969,8 @@ class ServingEngine:
         failed-over request that would bust the survivor's quota is
         refused with AdmissionShedError and stays queued at the router
         for the next placement attempt."""
+        if self._recurrent:
+            self._refuse_recurrent("restore_request")
         if self._draining:
             raise EngineDrainingError(
                 "engine is draining; restore on another replica")
@@ -1011,8 +1053,10 @@ class ServingEngine:
         chaos-suite hook proving the engine left the pool consistent."""
         tables = [list(r.pages)
                   for r in self.scheduler.running.values() if r.pages]
+        slots = ({slot: r.rid for slot, r in self.scheduler.running.items()}
+                 if self._recurrent else None)
         return self.pool.audit(block_tables=tables,
-                               check_device=check_device)
+                               check_device=check_device, slots=slots)
 
     def _capture_requests(self) -> list[RequestSnapshot]:
         """Sealed snapshot of every live request, via ONE batched
@@ -1222,16 +1266,14 @@ class ServingEngine:
         phase-split contract (``{"decode": 0, "mixed": 1}``, SERVING.md
         "Disaggregated serving") survives warming."""
         if decode:
-            _, _, pools = self._decode_step(*self._warm_args("decode"))
-            self.pool.pools = pools
+            self._call_step(self._decode_step, self._warm_lanes("decode"))
         if mixed:
-            _, _, _, pools = self._mixed_step(*self._warm_args("mixed"))
-            self.pool.pools = pools
+            self._call_step(self._mixed_step, self._warm_lanes("mixed"))
         self._note_retraces()
 
-    def _warm_args(self, program: str) -> tuple:
-        """All-inactive arguments of one step program (every row targets
-        the reserved scratch page 0): the shapes and dtypes every real
+    def _warm_lanes(self, program: str) -> tuple:
+        """All-inactive lanes of one step program (every row targets the
+        reserved scratch page 0): the shapes and dtypes every real
         dispatch has, writing and registering nothing."""
         S, M, K = self.max_slots, self.max_pages_per_slot, self._chunk
         zi = jnp.zeros((S,), jnp.int32)
@@ -1240,12 +1282,19 @@ class ServingEngine:
         gt = jnp.ones((S,), bool)
         tables = jnp.zeros((S, M), jnp.int32)
         if program == "decode":
-            return (self._state, self.pool.pools, zi, tables, zi, zb,
-                    ones, ones, gt, zi, zi, *self._lora_args())
-        return (self._state, self.pool.pools,
-                jnp.zeros((S, K), jnp.int32),
+            return (zi, tables, zi, zb, ones, ones, gt, zi, zi,
+                    *self._lora_args())
+        return (jnp.zeros((S, K), jnp.int32),
                 tables, zi, zb, zi, zb, ones, ones, gt, zi, zi,
                 *self._lora_args())
+
+    def _warm_args(self, program: str) -> tuple:
+        """Every argument of one step program for an all-inactive
+        dispatch: the weights, the page pairs, for a model with
+        recurrent state the state, then the lanes."""
+        lead = ((self._state, self.pool.pools, self.pool.state)
+                if self._recurrent else (self._state, self.pool.pools))
+        return (*lead, *self._warm_lanes(program))
 
     def lower_step_programs(self) -> dict:
         """jax AOT view of the two step programs at the shapes every
@@ -1283,6 +1332,7 @@ class ServingEngine:
                 # flows through the ONE mixed program
                 "prefill_programs": self.mixed_program_count(),
                 "prefix_cache": self.prefix_cache,
+                "recurrent_state": self._recurrent,
                 "kv_quant": self.kv_quant,
                 "host_tier": self.pool.host_tier is not None,
                 "speculative": self._spec is not None,
@@ -1537,6 +1587,19 @@ class ServingEngine:
                 nt = _sample_rows(last, temps, top_ps, greedy, seeds, counts)
             return nt, ok, pools
 
+        if self._recurrent:
+            # the same body with the per-slot state threaded beside the
+            # pages and DONATED (the step rewrites every slot's row, so
+            # the new state takes the old one's memory); the model's
+            # own counters ride back with it
+            def decode_step_state(state, pools, rstate, tok, tables,
+                                  seq_lens, active, temps, top_ps, greedy,
+                                  seeds, counts):
+                nt, ok, cache = decode_step(
+                    state, HybridCache(pools, rstate), tok, tables,
+                    seq_lens, active, temps, top_ps, greedy, seeds, counts)
+                return nt, ok, cache.kv, cache.state, cache.counts
+            return jax.jit(decode_step_state, donate_argnums=(2,))
         if self._tp is None:
             return jax.jit(decode_step)
         tp = self._tp
@@ -1619,6 +1682,21 @@ class ServingEngine:
                                active, n_live, forced, temps, top_ps,
                                greedy, seeds, counts, R, ps, False)
 
+        if self._recurrent:
+            def mixed_step_state(state, pools, rstate, toks, tables,
+                                 seq_lens, active, n_live, forced, temps,
+                                 top_ps, greedy, seeds, counts):
+                (logits, cache), _ = functional_call(
+                    model, state, toks, None, HybridCache(pools, rstate),
+                    0, (tables, seq_lens, active, n_live), training=False)
+                # no row is ever rejected (speculation is refused), so
+                # the tail's rollback zeroes nothing: of the pages only
+                samp, m, ok, kv = _mixed_tail(
+                    logits, cache.kv, toks, tables, seq_lens, active,
+                    n_live, forced, temps, top_ps, greedy, seeds, counts,
+                    R, ps, False)
+                return samp, m, ok, kv, cache.state, cache.counts
+            return jax.jit(mixed_step_state, donate_argnums=(2,))
         if self._tp is None:
             return jax.jit(mixed_step)
         tp = self._tp
@@ -1714,15 +1792,14 @@ class ServingEngine:
                 atable = np.zeros((S,), np.int32)
                 atable[slot] = req.adapter_slot
                 tr.bump("rows_sampled", S * self.scheduler.spec_k)
-                samp, _, ok, new_pools = self._mixed_step(
-                    self._state, self.pool.pools, jnp.asarray(toks),
+                samp, _, ok = self._call_step(self._mixed_step, (
+                    jnp.asarray(toks),
                     jnp.asarray(tables), jnp.asarray(seq_lens),
                     jnp.asarray(active), jnp.asarray(n_live),
                     jnp.asarray(forced), jnp.asarray(temps),
                     jnp.asarray(top_ps), jnp.asarray(greedy),
                     jnp.asarray(seeds), jnp.asarray(counts),
-                    *self._lora_args(atable))
-                self.pool.pools = new_pools
+                    *self._lora_args(atable)))
                 samp, ok = self._watched_sync(samp, ok)
                 start += n
                 tok = int(samp[slot, 0])  # the pass's last live row
@@ -1887,10 +1964,10 @@ class ServingEngine:
                         sum(req.context_len // ps + 1
                             for req in self.scheduler.running.values()))
         with tr.span("decode_dispatch", slots=len(self.scheduler.running)):
-            nt, ok, new_pools = self._decode_step(
-                self._state, self.pool.pools, *lanes)
-            self.pool.pools = new_pools
+            nt, ok = self._call_step(self._decode_step, lanes)
         nt, ok = self._watched_sync(nt, ok)
+        if self._recurrent and tr.enabled:
+            self._bump_state_counters()
         with tr.span("sample_emit"):
             for slot, req in list(self.scheduler.running.items()):
                 req.context_len += 1  # this step's KV write at old
@@ -1931,10 +2008,10 @@ class ServingEngine:
         with tr.span("mixed_dispatch", slots=len(plan) + len(n_drafted),
                      chunk_tokens=chunk_tokens,
                      drafts=sum(n_drafted.values())):
-            samp, acc, ok, new_pools = self._mixed_step(
-                self._state, self.pool.pools, *lanes)
-            self.pool.pools = new_pools
+            samp, acc, ok = self._call_step(self._mixed_step, lanes)
         samp, acc, ok = self._watched_sync(samp, acc, ok)
+        if self._recurrent and tr.enabled:
+            self._bump_state_counters()
         with tr.span("sample_emit"):
             self._mixed_emit(events, plan, n_drafted, samp, acc, ok)
         return chunk_tokens
@@ -2108,6 +2185,35 @@ class ServingEngine:
                     tr.bump("spec_rejected_tokens", n_draft - m)
                 for t in emit:
                     self._emit(req, t, events)
+
+    def _call_step(self, step, lanes) -> list:
+        """Run one step program over ``lanes``. What it returns of the
+        pool (the page pairs and, for a model with recurrent state, the
+        state, whose old arrays the program was given to reuse) replaces
+        the pool's arrays; the rest is handed back."""
+        pool = self.pool
+        if not self._recurrent:
+            *out, pool.pools = step(self._state, pool.pools, *lanes)
+            return out
+        *out, pool.pools, pool.state, self._step_counts = step(
+            self._state, pool.pools, pool.state, *lanes)
+        return out
+
+    def _bump_state_counters(self) -> None:
+        """A traced step of a model with recurrent state: the slots and
+        bytes of state it held, and what the program itself counted in
+        its expert layers (summed over them): assignments routed (live
+        rows x top_k), those that landed on a held expert, and held
+        experts with at least one row."""
+        tr, pool = self.tracer, self.pool
+        live = len(pool.state_slots)
+        tr.bump("state_slots_live", live)
+        tr.bump("state_bytes_live", live * pool.state_bytes_per_slot)
+        routed, held, touched = (int(v) for v in
+                                 np.asarray(self._step_counts))
+        tr.bump("expert_rows_routed", routed)
+        tr.bump("expert_rows_held", held)
+        tr.bump("experts_touched", touched)
 
     def _note_retraces(self) -> None:
         """Retrace sentinel, one per step shape ("decode", "mixed"):

@@ -56,6 +56,11 @@ router that acts on it is :class:`paddle_tpu.serving.fleet.FleetRouter`
   ``ServingEngine(tp=N)`` construction instead of a shape crash inside
   the compiled step. NOT retryable: every replica of the same config
   would fail identically.
+- :class:`RecurrentStateError` — a feature that assumes a request's
+  whole state is its pages (speculation, the host tier, snapshots and
+  hand-off, LoRA, int8 KV) was asked of an engine whose model keeps
+  per-slot recurrent state (SERVING.md "Models with recurrent state").
+  NOT retryable.
 - :class:`TransportError` — a fleet wire message failed its blake2b
   digest re-verify at receive (``serving/transport.py``): the payload
   was corrupted in flight. The message is dropped and counted, never
@@ -81,6 +86,7 @@ from __future__ import annotations
 __all__ = ["ServingError", "QueueFullError", "RequestTooLargeError",
            "SchedulerStalledError", "EngineDrainingError",
            "FleetOverloadedError", "TPConfigError", "AdmissionShedError",
+           "RecurrentStateError",
            "TransportError", "StaleEpochError", "ReplicaSpawnError"]
 
 
@@ -140,6 +146,19 @@ class TPConfigError(ServingError, ValueError):
     the TP degree, or fewer than N devices are visible. Raised at
     engine construction — the compiled step never sees the bad shapes.
     Not retryable: homogeneous replicas all reject it identically."""
+
+    retryable = False
+
+
+class RecurrentStateError(ServingError, ValueError):
+    """A feature was asked for that the engine cannot honour for a model
+    with per-slot recurrent state (a state-space layer): speculation,
+    the host tier, snapshots / restore / hand-off, LoRA, int8 KV. Each
+    assumes that a request's whole state is its pages and can be cut at
+    any token; a recurrent state can be kept only where it was
+    checkpointed. Raised at construction or at the call that asks.
+
+    Not retryable: homogeneous replicas all refuse identically."""
 
     retryable = False
 

@@ -1,7 +1,11 @@
 """Paged KV-cache pool for the continuous-batching serving engine.
 
 One fixed ``[num_pages, page_size, n_kv_heads, head_dim]`` array pair per
-layer (the PagedAttention pool, SOSP '23); sequences own pages through
+layer that keeps K/V pages (every layer of a uniform decoder; the
+attention layers of a model that declares ``cache_layers()``, whose
+recurrent layers get a per-slot state beside the pages:
+``KVCachePool.state``, SERVING.md "Models with recurrent state")
+(the PagedAttention pool, SOSP '23); sequences own pages through
 per-request int32 block tables instead of contiguous ``[B, max_len]``
 buffers, so cache memory fragments at page granularity instead of
 request granularity and a request's reservation grows one page at a
@@ -48,6 +52,7 @@ import math
 import struct
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -58,7 +63,19 @@ from ..quantization.serving import QuantizedKV
 from .errors import ServingError
 from .tiering import HostTier
 
-__all__ = ["KVCachePool", "PoolExhaustedError", "PrefixMatch"]
+__all__ = ["KVCachePool", "PoolExhaustedError", "PrefixMatch", "HybridCache",
+           "declared_cache_layers"]
+
+
+class HybridCache(NamedTuple):
+    """What the step programs hand a model whose layers do not all keep
+    K/V pages: ``kv`` the page pairs of its attention layers, ``state``
+    the per-slot arrays of its recurrent layers (``KVCachePool.state``),
+    both in layer order. The model returns the same with ``counts`` set
+    (small integers it counted in the step; the engine's counters)."""
+    kv: list
+    state: list
+    counts: Any = None
 
 # chain root for the page-content hash (the "parent" of the first page).
 # A quantized pool chains from a DIFFERENT root (the mode tag hashed in),
@@ -106,6 +123,22 @@ def _page_hash(parent: bytes, tokens) -> bytes:
     return h.digest()
 
 
+def declared_cache_layers(config) -> tuple[list, list]:
+    """What a model's config says its layers keep for a request:
+    ``config.cache_layers()`` gives, in layer order, ``("pages", kv
+    heads, head dim)``, ``("state", ((shape, dtype), ...))`` (arrays kept
+    per slot) or None; a config without it is a uniform decoder, every
+    one of its ``num_hidden_layers`` keeping pages of
+    ``num_key_value_heads`` x ``head_dim``. Returns the page formats and
+    the state declarations, each in layer order."""
+    declare = getattr(config, "cache_layers", None)
+    layers = (declare() if declare is not None else
+              [("pages", config.num_key_value_heads, config.head_dim)]
+              * config.num_hidden_layers)
+    return ([spec[1:] for spec in layers if spec and spec[0] == "pages"],
+            [spec[1] for spec in layers if spec and spec[0] == "state"])
+
+
 class PoolExhaustedError(ServingError):
     """Raised by ``alloc`` when the pool cannot satisfy a request; the
     scheduler catches it and preempts (never propagates to users)."""
@@ -149,7 +182,7 @@ class KVCachePool:
                  num_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
                  cache_enabled: bool = True, quantized: bool = False,
                  host_tier=None, sharding=None, tp_degree: int = 1,
-                 pp_degree: int = 1):
+                 pp_degree: int = 1, state_layers=(), max_slots: int = 0):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "reserved scratch page)")
@@ -200,6 +233,20 @@ class KVCachePool:
             self.pools = [(_place(jnp.zeros(shape, dtype)),
                            _place(jnp.zeros(shape, dtype)))
                           for _ in range(1 if self.stacked else num_layers)]
+        # per-slot recurrent state beside the pages (a model whose layers
+        # are not all attention): one tuple of [max_slots, ...] arrays
+        # for each layer that declares one, threaded through the step
+        # programs like the pages. A slot's row belongs to the request
+        # that holds the slot (``state_admit`` / ``state_release``: host
+        # accounting); its content is reset inside the program, by the
+        # first row a request runs at position 0.
+        self.state = [tuple(jnp.zeros((max_slots, *shape), jnp.dtype(dt))
+                            for shape, dt in arrays)
+                      for arrays in state_layers]
+        self.state_slots: dict[int, str] = {}      # slot -> request id
+        self.state_bytes_per_slot = sum(
+            math.prod(shape) * jnp.dtype(dt).itemsize
+            for arrays in state_layers for shape, dt in arrays)
         # fp and int8 caches chain their content hashes from different
         # roots — same tokens, different page content, never aliased
         self._hash_root = _HASH_ROOT_INT8 if quantized else _HASH_ROOT
@@ -254,14 +301,20 @@ class KVCachePool:
                     dtype=jnp.bfloat16, cache_enabled: bool = True,
                     quantized: bool = False, host_tier=None,
                     sharding=None, tp_degree: int = 1,
-                    pp_degree: int = 1) -> "KVCachePool":
-        """Build from a model config carrying num_hidden_layers /
-        num_key_value_heads / head_dim (LlamaConfig shape)."""
-        return cls(config.num_hidden_layers, num_pages, page_size,
-                   config.num_key_value_heads, config.head_dim, dtype,
+                    pp_degree: int = 1, max_slots: int = 0) -> "KVCachePool":
+        """Build from what the model's config says each layer keeps for
+        a request (``declared_cache_layers``): one page pair for each
+        layer that keeps pages, ``max_slots`` rows of each array of each
+        layer that keeps a per-slot state."""
+        pages, state_layers = declared_cache_layers(config)
+        if not pages or len(set(pages)) != 1:
+            raise ValueError("the pool keeps one page format: the layers "
+                             f"that keep pages declare {sorted(set(pages))}")
+        return cls(len(pages), num_pages, page_size, *pages[0], dtype,
                    cache_enabled=cache_enabled, quantized=quantized,
                    host_tier=host_tier, sharding=sharding,
-                   tp_degree=tp_degree, pp_degree=pp_degree)
+                   tp_degree=tp_degree, pp_degree=pp_degree,
+                   state_layers=state_layers, max_slots=max_slots)
 
     # ---- accounting ----
 
@@ -346,8 +399,30 @@ class KVCachePool:
                     self.num_in_use * self.page_size * shard_bpt,
                 "tp_shard_capacity_bytes":
                     self.capacity * self.page_size * shard_bpt,
+                "state_layers": len(self.state),
+                "state_slots_live": len(self.state_slots),
+                "state_bytes_per_slot": self.state_bytes_per_slot,
+                "state_bytes_live":
+                    len(self.state_slots) * self.state_bytes_per_slot,
                 **tier,
                 **self.counters}
+
+    # ---- per-slot recurrent state (host accounting) ----
+
+    def state_admit(self, slot: int, rid: str) -> None:
+        """The request ``rid`` takes the state row of ``slot``. Nothing
+        moves on the device: its first row runs at position 0, and the
+        program starts a slot at position 0 from zero state."""
+        with self.tracer.span("state_admit", slot=slot):
+            if slot in self.state_slots:
+                raise AssertionError(
+                    f"state slot {slot} admitted to {rid!r} while "
+                    f"{self.state_slots[slot]!r} holds it")
+            self.state_slots[slot] = rid
+
+    def state_release(self, slot: int) -> None:
+        with self.tracer.span("state_release", slot=slot):
+            del self.state_slots[slot]
 
     # ---- alloc / free ----
 
@@ -984,7 +1059,8 @@ class KVCachePool:
 
     # ---- invariant audit ----
 
-    def audit(self, block_tables=None, check_device: bool = True) -> dict:
+    def audit(self, block_tables=None, check_device: bool = True,
+              slots=None) -> dict:
         """Invariant checker for the pool's host-side accounting —
         called from serving test teardowns and the faults-marked chaos
         suites, so every chaos scenario proves it left the pool
@@ -1097,8 +1173,22 @@ class KVCachePool:
                                 f"{name} content in layer {li}")
                     if problems and problems[-1].startswith("scrubbed"):
                         break   # one layer's evidence is enough
+        if self.state and slots is not None and slots != self.state_slots:
+            # ``slots``: slot -> request id of every running request
+            problems.append(f"state rows held {self.state_slots} but the "
+                            f"running slots are {slots}")
+        if self.state and check_device:
+            live = jnp.asarray(sorted(self.state_slots), jnp.int32)
+            for li, arrays in enumerate(self.state):
+                if not all(bool(jnp.all(jnp.isfinite(a[live].astype(
+                        jnp.float32)))) for a in arrays):
+                    problems.append(f"a live slot's state is not finite "
+                                    f"in state layer {li}")
         if problems:
             raise AssertionError(
                 "KV pool audit failed:\n- " + "\n- ".join(problems))
-        return {"pages": self.num_pages - 1, "free": len(free),
-                "cached": len(cached), "held": len(held)}
+        out = {"pages": self.num_pages - 1, "free": len(free),
+               "cached": len(cached), "held": len(held)}
+        if self.state:
+            out["state_slots"] = len(self.state_slots)
+        return out

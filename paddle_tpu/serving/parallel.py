@@ -75,17 +75,25 @@ def validate_tp_config(config, tp: int, pp: int = 1) -> None:
     :class:`TPConfigError` instead of a shape crash inside the compiled
     step. Every dimension the TP layout splits must divide evenly, and
     the decoder stack must carve into ``pp`` equal stages."""
+    family = type(config).__name__
     if tp < 1:
         raise TPConfigError(f"tp must be >= 1, got {tp}")
     if pp < 1:
         raise TPConfigError(f"pp must be >= 1, got {pp}")
+    if (tp > 1 or pp > 1) and hasattr(config, "cache_layers"):
+        raise TPConfigError(
+            f"{family}: a model whose layers keep different things "
+            f"(cache_layers(): pages, per-slot state, nothing) is served "
+            f"at tp=1, pp=1 only; the TP/PP step programs know a uniform "
+            f"decoder's shapes (got tp={tp}, pp={pp})")
     if pp > 1:
         layers = getattr(config, "num_hidden_layers", None)
         if layers is not None and layers % pp:
             raise TPConfigError(
-                f"num_hidden_layers={layers} is not divisible by pp={pp} "
-                f"(the stacked decoder shards {layers // pp or 1}+ layers "
-                f"per stage; stages must be equal)")
+                f"{family}: num_hidden_layers={layers} is not divisible "
+                f"by pp={pp} (the stacked decoder shards "
+                f"{layers // pp or 1}+ layers per stage; stages must be "
+                f"equal)")
     if tp == 1:
         return
     checks = (
@@ -98,8 +106,8 @@ def validate_tp_config(config, tp: int, pp: int = 1) -> None:
         val = getattr(config, field, None)
         if val is not None and val % tp:
             raise TPConfigError(
-                f"{field}={val} is not divisible by tp={tp} ({what} "
-                f"shards this dimension)")
+                f"{family}: {field}={val} is not divisible by tp={tp} "
+                f"({what} shards this dimension)")
 
 
 def partition_devices(n_groups: int, pp: int, tp: int | None = None,

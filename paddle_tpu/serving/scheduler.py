@@ -373,6 +373,8 @@ class Scheduler:
         req.restored_len = 0
         req.draft_tokens = []   # drafts are per-step state; recompute
                                 # re-proposes from the same history
+        if pool.state:
+            pool.state_release(req.slot)
         self._free_slots.append(req.slot)
         del self.running[req.slot]
         req.slot = None
@@ -658,6 +660,8 @@ class Scheduler:
             req.cached_partial = partial_q > 0
             req.adapter_slot = aslot
             req.slot = self._free_slots.pop()
+            if pool.state:      # a model with per-slot recurrent state
+                pool.state_admit(req.slot, req.rid)
             req.state = RUNNING
             req.prefill_target = n_valid
             # chunked: start at the cached length; the engine's mixed

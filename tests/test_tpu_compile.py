@@ -84,8 +84,8 @@ def _kernels(text: str, name: str) -> int:
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("heads,kv_heads", [(32, 8), (16, 8)],
-                         ids=["llama3_8b", "serving_bench"])
+@pytest.mark.parametrize("heads,kv_heads", [(32, 8), (16, 8), (32, 2)],
+                         ids=["llama3_8b", "serving_bench", "nemotron_h"])
 def test_paged_attention_compiles(mosaic, one_chip, heads, kv_heads, quant):
     slots, page, d, pages, table = 8, 16, 128, 512, 32
 
