@@ -139,11 +139,19 @@ def top_p_sampling(x, ps, threshold=None, seed=None, key=None, name=None):
     absolute per-token floor. Returns (values [B,1], indices [B,1] int32):
     one token per row sampled from the renormalised nucleus. The top-1 token
     is always kept (reference kernel contract), so ps<=0 is greedy decode.
+
+    The descending order and the sorted probabilities come from ONE stable
+    key-value sort of ``(-x, iota)``: the negated keys are the sorted
+    probabilities (the bits a ``take_along_axis(x, order)`` would read) and
+    the carried iota is ``argsort(-x)``, ties in index order. Nothing
+    gathers over the vocabulary.
     """
     x = jnp.asarray(x)
     ps = jnp.asarray(ps).reshape(-1, 1)
-    order = jnp.argsort(-x, axis=-1)
-    sorted_p = jnp.take_along_axis(x, order, axis=-1)
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    neg_sorted, order = jax.lax.sort_key_val(-x, iota, dimension=-1,
+                                             is_stable=True)
+    sorted_p = -neg_sorted
     prefix = jnp.cumsum(sorted_p, axis=-1) - sorted_p  # exclusive cumsum
     keep = prefix < ps
     keep = keep.at[:, 0].set(True)  # always keep the argmax

@@ -203,3 +203,28 @@ def test_paged_attention_in_tp_shard_map_compiles(mosaic, topo):
         out_specs=heads_spec, check_vma=False)
     text = _compile(step, q, pool, pool, tables, lens)
     assert _kernels(text, paged_attention.KERNEL_NAME) == 1
+
+
+@pytest.mark.parametrize("slots", [16, 64], ids=["mistral_16", "nemotron_64"])
+def test_sampler_compiles_without_a_vocabulary_wide_gather(
+        no_persistent_cache, one_chip, slots):
+    """``engine._sample_rows`` at the two serving cells' sizes. Of
+    ``argsort`` + ``take_along_axis`` the chip's compiler made the sorted
+    probabilities a gather of one element an index, the ``kCustom
+    f32[slots x V]`` fusion that took 7.4 and 21.4 ms a step (PERF.md,
+    PR 31); the sort returns them now, and the one gather left picks one
+    index a row."""
+    from paddle_tpu.serving.engine import _sample_rows
+    V = 32768
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    text = _compile(_sample_rows, S((slots, V), BF16), S((slots,), F32),
+                    S((slots,), F32), S((slots,), jnp.bool_),
+                    S((slots,), I32), S((slots,), I32))
+    gathers = re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
+    assert gathers == [f"s32[{slots}]"]
+    assert f"f32[{slots * V}]" not in text
+    assert len(re.findall(r" sort\(", text)) == 1
+    assert not re.search(r" scatter\(", text)
