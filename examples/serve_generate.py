@@ -121,8 +121,8 @@ def main():
     # decode matmuls to int8 weights dequantized in the matmul epilogue
     # (SERVING.md "Quantized KV & weights"). Greedy tokens match the fp
     # cache on this workload — the error model bounds per-element dequant
-    # error at scale/2, and the A/B harness (tools/profile_serving.py
-    # --kv-int8) checks >=99% token agreement on bigger traces.
+    # error at scale/2, and tests/test_serving_quant.py holds >=99%
+    # token agreement (test_teacher_forced_decisive_agreement_vs_fp_cache).
     from paddle_tpu.quantization import quantize_for_serving, \
         serving_state_bytes
     eng3 = ServingEngine(model, num_pages=64, page_size=4, max_slots=4,
@@ -171,8 +171,7 @@ def main():
 
     # HostTier(max_bytes=...) bounds the host pool; Workload/make_workload
     # (paddle_tpu.serving.workload) builds the seeded Poisson multi-tenant
-    # traces the bench + profiler replay against it — see
-    # tools/profile_serving.py --tiered and bench.py llama_serving_tiered
+    # traces that tests/test_serving_tiering.py replays against it
     _ = HostTier
 
 

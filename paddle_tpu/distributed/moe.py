@@ -79,7 +79,7 @@ def _fcfs_cumsum(mask, block: int = 512):
 
     Why: ``jnp.cumsum`` over T=8k tokens lowers to a log-depth chain of
     ~13 dependent kernels over [T, E] — latency-bound, ~1 ms per cumsum
-    on a v5e (PROFILE_qwen2_moe.md names routing as the MoE block's top
+    on a v5e (a July profile named routing as the MoE block's top
     sink). One [B, B] @ [B, E] matmul per block does the same work in a
     single MXU pass. Exact: 0/1 values, block sums <= block <= 512, fp32
     accumulation — integer-exact far beyond these counts."""

@@ -58,8 +58,8 @@ class FlightRecorder:
         return list(self._ring)
 
     def histogram(self) -> dict[str, int]:
-        """Event-name counts over the ring — the one-line summary the
-        profile_serving --flight-recorder playbook prints."""
+        """Event-name counts over the ring — the one-line summary at the
+        head of a post-mortem (OBSERVABILITY.md "Flight recorder")."""
         h: collections.Counter = collections.Counter(
             ev["name"] for ev in self._ring)
         return dict(sorted(h.items(), key=lambda kv: (-kv[1], kv[0])))

@@ -3,7 +3,7 @@ in place (parity: the reference's cutlass grouped GEMM,
 ``fusion/cutlass/moe/`` — routing + dispatch fused into kernels whose
 expert GEMMs read dispatched tokens directly).
 
-Why this kernel exists (PROFILE_qwen2_moe.md round-5 addendum): after the
+Why this kernel exists (the round-5 profile of July): after the
 gating chain was exonerated by the round-5 A/B, the sparse block's residual
 sink is the `[E, capacity, h]` packed buffer the grouped path materializes
 on BOTH sides of the expert FFN (pack-gather -> batched GEMMs ->

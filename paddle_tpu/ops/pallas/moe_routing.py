@@ -6,7 +6,7 @@ round-5 A/B showed the in-situ routing cost is too small (~0.1-0.2 ms) to
 justify an independent switch, so it rides with the dispatch that needs
 its sparse outputs anyway.
 
-PROFILE_qwen2_moe.md (round 5) named routing/gating as a suspected sink:
+The round-5 profile of July named routing/gating as a suspected sink:
 the XLA lowering of ``_top2_parts`` is ~30 small serially-dependent
 kernels over a [T, E] logits tile (softmax, two argmaxes, one-hots,
 position cumsums, renorm) — latency-bound on the VPU, ~1.2 ms forward at

@@ -183,6 +183,6 @@ def serving_state_bytes(model: Layer) -> int:
     (the numerator of the weights-only MBU): sum of ``nbytes`` over the
     full serving state. For a :func:`quantize_for_serving` model this
     counts 1 byte per int8 weight element plus the fp32 scale vectors —
-    the *necessary* bytes bench.py's int8 configs score MBU against."""
+    the *necessary* bytes a weights-only MBU is scored against."""
     state = model.state_dict(include_non_persistable_buffer=True)
     return int(sum(int(v.nbytes) for v in state.values()))

@@ -9,7 +9,7 @@ step, queue-wait percentiles (arrival -> first admission), and the
 failure-outcome counters of the robustness layer (rejects, timeouts,
 quarantines, preemption-limit kills, drain evictions — see the
 "Serving failure modes" table in SERVING.md). The clock is injectable
-so tests (and ``bench.py --dry``) can feed a deterministic virtual
+so tests can feed a deterministic virtual
 time; deadline enforcement in the engine runs on this same clock, and a
 ``Tracer`` (paddle_tpu.observability) constructed on the same clock
 puts spans and percentiles in one timebase.
@@ -650,8 +650,8 @@ class ServingMetrics:
 
     def spec_accept_histogram(self) -> dict[int, dict]:
         """Accept stats keyed by draft length: {n_draft: {"steps",
-        "accepted_mean", "accept_rate"}} — the profiler's per-length
-        report (tools/profile_serving.py --spec)."""
+        "accepted_mean", "accept_rate"}} — the per-length report
+        (tests/test_serving_spec.py::TestSpecObservability)."""
         out = {}
         for n, (acc, steps) in sorted(self._spec_hist.items()):
             out[n] = {"steps": steps,

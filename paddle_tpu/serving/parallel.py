@@ -38,7 +38,8 @@ and the shared GQA decode core all run per-shard unchanged (the GQA ratio
 transformer block issues exactly ONE psum (after o_proj / down_proj), the
 vocab-parallel embedding one psum, and the vocab-sharded logits one
 all_gather — nothing ever gathers the KV pool
-(``tools/profile_serving.py --tp`` asserts these counts on the jaxpr).
+(``tests/test_serving_tp.py::TestTPPrograms`` asserts these counts on the
+jaxpr).
 
 Because pool arrays and weights stay GLOBAL logical ``jax.Array``s with a
 ``NamedSharding`` (sharding is a layout property, not a shape change),
@@ -458,7 +459,7 @@ def _subjaxprs(v):
 def collective_counts(fn, *args) -> dict[str, int]:
     """Trace ``fn(*args)`` and count collective primitives, recursing into
     sub-jaxprs (shard_map/pjit/scan bodies). The TP contract audited by
-    ``tools/profile_serving.py --tp``: a step program carries exactly
+    ``tests/test_serving_tp.py``: a step program carries exactly
     ``2 * num_layers + 1`` psums (one per attention block, one per MLP
     block, one for the vocab-parallel embedding) and exactly 1 all_gather
     (the vocab-sharded logits) — never an all_gather of the KV pool.
@@ -466,7 +467,7 @@ def collective_counts(fn, *args) -> dict[str, int]:
     Beside the plain per-primitive STATIC counts (``psum``, ``ppermute``,
     … — occurrences in the traced program, the original report), the dict
     carries two derived families the pp audit
-    (``tools/profile_serving.py --pp``) pins:
+    (``tests/test_serving_pp.py``) pins:
 
     - ``"<prim>[<axis>]"`` — static count split by mesh axis, so the TP
       budget and the pipeline ring are separable: a pp×mp step shows

@@ -354,10 +354,9 @@ def heavy_tail_workload(seed: int = 0, n_requests: int = 24,
     with short decode-heavy traffic on small shared system prompts.
     Without chunking, each long prompt monopolizes an entire step and
     every decoding slot's inter-token latency eats the full prefill;
-    with chunking the prompt streams through in budget-sized bites —
-    this trace is what ``bench.py llama_serving_chunked`` and
-    ``tools/profile_serving.py --chunked`` A/B over. Deterministic in
-    ``seed``; any :class:`WorkloadSpec` field can be overridden."""
+    with chunking the prompt streams through in budget-sized bites
+    (``tests/test_serving_chunked.py::TestHeavyTailWorkload`` replays
+    it). Deterministic in ``seed``; any :class:`WorkloadSpec` field can be overridden."""
     kw: dict = dict(seed=seed, n_requests=n_requests,
                     arrival="poisson", rate=0.75,
                     tenants=2, zipf_alpha=1.2, system_len=(8, 16),
@@ -382,10 +381,9 @@ def long_prompt_workload(seed: int = 0, n_requests: int = 16,
     ``prompt_scale`` is the 10x knob: it shifts the lognormal mu by
     ``ln(prompt_scale)`` and scales the clip range, so
     ``prompt_scale=10`` makes the same trace's prompts ~10x longer
-    while arrivals, tenants and decode lengths stay fixed —
-    ``bench.py llama_serving_disagg`` and ``tools/profile_serving.py
-    --disagg`` sweep this knob to show colocated ITL degrading while
-    the disaggregated arm stays flat. Deterministic in ``seed``; any
+    while arrivals, tenants and decode lengths stay fixed: the knob
+    a colocated-against-disaggregated comparison sweeps (no benchmark
+    cell does yet; speed not measured). Deterministic in ``seed``; any
     :class:`WorkloadSpec` field can be overridden."""
     scale = float(prompt_scale)
     if scale <= 0.0:
@@ -414,7 +412,8 @@ def overload_workload(seed: int = 0, n_requests: int = 48,
     arrivals overflow the queue during on-phases so admission quotas,
     fair scheduling and the brownout ladder all engage; FCFS collapses
     the cold tenants' TTFT on this trace, which is exactly what
-    ``bench.py llama_serving_fairness`` A/Bs. Deadlines default OFF
+    ``tests/test_serving_fairness.py::TestOverloadAcceptance`` compares
+    against fairness with brownout. Deadlines default OFF
     (pass ``tenant_deadlines=...`` to exercise infeasibility shedding
     on a virtual clock). Deterministic in ``seed``; any
     :class:`WorkloadSpec` field can be overridden."""

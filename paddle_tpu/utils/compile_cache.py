@@ -1,5 +1,5 @@
 """Persistent XLA compilation cache for the scripts that run on the chip
-(``chip_smoke.py``, ``bench.py``, ``tools/run_tpu_checks.py``).
+(``chip_smoke.py``, ``benchmarks/run.py``, ``tools/run_tpu_checks.py``).
 
 A chip run starts on a fresh machine and a cold llama-8B-width step
 program is tens of seconds of compiling; processes of one run, and runs

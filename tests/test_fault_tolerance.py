@@ -298,7 +298,7 @@ def test_preemption_guard_drains_and_checkpoints(tmp_path):
 # --------------------------------------------------------------------------
 
 _CHAOS_WORKER = """
-    import os, sys
+    import os, sys, time
     sys.path.insert(0, {repo!r})
     import jax; jax.config.update("jax_platforms", "cpu")
     import numpy as np
@@ -333,6 +333,17 @@ _CHAOS_WORKER = """
                   "w") as f:
             f.write(os.path.basename(latest))
     step._host_step = start  # RNG/lr streams continue from the true step
+    # The order the test's last assertion depends on, made explicit: rank
+    # 1's epoch-0 life (nothing to resume from, all 8 losses logged) is
+    # over before rank 0 commits a checkpoint, let alone dies at step 4
+    # and takes the gang down. Left to the scheduler, a late rank 1
+    # resumed from step_0 or was terminated by the launcher mid-run.
+    rank1_done = os.path.join(log_dir, "done_e0.r1")
+    if (rank, epoch) == (0, 0) and os.environ.get("PADDLE_TRAINERS_NUM") == "2":
+        deadline = time.monotonic() + 240
+        while not os.path.exists(rank1_done):
+            assert time.monotonic() < deadline, "rank 1 never finished"
+            time.sleep(0.05)
     handles = {{}}
     for i in range(start, 8):
         if i - 2 in handles:  # commit horizon: step i-2 must be durable
@@ -348,6 +359,8 @@ _CHAOS_WORKER = """
                 async_save=True, async_timeout=120)
     for h in handles.values():
         h.result(timeout=120)
+    if (rank, epoch) == (1, 0):
+        open(rank1_done, "w").close()
 """
 
 

@@ -386,3 +386,50 @@ class TestGoodput:
         assert s["goodput_at_slo"] == pytest.approx(2 / 4.0)  # no SLO set
         m.set_slo(ttft_p99_s=1.0, itl_p99_s=0.25)
         assert m.summary()["goodput_at_slo"] == pytest.approx(1 / 4.0)
+
+
+# ---------------------------------------------------------------------------
+# /metrics without an engine
+# ---------------------------------------------------------------------------
+
+def test_metrics_endpoint_serves_parseable_prometheus_text():
+    """Tier-1-safe /metrics smoke: a MetricsServer on an ephemeral port
+    fed by an explicit render callable (no engine, no jax) must serve
+    text every strict Prometheus parser accepts, plus /healthz JSON."""
+    import urllib.request
+
+    from paddle_tpu.observability import (MetricsServer, parse_prometheus,
+                                          render_prometheus)
+
+    text_src = render_prometheus(
+        {"tokens_per_s": 12.5, "ttft_p99_s": 0.25, "goodput_at_slo": 3.0,
+         "note": "non-numeric values are skipped"},
+        {"in_use": 7, "utilization": 0.5},
+        {"compiles": 2})
+    srv = MetricsServer(render=lambda: text_src,
+                        health=lambda: {"status": "ok"})
+    port = srv.start()
+    try:
+        assert port != 0
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/metrics", timeout=10) as r:
+            assert r.status == 200
+            assert r.headers["Content-Type"].startswith("text/plain")
+            body = r.read().decode()
+        metrics = parse_prometheus(body)  # raises on any malformed line
+        assert metrics["paddle_serving_tokens_per_seconds"] == 12.5
+        assert metrics["paddle_serving_ttft_p99_seconds"] == 0.25
+        assert metrics["paddle_serving_goodput_at_slo"] == 3.0
+        assert metrics["paddle_serving_pool_in_use"] == 7
+        assert metrics["paddle_serving_trace_compiles_total"] == 2
+        assert "paddle_serving_note" not in metrics
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/healthz", timeout=10) as r:
+            assert json.loads(r.read().decode()) == {"status": "ok"}
+        with urllib.request.urlopen(
+                f"http://127.0.0.1:{port}/nope", timeout=10) as r:
+            raise AssertionError("unknown path must 404")
+    except urllib.error.HTTPError as e:
+        assert e.code == 404
+    finally:
+        srv.stop()

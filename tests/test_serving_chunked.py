@@ -158,7 +158,6 @@ class TestChunkedParity:
             outs.append(eng.run_to_completion(max_steps=200)[rid])
         assert outs[0] == outs[1]
 
-    @pytest.mark.slow
     def test_unchunked_arm_matches_chunked_arm(self, model, refs):
         outs = []
         for chunked in (False, True):

@@ -562,8 +562,8 @@ def _mk_engine(model, recorder=None, **kw):
     return ServingEngine(model, flight_recorder=recorder, **cfg)
 
 
-@pytest.mark.slow
 class TestFleetRealModel:
+    @pytest.mark.slow
     def test_kill_mid_stream_bitwise_parity(self, model, fault_free):
         prompts = [RNG.integers(1, 500, size=int(n)).tolist()
                    for n in (5, 9, 7, 12)]
@@ -581,6 +581,7 @@ class TestFleetRealModel:
                 assert router.engines[h["replica"]] \
                     .decode_program_count() == 1
 
+    @pytest.mark.slow
     def test_kill_at_every_k_real_engine(self, model, fault_free):
         """Real-engine version of the property sweep (short stream)."""
         prompt = RNG.integers(1, 500, size=6).tolist()

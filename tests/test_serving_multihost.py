@@ -289,6 +289,9 @@ class TestSocketFleet:
             assert st["socket_frames_sent"] > 0
             assert st["socket_bytes_recv"] > 0
             assert fleet.rt.counters["corrupt_dropped"] == 0
+            # socket latency expires no lease on a healthy wire
+            assert fleet.router.fleet_metrics.counters[
+                "lease_expirations"] == 0
             # same streams the default loopback fleet produces
             router = FleetRouter([FakeEngine(), FakeEngine()])
             lrids = [router.submit(list(p), max_new) for p in prompts]

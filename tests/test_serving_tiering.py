@@ -527,9 +527,11 @@ class TestTieredEngine:
     def test_int8_tier_on_equals_tier_off_bitwise(self, model, fault_free):
         """Quantized KV: codes AND scales round-trip the host tier, so
         the tiered int8 engine matches the untiered one token-for-token
-        while actually restoring pages."""
+        while actually restoring pages; and under this forced eviction
+        the hit rate is strictly higher with the tier (an eviction is a
+        demotion, not a loss)."""
         prompts = _tenant_prompts(4, system_len=16, suffix_len=4)
-        outs = []
+        outs, hit_rates = [], []
         for tier in (None, HostTier()):
             eng = ServingEngine(model, num_pages=10, page_size=4,
                                 max_slots=1, kv_quant=True, host_tier=tier)
@@ -539,7 +541,9 @@ class TestTieredEngine:
                 got.append(eng.run_to_completion(max_steps=100)[rid])
             assert eng.decode_program_count() == 1
             outs.append(got)
+            hit_rates.append(eng.metrics.summary()["cache_hit_rate"])
         assert outs[0] == outs[1]
+        assert hit_rates[1] > hit_rates[0]
         assert eng.pool.host_tier.counters["restored_pages"] > 0
         assert eng.pool._tier_tag == "int8"
 
