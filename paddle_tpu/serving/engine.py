@@ -1269,6 +1269,9 @@ class ServingEngine:
             self._call_step(self._decode_step, self._warm_lanes("decode"))
         if mixed:
             self._call_step(self._mixed_step, self._warm_lanes("mixed"))
+        # the pool's one compiled writer: the first eviction of a
+        # serving window must not compile either
+        self.pool.warm_scrub()
         self._note_retraces()
 
     def _warm_lanes(self, program: str) -> tuple:
@@ -1301,8 +1304,10 @@ class ServingEngine:
         dispatch uses, without running them:
         ``{"decode": Lowered, "mixed": Lowered}``. ``.compile()`` gives
         ``as_text()`` (is the paged-attention kernel in the decode step)
-        and ``memory_analysis()`` (does the step fit beside the pool).
-        ``step_program_counts()`` is not changed by it."""
+        and ``memory_analysis()`` (does the step fit beside the pool; is
+        the donated pool aliased to its result: ``alias_size_in_bytes``).
+        Nothing is consumed and ``step_program_counts()`` is not changed
+        by it."""
         return {"decode": self._decode_step.lower(*self._warm_args("decode")),
                 "mixed": self._mixed_step.lower(*self._warm_args("mixed"))}
 
@@ -1587,11 +1592,14 @@ class ServingEngine:
                 nt = _sample_rows(last, temps, top_ps, greedy, seeds, counts)
             return nt, ok, pools
 
+        # every body donates the page pairs: the scatter of this step's
+        # rows updates the arrays it was given, and ``_call_step`` puts
+        # the returned ones in their place
         if self._recurrent:
             # the same body with the per-slot state threaded beside the
-            # pages and DONATED (the step rewrites every slot's row, so
-            # the new state takes the old one's memory); the model's
-            # own counters ride back with it
+            # pages and donated with them (the step rewrites every
+            # slot's row, so the new state takes the old one's memory);
+            # the model's own counters ride back with it
             def decode_step_state(state, pools, rstate, tok, tables,
                                   seq_lens, active, temps, top_ps, greedy,
                                   seeds, counts):
@@ -1599,9 +1607,9 @@ class ServingEngine:
                     state, HybridCache(pools, rstate), tok, tables,
                     seq_lens, active, temps, top_ps, greedy, seeds, counts)
                 return nt, ok, cache.kv, cache.state, cache.counts
-            return jax.jit(decode_step_state, donate_argnums=(2,))
+            return jax.jit(decode_step_state, donate_argnums=(1, 2))
         if self._tp is None:
-            return jax.jit(decode_step)
+            return jax.jit(decode_step, donate_argnums=(1,))
         tp = self._tp
         if tp.pp > 1:
             # pp>1: the forward routes through the staged pipeline ring
@@ -1696,9 +1704,9 @@ class ServingEngine:
                     n_live, forced, temps, top_ps, greedy, seeds, counts,
                     R, ps, False)
                 return samp, m, ok, kv, cache.state, cache.counts
-            return jax.jit(mixed_step_state, donate_argnums=(2,))
+            return jax.jit(mixed_step_state, donate_argnums=(1, 2))
         if self._tp is None:
-            return jax.jit(mixed_step)
+            return jax.jit(mixed_step, donate_argnums=(1,))
         tp = self._tp
         if tp.pp > 1:
             # pp>1: the forward is the microbatched pipeline ring — the
@@ -2187,10 +2195,12 @@ class ServingEngine:
                     self._emit(req, t, events)
 
     def _call_step(self, step, lanes) -> list:
-        """Run one step program over ``lanes``. What it returns of the
-        pool (the page pairs and, for a model with recurrent state, the
-        state, whose old arrays the program was given to reuse) replaces
-        the pool's arrays; the rest is handed back."""
+        """Run one step program over ``lanes``: the only place that
+        calls one. Every body donates the page pairs (and, for a model
+        with recurrent state, the state): the program writes this step's
+        rows into the arrays it was given, which are deleted when the
+        call returns. What it returns of the pool replaces the pool's
+        arrays; the rest is handed back."""
         pool = self.pool
         if not self._recurrent:
             *out, pool.pools = step(self._state, pool.pools, *lanes)

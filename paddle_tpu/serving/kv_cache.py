@@ -29,8 +29,9 @@ contracts, SERVING.md):
 - the device arrays are allocated ONCE at pool construction and only
   ever updated functionally inside the compiled prefill/decode programs
   — alloc/free/match move host-side integers, never device memory
-  (the two exceptions, ``cow_into`` and scrub-on-evict, are single
-  functional ``.at[]`` updates);
+  (the two exceptions: ``cow_into``, a functional ``.at[]`` update,
+  and scrub-on-evict, one compiled program that zeroes the pages in
+  the donated arrays);
 - page 0 is reserved as the scratch page: never handed out, used as the
   write/gather target for inactive slots and padded block-table entries
   (always masked by seq_lens, so its garbage is never read into a
@@ -47,6 +48,7 @@ contracts, SERVING.md):
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import math
 import struct
@@ -111,6 +113,20 @@ def _page_zero(arr, idx, stacked: bool = False):
     if stacked:
         return arr.at[:, idx].set(0)
     return arr.at[idx].set(0)
+
+
+# pages one call of the compiled scrub zeroes; a longer list takes
+# several calls, a shorter one repeats its first page (one shape, so
+# one program, compiled when the engine warms its step programs)
+_SCRUB_WIDTH = 16
+
+
+@functools.partial(jax.jit, static_argnums=(2,), donate_argnums=(0,))
+def _scrub_in_place(pools, idx, stacked):
+    """Zero pages ``idx`` of every pool array in the arrays themselves:
+    the pool is donated, as the step programs take it."""
+    return [tuple(_page_zero(a, idx, stacked) for a in pair)
+            for pair in pools]
 
 
 def _page_hash(parent: bytes, tokens) -> bytes:
@@ -218,8 +234,11 @@ class KVCachePool:
             if sharding is None:
                 return z
             return jax.device_put(z, sharding[1] if scale else sharding[0])
-        # per-layer (pool_k, pool_v); functionally replaced by the compiled
-        # programs each step, so the handles here always name the latest.
+        # per-layer (pool_k, pool_v). A step program is given these
+        # arrays to write in place (donated: deleted when it returns) and
+        # its result replaces them, so the handles here always name the
+        # latest; read a page through the pool, never keep an array
+        # across a step.
         # Quantized mode stores int8 codes + one fp32 absmax scale per
         # [page, slot, kv_head] row (see quantization/serving.py).
         if quantized:
@@ -825,20 +844,14 @@ class KVCachePool:
                             jnp.asarray(next(it), arr.dtype))
             self.pools = [tuple(pair)]
             return
-        new_pools = []
-        for pk, pv in self.pools:
-            pair = []
-            for arr in (pk, pv):
-                if isinstance(arr, QuantizedKV):
-                    q = jnp.asarray(next(it), arr.q.dtype)
-                    s = jnp.asarray(next(it), arr.scale.dtype)
-                    pair.append(QuantizedKV(arr.q.at[page].set(q),
-                                            arr.scale.at[page].set(s)))
-                else:
-                    pair.append(arr.at[page].set(
-                        jnp.asarray(next(it), arr.dtype)))
-            new_pools.append(tuple(pair))
-        self.pools = new_pools
+        def put(arr):
+            if isinstance(arr, QuantizedKV):
+                q = jnp.asarray(next(it), arr.q.dtype)
+                s = jnp.asarray(next(it), arr.scale.dtype)
+                return QuantizedKV(arr.q.at[page].set(q),
+                                   arr.scale.at[page].set(s))
+            return arr.at[page].set(jnp.asarray(next(it), arr.dtype))
+        self._rewrite(put)
 
     def restore_charge(self, m: PrefixMatch | None) -> int:
         """Prefill-budget tokens the match's host-resolved tokens would
@@ -998,26 +1011,43 @@ class KVCachePool:
 
     # ---- device-side page ops ----
 
+    def _rewrite(self, fn) -> None:
+        """Replace every pool array by ``fn`` of it, one pair at a time.
+        For the writers that stay eager (``cow_into``, ``rewind``, a
+        host page's restore): each call builds a NEW array from the
+        current one, so a whole new list built beside the old one would
+        hold two pools at once, the memory the step programs no longer
+        take since they write in place."""
+        for i, pair in enumerate(self.pools):
+            self.pools[i] = tuple(fn(a) for a in pair)
+
     def cow_into(self, src: int, dst: int) -> None:
         """Copy-on-write materialization: device-copy page ``src`` into
         the freshly-allocated page ``dst``. The cached source is never
         written in place — the hitter extends its own copy."""
-        self.pools = [(_page_copy(pk, src, dst, self.stacked),
-                       _page_copy(pv, src, dst, self.stacked))
-                      for pk, pv in self.pools]
+        self._rewrite(lambda a: _page_copy(a, src, dst, self.stacked))
         self.counters["prefix_cow_copies"] += 1
         self.tracer.instant("cow_copy", track="pool", src=src, dst=dst)
 
     def scrub(self, pages: list[int]) -> None:
         """Zero pages (eviction / quarantine): restores the
-        masked-garbage-is-zero invariant before reuse."""
-        if not pages:
-            return
-        idx = jnp.asarray(sorted(set(pages)), jnp.int32)
-        self.pools = [(_page_zero(pk, idx, self.stacked),
-                       _page_zero(pv, idx, self.stacked))
-                      for pk, pv in self.pools]
-        self._scrubbed.update(int(p) for p in pages)
+        masked-garbage-is-zero invariant before reuse. One compiled
+        program of one shape that writes the donated pool in place: an
+        eviction inside a serving window costs a dispatch, neither a
+        compile nor a copy of the pool."""
+        pages = sorted(set(int(p) for p in pages))
+        for i in range(0, len(pages), _SCRUB_WIDTH):
+            part = pages[i:i + _SCRUB_WIDTH]
+            idx = np.asarray(part + part[:1] * (_SCRUB_WIDTH - len(part)),
+                             np.int32)
+            self.pools = _scrub_in_place(self.pools, idx, self.stacked)
+        self._scrubbed.update(pages)
+
+    def warm_scrub(self) -> None:
+        """Compile the scrub beside the step programs (``ServingEngine.
+        warm_programs``) by zeroing the reserved scratch page 0."""
+        self.pools = _scrub_in_place(
+            self.pools, np.zeros(_SCRUB_WIDTH, np.int32), self.stacked)
 
     def rewind(self, pages: list[int], start: int, stop: int) -> None:
         """Zero cache POSITIONS ``[start, stop)`` of a request's block
@@ -1038,9 +1068,7 @@ class KVCachePool:
         pg = jnp.asarray([pages[p // ps] for p in range(start, stop)],
                          jnp.int32)
         off = jnp.asarray([p % ps for p in range(start, stop)], jnp.int32)
-        self.pools = [(self._pos_zero(pk, pg, off, self.stacked),
-                       self._pos_zero(pv, pg, off, self.stacked))
-                      for pk, pv in self.pools]
+        self._rewrite(lambda a: self._pos_zero(a, pg, off, self.stacked))
         self.counters["rewound_tokens"] += stop - start
 
     @staticmethod

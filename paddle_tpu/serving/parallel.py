@@ -421,8 +421,10 @@ class TPContext:
         identically on every shard from the all-gathered logits, which is
         what keeps sampling and the fold_in contract single-program.
         ``check_vma=False`` skips the replication proof for exactly those
-        outputs. The un-jitted shard_map callable is kept on the returned
-        function as ``_tp_inner`` so the collective-count report
+        outputs. The pools are donated, as in the single-chip bodies:
+        each shard writes into the arrays it was given. The un-jitted
+        shard_map callable is kept on the returned function as
+        ``_tp_inner`` so the collective-count report
         (:func:`collective_counts`) can trace it."""
         ax = self.axis
 
@@ -435,7 +437,7 @@ class TPContext:
         out_specs = (*([P()] * n_lead), self.pool_specs(pools))
         inner = shard_map(body, mesh=self.mesh, in_specs=in_specs,
                           out_specs=out_specs, check_vma=False)
-        step = jax.jit(inner)
+        step = jax.jit(inner, donate_argnums=(1,))
         step._tp_inner = inner
         return step
 

@@ -522,7 +522,7 @@ def row_engines(model):
 
 
 def _rows_shape(eng, k, refs, monkeypatch):
-    samp, m, ok, _ = eng._mixed_step(*eng._warm_args("mixed"))
+    samp, m, ok = eng._call_step(eng._mixed_step, eng._warm_lanes("mixed"))
     assert samp.shape == (ROWS_SLOTS, k)
     assert m.shape == ok.shape == (ROWS_SLOTS,)
 

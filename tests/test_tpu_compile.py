@@ -228,3 +228,78 @@ def test_sampler_compiles_without_a_vocabulary_wide_gather(
     assert f"f32[{slots * V}]" not in text
     assert len(re.findall(r" sort\(", text)) == 1
     assert not re.search(r" scatter\(", text)
+
+
+@pytest.mark.parametrize("rows", [1, 64], ids=["decode", "mixed"])
+def test_a_donated_pool_is_written_in_place(mosaic, monkeypatch, one_chip,
+                                            rows):
+    """The cache path of a step program over four layers at the pool
+    shape of ``mistral_7b_serve`` (16 slots, tables ``[16, 129]``, pool
+    ``bf16[2065,16,8,128]``), jitted with the pool donated as
+    ``ServingEngine`` does: ``paged_attention_write_attend`` (one row a
+    slot and the kernel; 64 rows, the XLA gather and the rollback
+    scatter of ``_mixed_tail``). Undonated, the decode form made nine
+    copies of a layer's whole pool and the mixed form four asynchronous
+    ones (3.5 s of a 51 s window on the chip: PERF.md, PR 34); donated,
+    every pool array is aliased to its result and none is copied."""
+    from paddle_tpu.nn.functional import attention
+    from paddle_tpu.serving.kv_cache import KVCachePool
+    monkeypatch.setattr(attention, "_flash_backend_ok", lambda: True)
+    layers, slots, heads, kv_heads, d = 4, 16, 32, 8, 128
+    pages, page, table = 2065, 16, 129
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(pools, q, k, v, tables, lens, active, n_live, m):
+        cols = jnp.arange(rows)[None, :]
+        pos = lens[:, None] + cols
+        new = []
+        for pair in pools:
+            q, pair = attention.paged_attention_write_attend(
+                q, k, v, pair, tables, lens, pos, active,
+                n_live if rows > 1 else None)
+            new.append(pair)
+        if rows > 1:
+            rej = (cols < n_live[:, None]) & (cols > m[:, None])
+            at = jnp.take_along_axis(tables, pos // page, axis=1)
+            at, off = jnp.where(rej, at, 0), jnp.where(rej, pos % page, 0)
+            new = [tuple(KVCachePool._pos_zero(a, at, off) for a in pair)
+                   for pair in new]
+        return q, new
+
+    pool = S((pages, page, kv_heads, d), BF16)
+    kv = S((slots, rows, kv_heads, d), BF16)
+    lane = S((slots,), I32)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        [(pool, pool)] * layers, S((slots, rows, heads, d), BF16), kv, kv,
+        S((slots, table), I32), lane, S((slots,), jnp.bool_), lane,
+        lane).compile()
+    text = compiled.as_text()
+    assert _kernels(text, paged_attention.KERNEL_NAME) == (
+        layers if rows == 1 else 0)
+    whole = re.escape(f"bf16[{pages},{page},{kv_heads},{d}]")
+    assert not re.findall(
+        rf"= \(?{whole}[^=]*? (?:copy|copy-start|slice-start)\(", text)
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            == 2 * layers * pages * page * kv_heads * d * 2)
+
+
+def test_the_scrub_zeroes_pages_of_a_donated_pool_in_place(one_chip):
+    """``KVCachePool.scrub``'s one program at the pool shape of
+    ``mistral_7b_serve`` over four layers: every pool array is aliased
+    to its result and none is copied (eager, each eviction copied all
+    32 arrays and cost 76 ms of a serving window: PERF.md, PR 34)."""
+    from paddle_tpu.serving import kv_cache
+    layers, shape = 4, (2065, 16, 8, 128)
+    pool = jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((kv_cache._SCRUB_WIDTH,), I32,
+                               sharding=one_chip)
+    compiled = kv_cache._scrub_in_place.lower(
+        [(pool, pool)] * layers, idx, False).compile()
+    whole = re.escape("bf16[%d,%d,%d,%d]" % shape)
+    assert not re.findall(
+        rf"= \(?{whole}[^=]*? (?:copy|copy-start|slice-start)\(",
+        compiled.as_text())
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            == 2 * layers * 2 * int(np.prod(shape)))
