@@ -832,16 +832,21 @@ def moe_held_tiles_compute(x, local, w, w_in, w_gate, w_out, activation,
 
 
 class HeldExpertsMoE(Layer):
-    """One chip's share of a routed-expert layer with a latent expert
-    width (LatentMoE) and a shared expert, for SERVING: it is told which
-    experts it holds (``experts_held = (first, count)``), keeps ``w_in``
-    / ``w_out`` for those only, routes every row over ALL
-    ``num_experts`` (sigmoid scores, a correction bias that chooses but
-    does not weigh, top-k, weights normalised over the chosen and
-    scaled), and returns the held experts' part of the routed sum plus
-    the shared expert. What the absent experts would add is left out:
+    """One chip's share of a routed-expert layer and a shared expert,
+    for SERVING: it is told which experts it holds (``experts_held =
+    (first, count)``), keeps ``w_in`` / ``w_out`` (and ``w_gate``) for
+    those only, routes every row over ALL ``num_experts`` (sigmoid
+    scores, with ``score_bias`` a correction bias that chooses but does
+    not weigh, top-k, weights normalised over the chosen and scaled),
+    and returns the held experts' part of the routed sum plus the
+    shared expert. What the absent experts would add is left out:
     summed over the chips that share the layer, the parts are the whole
     routed sum. No row is ever dropped (no capacity).
+
+    The experts work in a latent width ``d_latent`` between two
+    projections (LatentMoE), or with ``d_latent=None`` on ``d_model``
+    itself; ``shared_gated`` gives the shared expert a gate,
+    ``act(x G) * (x U)``, as ``gated`` gives the routed ones.
 
     ``forward(x, live)`` -> ``(out, counts)``; ``live`` [rows] marks the
     rows that count (dead rows get the shared expert only), ``counts``
@@ -854,7 +859,8 @@ class HeldExpertsMoE(Layer):
     def __init__(self, d_model, d_latent, d_hidden, num_experts, top_k,
                  experts_held=None, d_shared=0, activation=relu2,
                  gated=False, norm_topk_prob=True,
-                 routed_scaling_factor=1.0, tile_rows: int = 64):
+                 routed_scaling_factor=1.0, tile_rows: int = 64,
+                 shared_gated=False, score_bias=True):
         super().__init__()
         first, count = experts_held or (0, num_experts)
         if not (0 <= first and count >= 1
@@ -868,16 +874,24 @@ class HeldExpertsMoE(Layer):
         self.activation = activation
         self.tile_rows = int(tile_rows)
         self.gate = nn.Linear(d_model, num_experts, bias_attr=False)
-        self.e_score_correction_bias = Parameter(
+        self.e_score_correction_bias = (Parameter(
             I.Constant(0.0)((num_experts,), jnp.float32), trainable=False)
-        self.fc1_latent_proj = nn.Linear(d_model, d_latent, bias_attr=False)
-        self.fc2_latent_proj = nn.Linear(d_latent, d_model, bias_attr=False)
-        self.experts = ExpertFFN(count, d_latent, d_hidden,
+            if score_bias else None)
+        self.fc1_latent_proj = self.fc2_latent_proj = None
+        if d_latent is not None:
+            self.fc1_latent_proj = nn.Linear(d_model, d_latent,
+                                             bias_attr=False)
+            self.fc2_latent_proj = nn.Linear(d_latent, d_model,
+                                             bias_attr=False)
+        self.experts = ExpertFFN(count, d_latent or d_model, d_hidden,
                                  activation=activation, ep_axis=None,
                                  gated=gated)
-        self.shared_up = self.shared_down = None
+        self.shared_up = self.shared_gate = self.shared_down = None
         if d_shared:
             self.shared_up = nn.Linear(d_model, d_shared, bias_attr=False)
+            if shared_gated:
+                self.shared_gate = nn.Linear(d_model, d_shared,
+                                             bias_attr=False)
             self.shared_down = nn.Linear(d_shared, d_model, bias_attr=False)
 
     def route(self, x):
@@ -887,8 +901,9 @@ class HeldExpertsMoE(Layer):
                          self.gate.weight.astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
         s = jax.nn.sigmoid(logits)
-        _, idx = jax.lax.top_k(
-            s + self.e_score_correction_bias.astype(jnp.float32), self.top_k)
+        chooses = (s if self.e_score_correction_bias is None else
+                   s + self.e_score_correction_bias.astype(jnp.float32))
+        _, idx = jax.lax.top_k(chooses, self.top_k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         if self.norm_topk_prob:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
@@ -904,8 +919,10 @@ class HeldExpertsMoE(Layer):
         with jax.named_scope("router"):
             idx, w = self.route(t)
             local, held = _held_assignments(idx, live, first, n_held)
-        with jax.named_scope("latent"):
-            lat = self.fc1_latent_proj(t)
+        lat = t
+        if self.fc1_latent_proj is not None:
+            with jax.named_scope("latent"):
+                lat = self.fc1_latent_proj(t)
         ex = self.experts
         with jax.named_scope("experts"):
             compute = (moe_held_dense_compute if T <= self.DENSE_ROWS
@@ -916,12 +933,17 @@ class HeldExpertsMoE(Layer):
                              self.activation)
             touched = jnp.sum(jnp.bincount(
                 local.reshape(-1), length=n_held + 1)[:n_held] > 0)
-        with jax.named_scope("latent"):
-            out = self.fc2_latent_proj(routed.astype(t.dtype))
+        out = routed.astype(t.dtype)
+        if self.fc2_latent_proj is not None:
+            with jax.named_scope("latent"):
+                out = self.fc2_latent_proj(out)
         if self.shared_up is not None:
             with jax.named_scope("shared"):
-                out = out + self.shared_down(
-                    self.activation(self.shared_up(t)))
+                h = (self.activation(self.shared_up(t))
+                     if self.shared_gate is None else
+                     self.activation(self.shared_gate(t))
+                     * self.shared_up(t))
+                out = out + self.shared_down(h)
         counts = jnp.stack([jnp.sum(live) * self.top_k, jnp.sum(held),
                             touched]).astype(jnp.int32)
         return out.reshape(shape), counts

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 
 __all__ = ["scaled_dot_product_attention", "flash_attention", "sdp_kernel",
            "paged_attention_decode", "cached_prefill_attention",
-           "paged_attention_write_attend"]
+           "paged_attention_write_attend", "paged_latent_attention_decode",
+           "paged_latent_write_attend"]
 
 # sdp_kernel override; None -> read FLAGS_flash_min_seq (default 256). The
 # Pallas kernel's block logic covers seq >= 256 (blocks halve to divide the
@@ -522,6 +523,18 @@ def paged_attention_decode(q, pool_k, pool_v, block_tables, seq_lens,
     return _grouped_decode_attn(q, kg, vg, seq_lens, scale)
 
 
+def _write_targets(block_tables, pos, active, n_live, page_size):
+    """Where a step's rows go in the pool: (page, offset) of each of
+    ``pos`` [b, s]; rows ``j >= n_live`` and the rows of inactive slots
+    go to the reserved scratch page 0."""
+    s = pos.shape[1]
+    live = active[:, None] & (jnp.arange(s)[None, :]
+                              < (n_live[:, None] if n_live is not None
+                                 else s))
+    page = jnp.take_along_axis(block_tables, pos // page_size, axis=1)
+    return jnp.where(live, page, 0), jnp.where(live, pos % page_size, 0)
+
+
 def paged_attention_write_attend(q, k, v, kv_cache, block_tables, seq_lens,
                                  pos, active, n_live=None, scale=None):
     """A paged attention layer's step: write this step's K/V rows into
@@ -536,15 +549,9 @@ def paged_attention_write_attend(q, k, v, kv_cache, block_tables, seq_lens,
     Rows ``j >= n_live`` and the rows of inactive slots write the
     reserved scratch page 0. Returns the attention output [b, s, h, d]
     and the new page pair."""
-    s = q.shape[1]
     pk, pv = kv_cache
-    ps = pk.shape[1]
-    live = active[:, None] & (jnp.arange(s)[None, :]
-                              < (n_live[:, None] if n_live is not None
-                                 else s))
-    page = jnp.take_along_axis(block_tables, pos // ps, axis=1)
-    page = jnp.where(live, page, 0)
-    off = jnp.where(live, pos % ps, 0)
+    page, off = _write_targets(block_tables, pos, active, n_live,
+                               pk.shape[1])
     from ...quantization.serving import QuantizedKV, kv_quantize
     if isinstance(pk, QuantizedKV):
         # int8 pool: quantize the step tokens at write time (codes
@@ -562,6 +569,97 @@ def paged_attention_write_attend(q, k, v, kv_cache, block_tables, seq_lens,
         out = paged_attention_decode(q, pk, pv, block_tables, seq_lens,
                                      scale=scale)
     return out, (pk, pv)
+
+
+# float32 scores the latent XLA path may hold at once: it walks the
+# query heads in blocks of this many bytes of scores
+_LATENT_SCORE_BYTES = 1 << 29
+
+
+def _latent_attend(q, rows, seq_lens, v_width, scale):
+    """Every query head against ONE shared row a key (multi-head latent
+    attention in the absorbed form), in plain XLA.
+
+    q [b, t, h, w]; rows [b, S, w], a slot's cached rows in order; row j
+    of a slot sits at position ``seq_lens + j`` and attends positions
+    ``<= seq_lens + j``. K is the row, V its first ``v_width`` columns.
+    The heads go in blocks (``lax.map``), so that scores for all heads,
+    rows and keys never exist at once; operands in the cache's dtype
+    with float32 accumulation, probabilities cast back to it, as
+    ``_grouped_decode_attn`` does. Returns float32 [b, t, h, v_width]."""
+    b, t, h, w = q.shape
+    S = rows.shape[1]
+    hb = max(1, min(h, _LATENT_SCORE_BYTES // (4 * b * t * S)))
+    while h % hb:
+        hb -= 1
+    limit = seq_lens[:, None] + jnp.arange(t)[None, :]            # [b, t]
+    mask = (jnp.arange(S)[None, None, None, :]
+            <= limit[:, :, None, None])                      # [b, t, 1, S]
+    v = rows[..., :v_width]
+
+    def block(qb):                                       # [b, t, hb, w]
+        sc = jnp.einsum("bthw,bsw->bths", qb, rows,
+                        preferred_element_type=jnp.float32) * scale
+        p = jax.nn.softmax(jnp.where(mask, sc, jnp.float32(-1e30)), axis=-1)
+        return jnp.einsum("bths,bsv->bthv", p.astype(rows.dtype), v,
+                          preferred_element_type=jnp.float32)
+
+    qb = jnp.moveaxis(q.astype(rows.dtype).reshape(b, t, h // hb, hb, w),
+                      2, 0)
+    out = jax.lax.map(block, qb)                   # [h / hb, b, t, hb, v]
+    return jnp.moveaxis(out, 0, 2).reshape(b, t, h, v_width)
+
+
+def paged_latent_attention_decode(q, pool, block_tables, seq_lens, v_width,
+                                  scale):
+    """Attention over a paged LATENT pool (``serving.kv_cache``: one
+    ``[num_pages, page_size, width]`` array a layer, a row a token), in
+    the absorbed form: q [b, t, h, width] is each head's query carried
+    into the row's space, K is the cached row and V its first
+    ``v_width`` columns, the same for all h heads. Row j of a slot
+    attends positions ``<= seq_lens + j``. Returns [b, t, h, v_width]
+    in q's dtype: the mix of cached rows, which the caller carries
+    through its value up-projection. The cache is never decompressed to
+    per-head K/V.
+
+    Routing as ``paged_attention_decode``: one row a slot on a TPU takes
+    the Pallas kernel ``paged_latent_attention_decode`` (live pages only,
+    by block-table lookup); anything else gathers the slots' rows and
+    attends in XLA over blocks of heads."""
+    b, t, h, w = q.shape
+    if _flash_backend_ok():
+        from ...ops.pallas.paged_attention import (
+            latent_kernel_applicable, paged_latent_attention_tpu)
+        if latent_kernel_applicable(q.shape, tuple(pool.shape), v_width):
+            return paged_latent_attention_tpu(q, pool, block_tables,
+                                              seq_lens, v_width, scale)
+    rows = pool[block_tables].reshape(b, -1, w)
+    return _latent_attend(q, rows, seq_lens, v_width, scale).astype(q.dtype)
+
+
+def paged_latent_write_attend(q, row, cache, block_tables, seq_lens, pos,
+                              active, n_live=None, *, v_width, scale):
+    """A latent attention layer's step: write this step's rows into the
+    layer's page array, then attend over the pool
+    (``paged_latent_attention_decode``), in both step programs.
+
+    q [b, s, h, w] (absorbed: latent part | rotated rope part), row
+    [b, s, w] (the normed latent | the rotated shared key); ``cache``
+    the layer's ``(pool,)``, whose rows are ``w`` padded with zeros to
+    whole lanes; ``pos`` [b, s] the pool position of each row. Rows
+    ``j >= n_live`` and the rows of inactive slots write the reserved
+    scratch page 0. Returns [b, s, h, v_width] and the new ``(pool,)``."""
+    pool, = cache
+    pad = pool.shape[2] - row.shape[-1]
+    page, off = _write_targets(block_tables, pos, active, n_live,
+                               pool.shape[1])
+    row = jnp.pad(row.astype(pool.dtype), ((0, 0), (0, 0), (0, pad)))
+    pool = pool.at[page, off].set(row)
+    with jax.named_scope("core"):
+        out = paged_latent_attention_decode(
+            jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pad))), pool,
+            block_tables, seq_lens, v_width, scale)
+    return out, (pool,)
 
 
 class sdp_kernel:

@@ -38,6 +38,13 @@ tensor parallelism
 (serving/parallel.py) each shard runs this same kernel unchanged on its
 ``kvh/tp`` heads of the sharded pool — head counts are derived from the
 array shapes, and no collective ever appears inside attention.
+
+``paged_latent_attention_tpu`` is the same walk over a LATENT pool (one
+``[num_pages, page_size, width]`` array, a row a token: multi-head
+latent attention in the absorbed form, ``serving/kv_cache.py``): K is
+the cached row and V its first columns, the same for every head, so all
+the heads of a slot attend together as one ``[heads, keys]`` score tile
+and a grid step takes ``_LATENT_PAGES`` pages at once.
 """
 
 from __future__ import annotations
@@ -50,12 +57,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_attention_tpu", "kernel_applicable", "KERNEL_NAME"]
+__all__ = ["paged_attention_tpu", "kernel_applicable", "KERNEL_NAME",
+           "paged_latent_attention_tpu", "latent_kernel_applicable",
+           "LATENT_KERNEL_NAME"]
 
 _LANES = 128
 # the pallas_call's name: how a compiled program's text (and a profiler
 # trace) shows that this kernel, and not the XLA gather path, is in it
 KERNEL_NAME = "paged_attention_decode"
+# the same kernel over a latent pool (one array, one row a token, every
+# query head against the same row): its own name, so that a trace tells
+# the two apart
+LATENT_KERNEL_NAME = "paged_latent_attention_decode"
+# pages a grid step of the latent kernel: a page of 16 rows is 18 KB,
+# far under what a grid step costs, so a step takes this many (the pool
+# is handed to the call that many times, each with its own block-table
+# index map) and its scores are one [heads, 128] tile
+_LATENT_PAGES = 8
 
 
 def _interpret() -> bool:
@@ -195,3 +213,120 @@ def paged_attention_tpu(q, pool_k, pool_v, block_tables, seq_lens,
         name=KERNEL_NAME,
     )(*operands)
     return out.reshape(b, 1, h, d)
+
+
+# ---------------------------------------------------------------------------
+# the latent pool (multi-head latent attention, absorbed form)
+# ---------------------------------------------------------------------------
+
+def latent_kernel_applicable(q_shape, pool_shape, v_width) -> bool:
+    """Shape gate of the latent kernel: one query row a slot, a page
+    that fills the sublanes of either dtype, rows and value columns
+    that fill the lanes (``KVCachePool`` pads a row to whole lanes)."""
+    _, s, h, w = q_shape
+    _, ps, pw = pool_shape
+    return (s == 1 and w == pw and w % _LANES == 0 and ps % 16 == 0
+            and h % 8 == 0 and 0 < v_width <= w and v_width % _LANES == 0)
+
+
+def _latent_decode_kernel(tables_ref, lens_ref, q_ref, *rest, page_size,
+                          n_groups, pages, v_width, scale):
+    row_refs = rest[:pages]
+    o_ref, acc_ref, m_ref, l_ref = rest[pages:]
+    s = pl.program_id(0)
+    g = pl.program_id(1)
+
+    @pl.when(g == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    seq_len = lens_ref[s]
+    span = pages * page_size
+
+    @pl.when(g * span <= seq_len)       # the group holds a live position
+    def _compute():
+        # the group's rows, [span, width]; a dead page of a live group
+        # is the last live page again (the index map clamps) and masked
+        # below by its nominal position
+        rows = jnp.concatenate([r[0] for r in row_refs], axis=0)
+        q = q_ref[0]                                      # [h, width]
+        # operands in the pool's dtype, float32 accumulation: what the
+        # XLA path does (``_latent_attend``)
+        sc = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # [h, span]
+        pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        sc = jnp.where(pos <= seq_len, sc, jnp.float32(-1e30))
+        m_prev = m_ref[:, 0:1]
+        l_prev = l_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        # V is the row's first ``v_width`` columns, already in VMEM
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
+            p.astype(rows.dtype), rows[:, :v_width],
+            preferred_element_type=jnp.float32)            # [h, v_width]
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(g == n_groups - 1)
+    def _finish():
+        o_ref[0] = (acc_ref[...] / l_ref[:, 0:1]).astype(o_ref.dtype)
+
+
+def paged_latent_attention_tpu(q, pool, block_tables, seq_lens, v_width,
+                               scale: float):
+    """Decode attention against a latent pool, in the absorbed form.
+
+    q: [b, 1, h, width], each head's query carried into the row's
+    space (latent part | rotary part); pool: [num_pages, page_size,
+    width], one row a token: K is the row, V its first ``v_width``
+    columns, the same for every head, so the h heads of a slot attend
+    together as one [h, keys] score tile. block_tables [b, max_pages],
+    seq_lens [b] (attends positions <= seq_lens). Returns
+    [b, 1, h, v_width] in q's dtype: the mix of latent rows, which the
+    caller carries through the value up-projection."""
+    b, _, h, w = q.shape
+    _, ps, _ = pool.shape
+    M = block_tables.shape[1]
+    P = _LATENT_PAGES
+    n_groups = -(-M // P)
+    q3 = q.reshape(b, h, w).astype(pool.dtype)
+    tables = jnp.asarray(block_tables, jnp.int32)
+    lens = jnp.asarray(seq_lens, jnp.int32)
+
+    def q_index(s_, g, tables_ref, lens_ref):
+        return (s_, 0, 0)
+
+    def row_index(i):
+        def index(s_, g, tables_ref, lens_ref):
+            # a dead page is the last live page again: in a dead GROUP
+            # every index repeats the step before and no DMA is issued
+            jj = jnp.minimum(g * P + i, lens_ref[s_] // ps)
+            return (tables_ref[s_, jj], 0, 0)
+        return index
+
+    kernel = functools.partial(_latent_decode_kernel, page_size=ps,
+                               n_groups=n_groups, pages=P, v_width=v_width,
+                               scale=scale)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_groups),
+            in_specs=[pl.BlockSpec((1, h, w), q_index)]
+            + [pl.BlockSpec((1, ps, w), row_index(i)) for i in range(P)],
+            out_specs=pl.BlockSpec((1, h, v_width), q_index),
+            scratch_shapes=[pltpu.VMEM((h, v_width), jnp.float32),
+                            pltpu.VMEM((h, _LANES), jnp.float32),
+                            pltpu.VMEM((h, _LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+        compiler_params=None if _interpret() else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=_interpret(),
+        name=LATENT_KERNEL_NAME,
+    )(tables, lens, q3, *([pool] * P))
+    return out.reshape(b, 1, h, v_width)

@@ -21,7 +21,8 @@ streams.
 
 from .engine import BrownoutConfig, ServingEngine
 from .errors import (AdmissionShedError, EngineDrainingError,
-                     FleetOverloadedError, QueueFullError,
+                     FleetOverloadedError, LatentCacheError,
+                     QueueFullError,
                      RecurrentStateError, ReplicaSpawnError,
                      RequestTooLargeError,
                      SchedulerStalledError, ServingError, StaleEpochError,
@@ -67,6 +68,7 @@ __all__ = [
     "ServingError", "QueueFullError", "RequestTooLargeError",
     "SchedulerStalledError", "EngineDrainingError", "FleetOverloadedError",
     "TPConfigError", "AdmissionShedError", "RecurrentStateError",
+    "LatentCacheError",
     "TransportError", "StaleEpochError", "ReplicaSpawnError",
     "Transport", "LoopbackTransport", "ChaosTransport", "EngineServer",
     "Message", "deterministic_jitter",

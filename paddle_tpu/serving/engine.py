@@ -76,9 +76,9 @@ import numpy as np
 
 from ..distributed import fault as _fault
 from ..observability.trace import PROFILE_TRACER
-from .errors import (AdmissionShedError, EngineDrainingError, QueueFullError,
-                     RecurrentStateError, RequestTooLargeError,
-                     SchedulerStalledError)
+from .errors import (AdmissionShedError, EngineDrainingError,
+                     LatentCacheError, QueueFullError, RecurrentStateError,
+                     RequestTooLargeError, SchedulerStalledError)
 from .kv_cache import HybridCache, KVCachePool, declared_cache_layers
 from .metrics import ServingMetrics
 from .scheduler import FINISHED, Request, SamplingParams, Scheduler
@@ -173,8 +173,17 @@ class ServingEngine:
         # Whatever assumes that a request's whole state is its pages is
         # switched off where it is a default (the prefix cache) and
         # refused where it is asked for.
-        self._recurrent = bool(declared_cache_layers(cfg)[1])
-        if self._recurrent:
+        page_formats, state_layers = declared_cache_layers(cfg)
+        self._recurrent = bool(state_layers)
+        # a model with a latent cache (``("latent", width)``: one page
+        # array a layer; SERVING.md "Models with a latent cache"): the
+        # prefix cache stays on, and what reads or writes a page as a K
+        # and a V array of heads is refused the same way
+        self._latent = page_formats[0][0] == "latent"
+        # either kind is handed a ``HybridCache`` by the step programs
+        # and hands its own counters back with it
+        self._hybrid = self._recurrent or self._latent
+        if self._hybrid:
             int8_kv = kv_quant or (kv_dtype is not None
                                    and jnp.dtype(kv_dtype) == jnp.int8)
             for asked, what in (
@@ -183,13 +192,13 @@ class ServingEngine:
                     (snapshot_store, "a snapshot store"), (lora, "LoRA"),
                     (int8_kv, "an int8 KV pool")):
                 if asked:
-                    self._refuse_recurrent(what)
-            if prefix_cache:
-                prefix_cache = False
-                _log.warning(
-                    "%s keeps per-slot recurrent state: the prefix cache "
-                    "is off (a cached page holds K/V, not the state at "
-                    "its boundary)", type(cfg).__name__)
+                    self._refuse(what)
+        if self._recurrent and prefix_cache:
+            prefix_cache = False
+            _log.warning(
+                "%s keeps per-slot recurrent state: the prefix cache "
+                "is off (a cached page holds K/V, not the state at "
+                "its boundary)", type(cfg).__name__)
         self.prefix_cache = prefix_cache
         self._step_counts = None    # the last step's HybridCache.counts
         # tensor parallelism (serving/parallel.py; SERVING.md
@@ -451,8 +460,8 @@ class ServingEngine:
             prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
             if not prompt:
                 raise ValueError("prompt must be non-empty")
-            if prefill_only and self._recurrent:
-                self._refuse_recurrent("a prefill-only hand-off")
+            if prefill_only and self._hybrid:
+                self._refuse("a prefill-only hand-off")
             adapter_hex = ""
             if adapter is not None and adapter != "":
                 from .lora import AdapterUnavailableError
@@ -911,12 +920,20 @@ class ServingEngine:
     # crash-consistent snapshots (serving/snapshot.py)
     # ------------------------------------------------------------------
 
-    def _refuse_recurrent(self, what: str) -> None:
-        raise RecurrentStateError(
-            f"{type(self.model.config).__name__} keeps per-slot recurrent "
-            f"state: the engine cannot honour {what}, which takes a "
-            f"request's pages for its whole state (SERVING.md \"Models "
-            f"with recurrent state\")")
+    def _refuse(self, what: str) -> None:
+        """A feature asked of a model whose cache is not K/V pages
+        alone: the named error of its kind."""
+        name = type(self.model.config).__name__
+        if self._recurrent:
+            raise RecurrentStateError(
+                f"{name} keeps per-slot recurrent state: the engine cannot "
+                f"honour {what}, which takes a request's pages for its "
+                f"whole state (SERVING.md \"Models with recurrent state\")")
+        raise LatentCacheError(
+            f"{name} keeps a latent cache, one row a token a layer: the "
+            f"engine cannot honour {what}, which has not been carried "
+            f"over from K/V pages of heads (SERVING.md \"Models with a "
+            f"latent cache\")")
 
     def save_snapshot(self, path: str) -> str:
         """Durable warm-restart snapshot: capture every live request's
@@ -925,8 +942,8 @@ class ServingEngine:
         rename — RESILIENCE.md). A crash mid-save leaves a torn staging
         dir that :meth:`restore` rejects; the previous committed
         snapshot at ``path`` is replaced only by the atomic rename."""
-        if self._recurrent:
-            self._refuse_recurrent("save_snapshot")
+        if self._hybrid:
+            self._refuse("save_snapshot")
         snaps = self._capture_requests()
         # "tp"/"pp" are informational: payloads are full logical pages
         # (the capture device_get gathers shards, and the stacked pp
@@ -949,8 +966,8 @@ class ServingEngine:
         sample; the injected KV only saves recompute). Raises
         :class:`CheckpointCorruptionError` on a torn or unverifiable
         snapshot dir. Returns the restored rids."""
-        if self._recurrent:
-            self._refuse_recurrent("restore")
+        if self._hybrid:
+            self._refuse("restore")
         snaps, _meta = load_engine_snapshot(path)
         return [self.restore_request(s) for s in snaps]
 
@@ -969,8 +986,8 @@ class ServingEngine:
         failed-over request that would bust the survivor's quota is
         refused with AdmissionShedError and stays queued at the router
         for the next placement attempt."""
-        if self._recurrent:
-            self._refuse_recurrent("restore_request")
+        if self._hybrid:
+            self._refuse("restore_request")
         if self._draining:
             raise EngineDrainingError(
                 "engine is draining; restore on another replica")
@@ -1293,10 +1310,11 @@ class ServingEngine:
 
     def _warm_args(self, program: str) -> tuple:
         """Every argument of one step program for an all-inactive
-        dispatch: the weights, the page pairs, for a model with
-        recurrent state the state, then the lanes."""
+        dispatch: the weights, the page arrays, for a model that takes
+        a ``HybridCache`` the per-slot state (a latent model's is
+        empty), then the lanes."""
         lead = ((self._state, self.pool.pools, self.pool.state)
-                if self._recurrent else (self._state, self.pool.pools))
+                if self._hybrid else (self._state, self.pool.pools))
         return (*lead, *self._warm_lanes(program))
 
     def lower_step_programs(self) -> dict:
@@ -1338,6 +1356,7 @@ class ServingEngine:
                 "prefill_programs": self.mixed_program_count(),
                 "prefix_cache": self.prefix_cache,
                 "recurrent_state": self._recurrent,
+                "latent_cache": self._latent,
                 "kv_quant": self.kv_quant,
                 "host_tier": self.pool.host_tier is not None,
                 "speculative": self._spec is not None,
@@ -1519,7 +1538,7 @@ class ServingEngine:
         if not req.pages:
             return
         page = req.pages[-1]
-        pk, pv = self.pool.pools[0]
+        pk, *pv = self.pool.pools[0]     # a latent pool's entry is (rows,)
         from ..quantization.serving import QuantizedKV
         if self.pool.stacked:
             # pp pool: pools[0] is the stacked [L, pages, ...] pair —
@@ -1530,7 +1549,7 @@ class ServingEngine:
                                  pk.scale.at[0, page, :, 0].set(jnp.nan))
             else:
                 pk = pk.at[0, page, :, 0].set(jnp.nan)
-            self.pool.pools[0] = (pk, pv)
+            self.pool.pools[0] = (pk, *pv)
             return
         if isinstance(pk, QuantizedKV):
             # int8 codes cannot hold a NaN — poison the page's fp32
@@ -1540,9 +1559,9 @@ class ServingEngine:
             # scales as well as codes — tested in test_serving_quant)
             self.pool.pools[0] = (
                 QuantizedKV(pk.q, pk.scale.at[page, :, 0].set(jnp.nan)),
-                pv)
+                *pv)
         else:
-            self.pool.pools[0] = (pk.at[page, :, 0].set(jnp.nan), pv)
+            self.pool.pools[0] = (pk.at[page, :, 0].set(jnp.nan), *pv)
 
     # ------------------------------------------------------------------
     # compiled programs
@@ -1595,11 +1614,12 @@ class ServingEngine:
         # every body donates the page pairs: the scatter of this step's
         # rows updates the arrays it was given, and ``_call_step`` puts
         # the returned ones in their place
-        if self._recurrent:
+        if self._hybrid:
             # the same body with the per-slot state threaded beside the
             # pages and donated with them (the step rewrites every
-            # slot's row, so the new state takes the old one's memory);
-            # the model's own counters ride back with it
+            # slot's row, so the new state takes the old one's memory;
+            # a latent model's state is empty); the model's own counters
+            # ride back with it
             def decode_step_state(state, pools, rstate, tok, tables,
                                   seq_lens, active, temps, top_ps, greedy,
                                   seeds, counts):
@@ -1690,7 +1710,7 @@ class ServingEngine:
                                active, n_live, forced, temps, top_ps,
                                greedy, seeds, counts, R, ps, False)
 
-        if self._recurrent:
+        if self._hybrid:
             def mixed_step_state(state, pools, rstate, toks, tables,
                                  seq_lens, active, n_live, forced, temps,
                                  top_ps, greedy, seeds, counts):
@@ -1974,8 +1994,8 @@ class ServingEngine:
         with tr.span("decode_dispatch", slots=len(self.scheduler.running)):
             nt, ok = self._call_step(self._decode_step, lanes)
         nt, ok = self._watched_sync(nt, ok)
-        if self._recurrent and tr.enabled:
-            self._bump_state_counters()
+        if self._hybrid and tr.enabled:
+            self._bump_hybrid_counters()
         with tr.span("sample_emit"):
             for slot, req in list(self.scheduler.running.items()):
                 req.context_len += 1  # this step's KV write at old
@@ -2018,8 +2038,8 @@ class ServingEngine:
                      drafts=sum(n_drafted.values())):
             samp, acc, ok = self._call_step(self._mixed_step, lanes)
         samp, acc, ok = self._watched_sync(samp, acc, ok)
-        if self._recurrent and tr.enabled:
-            self._bump_state_counters()
+        if self._hybrid and tr.enabled:
+            self._bump_hybrid_counters()
         with tr.span("sample_emit"):
             self._mixed_emit(events, plan, n_drafted, samp, acc, ok)
         return chunk_tokens
@@ -2196,29 +2216,36 @@ class ServingEngine:
 
     def _call_step(self, step, lanes) -> list:
         """Run one step program over ``lanes``: the only place that
-        calls one. Every body donates the page pairs (and, for a model
+        calls one. Every body donates the page arrays (and, for a model
         with recurrent state, the state): the program writes this step's
         rows into the arrays it was given, which are deleted when the
         call returns. What it returns of the pool replaces the pool's
         arrays; the rest is handed back."""
         pool = self.pool
-        if not self._recurrent:
+        if not self._hybrid:
             *out, pool.pools = step(self._state, pool.pools, *lanes)
             return out
         *out, pool.pools, pool.state, self._step_counts = step(
             self._state, pool.pools, pool.state, *lanes)
         return out
 
-    def _bump_state_counters(self) -> None:
-        """A traced step of a model with recurrent state: the slots and
-        bytes of state it held, and what the program itself counted in
-        its expert layers (summed over them): assignments routed (live
-        rows x top_k), those that landed on a held expert, and held
-        experts with at least one row."""
+    def _bump_hybrid_counters(self) -> None:
+        """A traced step of a model that takes a ``HybridCache``: the
+        slots and bytes of recurrent state it held, or the tokens and
+        bytes of latent rows its running requests hold, and what the
+        program itself counted in its expert layers (summed over them):
+        assignments routed (live rows x top_k), those that landed on a
+        held expert, and held experts with at least one row."""
         tr, pool = self.tracer, self.pool
-        live = len(pool.state_slots)
-        tr.bump("state_slots_live", live)
-        tr.bump("state_bytes_live", live * pool.state_bytes_per_slot)
+        if self._recurrent:
+            live = len(pool.state_slots)
+            tr.bump("state_slots_live", live)
+            tr.bump("state_bytes_live", live * pool.state_bytes_per_slot)
+        if self._latent:
+            tokens = sum(r.context_len
+                         for r in self.scheduler.running.values())
+            tr.bump("latent_tokens_live", tokens)
+            tr.bump("latent_bytes_live", tokens * pool.kv_bytes_per_token())
         routed, held, touched = (int(v) for v in
                                  np.asarray(self._step_counts))
         tr.bump("expert_rows_routed", routed)
@@ -2376,9 +2403,8 @@ def _mixed_tail(logits, pools, toks, tables, seq_lens, active, n_live,
         page = jnp.take_along_axis(tables, pos // ps, axis=1)
         page = jnp.where(rej, page, 0)
         off = jnp.where(rej, pos % ps, 0)
-        pools = [(KVCachePool._pos_zero(pk, page, off, stacked),
-                  KVCachePool._pos_zero(pv, page, off, stacked))
-                 for pk, pv in pools]
+        pools = [tuple(KVCachePool._pos_zero(a, page, off, stacked)
+                       for a in pair) for pair in pools]
     return samp, m, ok, pools
 
 

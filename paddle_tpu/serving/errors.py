@@ -61,6 +61,11 @@ router that acts on it is :class:`paddle_tpu.serving.fleet.FleetRouter`
   hand-off, LoRA, int8 KV) was asked of an engine whose model keeps
   per-slot recurrent state (SERVING.md "Models with recurrent state").
   NOT retryable.
+- :class:`LatentCacheError` — a feature that has not been carried over
+  to a latent page format (int8 pages, the host tier, snapshots and
+  hand-off, speculation, LoRA, a sharded pool) was asked of a pool or
+  an engine whose model keeps a latent cache (SERVING.md "Models with a
+  latent cache"). NOT retryable.
 - :class:`TransportError` — a fleet wire message failed its blake2b
   digest re-verify at receive (``serving/transport.py``): the payload
   was corrupted in flight. The message is dropped and counted, never
@@ -86,7 +91,7 @@ from __future__ import annotations
 __all__ = ["ServingError", "QueueFullError", "RequestTooLargeError",
            "SchedulerStalledError", "EngineDrainingError",
            "FleetOverloadedError", "TPConfigError", "AdmissionShedError",
-           "RecurrentStateError",
+           "RecurrentStateError", "LatentCacheError",
            "TransportError", "StaleEpochError", "ReplicaSpawnError"]
 
 
@@ -157,6 +162,19 @@ class RecurrentStateError(ServingError, ValueError):
     assumes that a request's whole state is its pages and can be cut at
     any token; a recurrent state can be kept only where it was
     checkpointed. Raised at construction or at the call that asks.
+
+    Not retryable: homogeneous replicas all refuse identically."""
+
+    retryable = False
+
+
+class LatentCacheError(ServingError, ValueError):
+    """A feature was asked for that has not been carried over to a
+    latent page format (one ``[pages, page_size, width]`` array a layer,
+    a model with latent attention): int8 pages, the host tier,
+    snapshots / restore / hand-off, speculation, LoRA, a sharded or
+    stacked pool. Each reads or writes a page as a K and a V array of
+    heads. Raised at construction or at the call that asks.
 
     Not retryable: homogeneous replicas all refuse identically."""
 

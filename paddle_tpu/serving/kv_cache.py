@@ -5,7 +5,11 @@ layer that keeps K/V pages (every layer of a uniform decoder; the
 attention layers of a model that declares ``cache_layers()``, whose
 recurrent layers get a per-slot state beside the pages:
 ``KVCachePool.state``, SERVING.md "Models with recurrent state")
-(the PagedAttention pool, SOSP '23); sequences own pages through
+(the PagedAttention pool, SOSP '23), or, for a model with latent
+attention, ONE ``[num_pages, page_size, width]`` array per layer whose
+row is a token's compressed latent beside its rotated shared key
+(``cache_layers()`` answers ``("latent", width)``; SERVING.md "Models
+with a latent cache"); sequences own pages through
 per-request int32 block tables instead of contiguous ``[B, max_len]``
 buffers, so cache memory fragments at page granularity instead of
 request granularity and a request's reservation grows one page at a
@@ -62,7 +66,7 @@ import numpy as np
 
 from ..observability.trace import NULL_TRACER
 from ..quantization.serving import QuantizedKV
-from .errors import ServingError
+from .errors import LatentCacheError, ServingError
 from .tiering import HostTier
 
 __all__ = ["KVCachePool", "PoolExhaustedError", "PrefixMatch", "HybridCache",
@@ -142,16 +146,19 @@ def _page_hash(parent: bytes, tokens) -> bytes:
 def declared_cache_layers(config) -> tuple[list, list]:
     """What a model's config says its layers keep for a request:
     ``config.cache_layers()`` gives, in layer order, ``("pages", kv
-    heads, head dim)``, ``("state", ((shape, dtype), ...))`` (arrays kept
-    per slot) or None; a config without it is a uniform decoder, every
-    one of its ``num_hidden_layers`` keeping pages of
-    ``num_key_value_heads`` x ``head_dim``. Returns the page formats and
-    the state declarations, each in layer order."""
+    heads, head dim)`` (a K and a V page array), ``("latent", width)``
+    (one page array: a row of ``width`` values a token), ``("state",
+    ((shape, dtype), ...))`` (arrays kept per slot) or None; a config
+    without it is a uniform decoder, every one of its
+    ``num_hidden_layers`` keeping pages of ``num_key_value_heads`` x
+    ``head_dim``. Returns the page formats (the declarations that keep
+    pages, as given) and the state declarations, each in layer order."""
     declare = getattr(config, "cache_layers", None)
     layers = (declare() if declare is not None else
               [("pages", config.num_key_value_heads, config.head_dim)]
               * config.num_hidden_layers)
-    return ([spec[1:] for spec in layers if spec and spec[0] == "pages"],
+    return ([tuple(spec) for spec in layers
+             if spec and spec[0] in ("pages", "latent")],
             [spec[1] for spec in layers if spec and spec[0] == "state"])
 
 
@@ -198,10 +205,34 @@ class KVCachePool:
                  num_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
                  cache_enabled: bool = True, quantized: bool = False,
                  host_tier=None, sharding=None, tp_degree: int = 1,
-                 pp_degree: int = 1, state_layers=(), max_slots: int = 0):
+                 pp_degree: int = 1, state_layers=(), max_slots: int = 0,
+                 latent_width: int = 0):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is the "
                              "reserved scratch page)")
+        # a latent pool (``latent_width`` > 0): every entry of ``pools``
+        # is ONE array ``[num_pages, page_size, row_width]``, a row a
+        # token, where a K/V pool has a pair of ``[..., kv heads, head
+        # dim]``. A row is ``latent_width`` values padded with zeros to
+        # whole lanes: the TPU's default layout of an array whose last
+        # dimension is not a multiple of 128 makes another dimension
+        # minor (offline compile, PR 35: ``bf16[8225,16,576]{0,2,1}``),
+        # and a page would no longer be one piece of memory. What has
+        # not been carried over to a latent pool is refused here, by
+        # name (SERVING.md "Models with a latent cache")
+        self.latent_width = int(latent_width)
+        self.row_width = -(-self.latent_width // 128) * 128
+        if self.latent_width:
+            for asked, what in (
+                    (quantized, "int8 latent pages"),
+                    (host_tier, "the host tier"),
+                    (sharding is not None or tp_degree > 1
+                     or pp_degree > 1, "a sharded or stacked pool (tp or "
+                                       "pp > 1)")):
+                if asked:
+                    raise LatentCacheError(
+                        f"a latent pool cannot honour {what} (SERVING.md "
+                        f"\"Models with a latent cache\")")
         self.num_layers = num_layers
         self.num_pages = num_pages
         self.page_size = page_size
@@ -226,7 +257,9 @@ class KVCachePool:
         self.tp_degree = int(tp_degree)
         self.pp_degree = int(pp_degree)
         self.stacked = self.pp_degree > 1
-        shape = (num_pages, page_size, num_kv_heads, head_dim)
+        shape = ((num_pages, page_size, self.row_width)
+                 if self.latent_width
+                 else (num_pages, page_size, num_kv_heads, head_dim))
         if self.stacked:
             shape = (num_layers,) + shape
 
@@ -234,7 +267,8 @@ class KVCachePool:
             if sharding is None:
                 return z
             return jax.device_put(z, sharding[1] if scale else sharding[0])
-        # per-layer (pool_k, pool_v). A step program is given these
+        # per-layer (pool_k, pool_v), or (rows,) in a latent pool. A step
+        # program is given these
         # arrays to write in place (donated: deleted when it returns) and
         # its result replaces them, so the handles here always name the
         # latest; read a page through the pool, never keep an array
@@ -248,6 +282,9 @@ class KVCachePool:
                     _place(jnp.zeros(shape[:-1], jnp.float32), scale=True))
             self.pools = [(_zeros(), _zeros())
                           for _ in range(1 if self.stacked else num_layers)]
+        elif self.latent_width:
+            self.pools = [(jnp.zeros(shape, dtype),)
+                          for _ in range(num_layers)]
         else:
             self.pools = [(_place(jnp.zeros(shape, dtype)),
                            _place(jnp.zeros(shape, dtype)))
@@ -329,11 +366,14 @@ class KVCachePool:
         if not pages or len(set(pages)) != 1:
             raise ValueError("the pool keeps one page format: the layers "
                              f"that keep pages declare {sorted(set(pages))}")
-        return cls(len(pages), num_pages, page_size, *pages[0], dtype,
+        kind, *dims = pages[0]
+        kvh, d, width = (0, 0, dims[0]) if kind == "latent" else (*dims, 0)
+        return cls(len(pages), num_pages, page_size, kvh, d, dtype,
                    cache_enabled=cache_enabled, quantized=quantized,
                    host_tier=host_tier, sharding=sharding,
                    tp_degree=tp_degree, pp_degree=pp_degree,
-                   state_layers=state_layers, max_slots=max_slots)
+                   state_layers=state_layers, max_slots=max_slots,
+                   latent_width=width)
 
     # ---- accounting ----
 
@@ -371,9 +411,12 @@ class KVCachePool:
 
     def kv_bytes_per_token(self) -> int:
         """HBM bytes ONE cached token position costs across all layers
-        (K+V): the per-token KV traffic unit the int8 bench configs score
-        MBU against. Quantized: 1 byte/element of codes plus the fp32
+        (K+V, or a latent pool's one row): the per-token KV traffic unit
+        the int8 bench configs score MBU against. Quantized: 1 byte/element of codes plus the fp32
         scale per kv-head row; fp: itemsize bytes/element."""
+        if self.latent_width:     # one (padded) row a token a layer
+            return (self.num_layers * self.row_width
+                    * jnp.dtype(self.dtype).itemsize)
         kvh, d = self.num_kv_heads, self.head_dim
         if self.quantized:
             per = kvh * d * 1 + kvh * 4   # int8 codes + fp32 scale row
@@ -777,8 +820,8 @@ class KVCachePool:
                     else:
                         parts.append(arr[li, page])
             return parts
-        for pk, pv in self.pools:
-            for arr in (pk, pv):
+        for pair in self.pools:
+            for arr in pair:
                 if isinstance(arr, QuantizedKV):
                     parts.append(arr.q[page])
                     parts.append(arr.scale[page])
@@ -1012,7 +1055,8 @@ class KVCachePool:
     # ---- device-side page ops ----
 
     def _rewrite(self, fn) -> None:
-        """Replace every pool array by ``fn`` of it, one pair at a time.
+        """Replace every pool array by ``fn`` of it, one layer's entry (a
+        K/V pair, a latent pool's one array) at a time.
         For the writers that stay eager (``cow_into``, ``rewind``, a
         host page's restore): each call builds a NEW array from the
         current one, so a whole new list built beside the old one would
@@ -1188,8 +1232,9 @@ class KVCachePool:
                     # stacked pp layout: pages live on dim 1, and one
                     # slice covers every layer at once
                     return arr[:, idx] if self.stacked else arr[idx]
-                for li, (pk, pv) in enumerate(self.pools):
-                    for name, arr in (("k", pk), ("v", pv)):
+                for li, pair in enumerate(self.pools):
+                    for name, arr in zip(("k", "v") if len(pair) == 2
+                                         else ("latent",), pair):
                         if isinstance(arr, QuantizedKV):
                             ok = (bool(jnp.all(_sel(arr.q) == 0))
                                   and bool(jnp.all(_sel(arr.scale) == 0)))

@@ -20,9 +20,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.nn.functional.attention import _grouped_decode_attn
-from paddle_tpu.ops.pallas.paged_attention import (kernel_applicable,
-                                                   paged_attention_tpu)
+from paddle_tpu.nn.functional.attention import (_grouped_decode_attn,
+                                                _latent_attend)
+from paddle_tpu.ops.pallas.paged_attention import (
+    kernel_applicable, latent_kernel_applicable, paged_attention_tpu,
+    paged_latent_attention_tpu)
 from paddle_tpu.quantization.serving import QuantizedKV, kv_quantize
 
 
@@ -90,6 +92,33 @@ def test_kernel_fp32_small_page_on_chip():
     want = np.asarray(jax.jit(_gather)(*args))
     # fp32 operands run the MXU at its default (bf16-pass) precision on
     # both sides
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+
+
+def test_latent_kernel_matches_gather_path_on_chip():
+    """``paged_latent_attention_decode`` at the widths of
+    ``openpangu_ultra_moe_serve`` (128 heads against rows of 576 values
+    padded to 640, V the first 512, page 16, tables of 257 pages) and
+    ragged lengths: one row, around a page boundary, around a group of 8
+    pages, a full table."""
+    rng = np.random.default_rng(1)
+    b, h, w, vw, ps, M = 8, 128, 640, 512, 16, 257
+    pool = jnp.asarray(rng.standard_normal((b * M + 1, ps, w)) * 0.5,
+                       jnp.bfloat16).at[..., 576:].set(0)
+    q = jnp.asarray(rng.standard_normal((b, 1, h, w)), jnp.bfloat16)
+    tables = jnp.asarray(1 + rng.permutation(b * M).reshape(b, M), jnp.int32)
+    lens = jnp.asarray([0, ps - 1, ps, 8 * ps - 1, 8 * ps, 1500,
+                        ps * M - 2, ps * M - 1], jnp.int32)
+    assert latent_kernel_applicable(q.shape, pool.shape, vw)
+    scale = 192 ** -0.5
+    got = np.asarray(jax.jit(
+        lambda q, p, t, n: paged_latent_attention_tpu(q, p, t, n, vw, scale)
+    )(q, pool, tables, lens).astype(jnp.float32))
+    want = np.asarray(jax.jit(
+        lambda q, p, t, n: _latent_attend(
+            q, p[t].reshape(b, -1, w), n, vw, scale)
+    )(q, pool, tables, lens))
+    assert np.isfinite(got).all()
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
 
 

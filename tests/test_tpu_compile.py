@@ -285,6 +285,92 @@ def test_a_donated_pool_is_written_in_place(mosaic, monkeypatch, one_chip,
             == 2 * layers * pages * page * kv_heads * d * 2)
 
 
+@pytest.mark.parametrize("rows", [1, 64], ids=["decode", "mixed"])
+def test_a_latent_pool_is_written_in_place_and_stays_compressed(
+        mosaic, monkeypatch, one_chip, rows):
+    """The cache path of a step program over two latent attention
+    layers at the shapes of ``openpangu_ultra_moe_serve`` (32 slots, 128
+    heads, rows of 512 + 64 in a pool ``bf16[8225,16,640]``, tables
+    ``[32, 257]``), the pool donated: ``paged_latent_write_attend`` with
+    one row a slot takes the kernel ``paged_latent_attention_decode``,
+    with 64 rows the XLA path over blocks of heads. Either way the pool
+    is aliased to its result and never copied (a row of 576 values, not
+    padded to whole lanes, gets a layout in which a page is not one
+    piece of memory and the kernel's operand is a copy of the whole
+    pool: PR 35), and no array holds scores for all of slots, rows,
+    heads and keys."""
+    from paddle_tpu.nn.functional import attention
+    monkeypatch.setattr(attention, "_flash_backend_ok", lambda: True)
+    layers, slots, heads, width, v = 2, 32, 128, 576, 512
+    pages, page, table = 8225, 16, 257
+
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(pools, q, row, tables, lens, active, n_live):
+        pos = lens[:, None] + jnp.arange(rows)[None, :]
+        out, new = 0, []
+        for entry in pools:
+            o, entry = attention.paged_latent_write_attend(
+                q, row, entry, tables, lens, pos, active,
+                n_live if rows > 1 else None, v_width=v, scale=192 ** -0.5)
+            out, new = out + o, new + [entry]
+        return out, new
+
+    pool = S((pages, page, 640), BF16)
+    lane = S((slots,), I32)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        [(pool,)] * layers, S((slots, rows, heads, width), BF16),
+        S((slots, rows, width), BF16), S((slots, table), I32), lane,
+        S((slots,), jnp.bool_), lane).compile()
+    text = compiled.as_text()
+    assert _kernels(text, paged_attention.LATENT_KERNEL_NAME) == (
+        layers if rows == 1 else 0)
+    assert not re.findall(
+        r"= \(?bf16\[8225,16,640\][^=]*? (?:copy|copy-start|slice-start)\(",
+        text)
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            == layers * pages * page * 640 * 2)
+    keys = table * page
+    shapes = {tuple(int(d) for d in m.split(","))
+              for m in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text)}
+    full = sorted(d for d in (slots, rows, heads, keys) if d > 1)
+    assert not [s for s in shapes if sorted(d for d in s if d > 1) == full]
+    if rows > 1:
+        assert [s for s in shapes if s[-1] == keys and heads not in s]
+
+
+def test_the_engine_decode_program_holds_the_latent_kernel(
+        mosaic, monkeypatch, one_chip):
+    """``ServingEngine``'s own two step programs for a toy of the
+    latent-attention family (a lane-wide latent, so that the kernel's
+    shape gate passes), lowered for the described chip from the engine's
+    ``_warm_args``: the decode program calls the kernel once a layer,
+    the mixed program not at all, and both alias the donated pool."""
+    from paddle_tpu.models.pangu_moe import (PanguMoEForCausalLM,
+                                             pangu_moe_tiny)
+    from paddle_tpu.nn.functional import attention
+    from paddle_tpu.serving import ServingEngine
+    monkeypatch.setattr(attention, "_flash_backend_ok", lambda: True)
+    model = PanguMoEForCausalLM(pangu_moe_tiny(
+        kv_lora_rank=128, dtype="bfloat16"))
+    model.eval()
+    eng = ServingEngine(model, num_pages=64, page_size=16, max_slots=8,
+                        max_pages_per_slot=8, prefill_chunk=16)
+    for name, step in (("decode", eng._decode_step),
+                       ("mixed", eng._mixed_step)):
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            eng._warm_args(name))
+        compiled = step.lower(*args).compile()
+        assert _kernels(compiled.as_text(),
+                        paged_attention.LATENT_KERNEL_NAME) == (
+            model.config.num_hidden_layers if name == "decode" else 0)
+        assert (compiled.memory_analysis().alias_size_in_bytes
+                >= sum(a.nbytes for e in eng.pool.pools for a in e))
+
+
 def test_the_scrub_zeroes_pages_of_a_donated_pool_in_place(one_chip):
     """``KVCachePool.scrub``'s one program at the pool shape of
     ``mistral_7b_serve`` over four layers: every pool array is aliased
