@@ -611,28 +611,34 @@ def _latent_attend(q, rows, seq_lens, v_width, scale):
 
 
 def paged_latent_attention_decode(q, pool, block_tables, seq_lens, v_width,
-                                  scale):
+                                  scale, n_live=None):
     """Attention over a paged LATENT pool (``serving.kv_cache``: one
     ``[num_pages, page_size, width]`` array a layer, a row a token), in
     the absorbed form: q [b, t, h, width] is each head's query carried
     into the row's space, K is the cached row and V its first
     ``v_width`` columns, the same for all h heads. Row j of a slot
-    attends positions ``<= seq_lens + j``. Returns [b, t, h, v_width]
-    in q's dtype: the mix of cached rows, which the caller carries
-    through its value up-projection. The cache is never decompressed to
-    per-head K/V.
+    attends positions ``<= seq_lens + j``; ``n_live`` [b] is the slot's
+    live rows (``None``: all t; 0: an inactive slot). Returns
+    [b, t, h, v_width] in q's dtype: the mix of cached rows, which the
+    caller carries through its value up-projection. The cache is never
+    decompressed to per-head K/V.
 
-    Routing as ``paged_attention_decode``: one row a slot on a TPU takes
-    the Pallas kernel ``paged_latent_attention_decode`` (live pages only,
-    by block-table lookup); anything else gathers the slots' rows and
-    attends in XLA over blocks of heads."""
+    Routing as ``paged_attention_decode``, by backend and shape alone:
+    on a TPU the Pallas kernel of ``ops/pallas/paged_attention`` (live
+    pages only, by block-table lookup; scores never leave VMEM), named
+    ``paged_latent_attention_decode`` for one row a slot and
+    ``paged_latent_attention_rows`` for more (the mixed program's chunk
+    and verify rows), which skips the rows ``>= n_live`` and hands them
+    back as zeros; anything else gathers the slots' rows and attends in
+    XLA over blocks of heads, every row alike."""
     b, t, h, w = q.shape
     if _flash_backend_ok():
         from ...ops.pallas.paged_attention import (
             latent_kernel_applicable, paged_latent_attention_tpu)
         if latent_kernel_applicable(q.shape, tuple(pool.shape), v_width):
             return paged_latent_attention_tpu(q, pool, block_tables,
-                                              seq_lens, v_width, scale)
+                                              seq_lens, v_width, scale,
+                                              n_live)
     rows = pool[block_tables].reshape(b, -1, w)
     return _latent_attend(q, rows, seq_lens, v_width, scale).astype(q.dtype)
 
@@ -648,17 +654,19 @@ def paged_latent_write_attend(q, row, cache, block_tables, seq_lens, pos,
     the layer's ``(pool,)``, whose rows are ``w`` padded with zeros to
     whole lanes; ``pos`` [b, s] the pool position of each row. Rows
     ``j >= n_live`` and the rows of inactive slots write the reserved
-    scratch page 0. Returns [b, s, h, v_width] and the new ``(pool,)``."""
+    scratch page 0, and the kernel route attends none of them. Returns
+    [b, s, h, v_width] and the new ``(pool,)``."""
     pool, = cache
     pad = pool.shape[2] - row.shape[-1]
     page, off = _write_targets(block_tables, pos, active, n_live,
                                pool.shape[1])
     row = jnp.pad(row.astype(pool.dtype), ((0, 0), (0, 0), (0, pad)))
     pool = pool.at[page, off].set(row)
+    live = jnp.where(active, q.shape[1] if n_live is None else n_live, 0)
     with jax.named_scope("core"):
         out = paged_latent_attention_decode(
             jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pad))), pool,
-            block_tables, seq_lens, v_width, scale)
+            block_tables, seq_lens, v_width, scale, live)
     return out, (pool,)
 
 

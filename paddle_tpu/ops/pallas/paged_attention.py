@@ -44,13 +44,19 @@ array shapes, and no collective ever appears inside attention.
 latent attention in the absorbed form, ``serving/kv_cache.py``): K is
 the cached row and V its first columns, the same for every head, so all
 the heads of a slot attend together as one ``[heads, keys]`` score tile
-and a grid step takes ``_LATENT_PAGES`` pages at once.
+and a grid step takes ``_LATENT_PAGES`` pages at once. It takes one
+query row a slot (the decode program) or many (the mixed program's
+chunk and verify rows, each causal up to its own position): the rows of
+a slot share the cached rows too, so a block of them is one taller
+score tile, and the rows and page groups that are not live are skipped.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +65,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention_tpu", "kernel_applicable", "KERNEL_NAME",
            "paged_latent_attention_tpu", "latent_kernel_applicable",
-           "LATENT_KERNEL_NAME"]
+           "latent_rows_tile", "latent_last_positions", "latent_step_live",
+           "latent_grid_steps",
+           "LATENT_KERNEL_NAME", "LATENT_ROWS_KERNEL_NAME"]
 
 _LANES = 128
 # the pallas_call's name: how a compiled program's text (and a profiler
@@ -74,6 +82,21 @@ LATENT_KERNEL_NAME = "paged_latent_attention_decode"
 # is handed to the call that many times, each with its own block-table
 # index map) and its scores are one [heads, 128] tile
 _LATENT_PAGES = 8
+# the same kernel handed more than one query row a slot (the mixed
+# program's chunk and verify rows): its own name, because
+# ``paged_latent_attention_decode_roofline`` divides the DECODE
+# program's live keys by the device time of every event of that name
+LATENT_ROWS_KERNEL_NAME = "paged_latent_attention_rows"
+# query rows (slot rows x heads) of one grid step of the latent kernel,
+# and of one product inside it: a tile's float32 accumulator, its query
+# and its output block live in VMEM for the whole walk over the page
+# groups; a product's scores are one [_LATENT_SUB_ROWS, 128] tile (16
+# rows of 128 heads: a bfloat16 tile's sublanes, so that a block of a
+# heads-major tile reads as one matrix)
+_LATENT_TILE_ROWS = 8192
+_LATENT_SUB_ROWS = 2048
+# what Mosaic gives a kernel that asks for nothing
+_VMEM_DEFAULT = 16 << 20
 
 
 def _interpret() -> bool:
@@ -219,114 +242,303 @@ def paged_attention_tpu(q, pool_k, pool_v, block_tables, seq_lens,
 # the latent pool (multi-head latent attention, absorbed form)
 # ---------------------------------------------------------------------------
 
+def latent_rows_tile(rows: int, heads: int) -> tuple[int, int]:
+    """``(tile, sub)``: the rows of a slot that one grid step of the
+    latent kernel holds, and the rows of one product inside it. The
+    ``rows x heads`` query rows of a slot all attend the same cached
+    rows, so ``sub`` rows are ONE ``[sub * heads, width]`` product; a
+    step walks its live ``sub``-blocks in a loop, so that a tile can be
+    as tall as its scratch allows and the grid as short. One row a slot
+    (the decode program) is one tile of one row."""
+    def divisor(n, query_rows):     # the largest that fits; at least 1
+        return max(d for d in range(1, n + 1)
+                   if n % d == 0 and d <= max(1, query_rows // heads))
+
+    tile = divisor(rows, _LATENT_TILE_ROWS)
+    return tile, divisor(tile, _LATENT_SUB_ROWS)
+
+
 def latent_kernel_applicable(q_shape, pool_shape, v_width) -> bool:
-    """Shape gate of the latent kernel: one query row a slot, a page
-    that fills the sublanes of either dtype, rows and value columns
-    that fill the lanes (``KVCachePool`` pads a row to whole lanes)."""
+    """Shape gate of the latent kernel: a page that fills the sublanes
+    of either dtype, rows and value columns that fill the lanes
+    (``KVCachePool`` pads a row to whole lanes), heads that fill the
+    sublanes and, with more than one row a slot, blocks of rows that
+    fill them in either dtype too."""
     _, s, h, w = q_shape
     _, ps, pw = pool_shape
-    return (s == 1 and w == pw and w % _LANES == 0 and ps % 16 == 0
-            and h % 8 == 0 and 0 < v_width <= w and v_width % _LANES == 0)
+    return (w == pw and w % _LANES == 0 and ps % 16 == 0 and h % 8 == 0
+            and (s == 1 or latent_rows_tile(s, h)[1] % 16 == 0)
+            and 0 < v_width <= w and v_width % _LANES == 0)
 
 
-def _latent_decode_kernel(tables_ref, lens_ref, q_ref, *rest, page_size,
-                          n_groups, pages, v_width, scale):
+def latent_last_positions(seq_lens, n_live, rows, tile, xp=jnp):
+    """``[slots, rows // tile]``: the last position that each tile of
+    the latent kernel attends, -1 for a tile with no live row. Tile
+    ``i`` holds a slot's rows ``[i * tile, (i + 1) * tile)``, of which
+    those ``< n_live`` are live; row ``j`` sits at position ``seq_lens +
+    j`` and attends positions up to its own. ``xp``: ``jnp`` for the
+    kernel's scalars, ``numpy`` for the engine's count."""
+    i = xp.arange(rows // tile)[None, :]
+    n = n_live[:, None]
+    last = seq_lens[:, None] + xp.minimum((i + 1) * tile, n) - 1
+    return xp.where(i * tile < n, last, -1)
+
+
+def latent_step_live(last_pos, g, span):
+    """The latent kernel's predicate: grid step ``(slot, tile, g)``
+    computes when page group ``g``, which holds the positions from ``g *
+    span``, has one that the tile's last live row attends
+    (``latent_last_positions``). The kernel and ``latent_grid_steps``
+    both ask here."""
+    return g * span <= last_pos
+
+
+def latent_grid_steps(seq_lens, n_live, *, rows, heads, max_pages,
+                      page_size) -> tuple[int, int]:
+    """``(live, dispatched)`` grid steps of ONE call of the latent
+    kernel (one layer) with ``rows`` query rows a slot: every slot of
+    the lanes ``seq_lens`` / ``n_live`` (an inactive slot has ``n_live``
+    0) walks ``rows / tile`` tiles x ``ceil(max_pages / 8)`` page
+    groups; live are the steps whose ``latent_step_live`` holds."""
+    tile, _ = latent_rows_tile(rows, heads)
+    last = latent_last_positions(np.asarray(seq_lens, np.int64),
+                                 np.asarray(n_live, np.int64), rows, tile,
+                                 np)
+    groups = np.arange(-(-max_pages // _LATENT_PAGES))
+    computes = latent_step_live(last[..., None], groups,
+                                _LATENT_PAGES * page_size)
+    return int(computes.sum()), int(computes.size)
+
+
+def _latent_kernel(tables_ref, lens_ref, last_ref, q_ref, *rest, page_size,
+                   n_tiles, n_groups, pages, heads, tile, sub, v_width,
+                   scale):
+    """One grid step ``(slot, tile, page group)``. One row a slot: q
+    and the output are ``[1, heads, .]`` blocks. More:
+    ``[heads, 1, tile, .]`` blocks of heads-major arrays, a block of
+    ``sub`` rows is read as ``[heads * sub, .]`` (row r of it is the
+    slot's row ``r % sub``), and a tile's ONE live row (a decode lane of
+    the mixed program) goes through its ``heads`` query rows alone."""
     row_refs = rest[:pages]
     o_ref, acc_ref, m_ref, l_ref = rest[pages:]
     s = pl.program_id(0)
-    g = pl.program_id(1)
+    i = pl.program_id(1)
+    g = pl.program_id(2)
+    span = pages * page_size
+    block = sub * heads                    # query rows of one product
+    blocks = tile // sub
+    first = lens_ref[s] + i * tile         # position of the tile's row 0
+    last_pos = last_ref[s * n_tiles + i]
+    live_rows = jnp.maximum(last_pos + 1 - first, 0)
+    n_sub = (live_rows + sub - 1) // sub   # blocks that hold a live row
+
+    def part(k, unit, n):
+        start = k * unit
+        if not isinstance(k, int):
+            start = pl.multiple_of(start, unit)
+        return pl.ds(start, n)
+
+    def state_of(k, n=block):
+        """The scratch rows of block k (the one live row: the first
+        ``heads`` of them)."""
+        return part(k, block, n)
+
+    def rows_of(k):
+        return part(k, sub, sub)
+
+    def clear(k, _=None):
+        at = state_of(k)
+        acc_ref[at, :] = jnp.zeros((block, v_width), jnp.float32)
+        m_ref[at, :] = jnp.full((block, _LANES), -1e30, jnp.float32)
+        l_ref[at, :] = jnp.zeros((block, _LANES), jnp.float32)
 
     @pl.when(g == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if blocks == 1:
+            clear(0)
+        else:
+            jax.lax.fori_loop(0, n_sub, clear, None)
 
-    seq_len = lens_ref[s]
-    span = pages * page_size
-
-    @pl.when(g * span <= seq_len)       # the group holds a live position
+    @pl.when(latent_step_live(last_pos, g, span))
     def _compute():
         # the group's rows, [span, width]; a dead page of a live group
         # is the last live page again (the index map clamps) and masked
         # below by its nominal position
         rows = jnp.concatenate([r[0] for r in row_refs], axis=0)
-        q = q_ref[0]                                      # [h, width]
-        # operands in the pool's dtype, float32 accumulation: what the
-        # XLA path does (``_latent_attend``)
-        sc = jax.lax.dot_general(
-            q, rows, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [h, span]
         pos = g * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
-        sc = jnp.where(pos <= seq_len, sc, jnp.float32(-1e30))
-        m_prev = m_ref[:, 0:1]
-        l_prev = l_ref[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(sc - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        # V is the row's first ``v_width`` columns, already in VMEM
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p.astype(rows.dtype), rows[:, :v_width],
-            preferred_element_type=jnp.float32)            # [h, v_width]
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+        def attend(q, at, limit):
+            """Query rows q [n, width], whose state is the scratch
+            rows ``at``, against the group: row r keeps positions
+            ``<= limit`` ([n, 1], or a scalar)."""
+            n = q.shape[0]
+            # operands in the pool's dtype, float32 accumulation: what
+            # the XLA path does (``_latent_attend``)
+            sc = jax.lax.dot_general(
+                q, rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [n, span]
+            sc = jnp.where(pos <= limit, sc, jnp.float32(-1e30))
+            # group 0 holds position 0, which every row attends, and
+            # the groups come in order: the running maximum is finite
+            # from a row's first step on, so -1e30 underflows to exact 0
+            m_prev = m_ref[at, 0:1]
+            l_prev = l_ref[at, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(sc - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            # V is the row's first ``v_width`` columns, already in VMEM
+            acc_ref[at, :] = acc_ref[at, :] * alpha + jax.lax.dot(
+                p.astype(rows.dtype), rows[:, :v_width],
+                preferred_element_type=jnp.float32)        # [n, v_width]
+            m_ref[at, :] = jnp.broadcast_to(m_new, (n, _LANES))
+            l_ref[at, :] = jnp.broadcast_to(l_new, (n, _LANES))
+
+        if q_ref.ndim == 3:     # one row a slot: the decode program
+            attend(q_ref[0], state_of(0), first)
+        else:
+            @pl.when(live_rows == 1)
+            def _one_row():
+                attend(q_ref[:, 0, 0, :], state_of(0, heads), first)
+
+            @pl.when(live_rows > 1)
+            def _blocks():
+                row = jax.lax.broadcasted_iota(
+                    jnp.int32, (block, 1), 0) % sub
+
+                def body(k, _):
+                    attend(q_ref[:, 0, rows_of(k), :].reshape(block, -1),
+                           state_of(k), first + k * sub + row)
+                # the blocks whose last row sits before the group's
+                # first position have nothing to attend in it
+                jax.lax.fori_loop(
+                    jnp.maximum(g * span - first, 0) // sub, n_sub, body,
+                    None)
+
+    def normalised(at, live):
+        """The scratch rows ``at`` over their sums; a row that is not
+        ``live`` ([n, 1] or a scalar) or was never attended comes out
+        as zero, not 0/0."""
+        l = l_ref[at, 0:1]
+        inv = jnp.where(live & (l > 0), 1.0 / jnp.where(l > 0, l, 1.0), 0.0)
+        return (acc_ref[at, :] * inv).astype(o_ref.dtype)
 
     @pl.when(g == n_groups - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...] / l_ref[:, 0:1]).astype(o_ref.dtype)
+        if o_ref.ndim == 3:
+            o_ref[0] = normalised(state_of(0), live_rows > 0)
+            return
+
+        @pl.when(live_rows == 1)
+        def _one_row():
+            o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+            o_ref[:, 0, 0, :] = normalised(state_of(0, heads), True)
+
+        @pl.when(live_rows != 1)
+        def _blocks():
+            row = jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0) % sub
+            for k in range(blocks):
+                @pl.when(k < n_sub)
+                def _live():
+                    o_ref[:, 0, rows_of(k), :] = normalised(
+                        state_of(k), k * sub + row < live_rows
+                    ).reshape(heads, sub, v_width)
+
+                @pl.when(k >= n_sub)
+                def _dead():
+                    o_ref[:, 0, rows_of(k), :] = jnp.zeros(
+                        (heads, sub, v_width), o_ref.dtype)
 
 
 def paged_latent_attention_tpu(q, pool, block_tables, seq_lens, v_width,
-                               scale: float):
-    """Decode attention against a latent pool, in the absorbed form.
+                               scale: float, n_live=None):
+    """Attention against a latent pool, in the absorbed form.
 
-    q: [b, 1, h, width], each head's query carried into the row's
+    q: [b, t, h, width], each head's query carried into the row's
     space (latent part | rotary part); pool: [num_pages, page_size,
     width], one row a token: K is the row, V its first ``v_width``
-    columns, the same for every head, so the h heads of a slot attend
-    together as one [h, keys] score tile. block_tables [b, max_pages],
-    seq_lens [b] (attends positions <= seq_lens). Returns
-    [b, 1, h, v_width] in q's dtype: the mix of latent rows, which the
-    caller carries through the value up-projection."""
-    b, _, h, w = q.shape
+    columns, the same for every head, so the t x h query rows of a slot
+    attend the same rows and ``sub`` of the t are one
+    ``[sub * h, keys]`` score tile. block_tables [b, max_pages];
+    seq_lens [b]: row j of a slot sits at ``seq_lens + j`` and attends
+    positions up to its own; n_live [b]: the slot's live rows (``None``:
+    all t; 0: an inactive slot). Rows ``>= n_live`` come out as zeros.
+    Returns [b, t, h, v_width] in q's dtype: the mix of latent rows,
+    which the caller carries through the value up-projection.
+
+    Grid (slot, row tile, page group), the groups innermost with the
+    online softmax's state in VMEM. A tile with no live row and a group
+    past what the tile's last live row attends compute nothing
+    (``latent_step_live``), and the dead groups, their index maps
+    repeating the step before, move nothing. The kernel is named by ``t``:
+    ``paged_latent_attention_decode`` for one row a slot,
+    ``paged_latent_attention_rows`` for more."""
+    b, t, h, w = q.shape
     _, ps, _ = pool.shape
     M = block_tables.shape[1]
     P = _LATENT_PAGES
     n_groups = -(-M // P)
-    q3 = q.reshape(b, h, w).astype(pool.dtype)
+    tile, sub = latent_rows_tile(t, h)
     tables = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.asarray(seq_lens, jnp.int32)
+    n_tiles = t // tile
+    last = latent_last_positions(
+        lens, (jnp.full((b,), t, jnp.int32) if n_live is None
+               else jnp.asarray(n_live, jnp.int32)), t, tile).reshape(-1)
+    if t == 1:
+        qk = q.reshape(b, h, w).astype(pool.dtype)
+        q_block, o_block = (1, h, w), (1, h, v_width)
+        out_shape = (b, h, v_width)
 
-    def q_index(s_, g, tables_ref, lens_ref):
-        return (s_, 0, 0)
+        def tile_index(s_, i, g, tables_ref, lens_ref, last_ref):
+            return (s_, 0, 0)
+    else:
+        # heads-major: what the absorbing product before and the value
+        # up-projection after (one matrix a head) hold their operands
+        # in, so that neither transpose is a pass over the queries
+        qk = q.transpose(2, 0, 1, 3).astype(pool.dtype)
+        q_block, o_block = (h, 1, tile, w), (h, 1, tile, v_width)
+        out_shape = (h, b, t, v_width)
 
-    def row_index(i):
-        def index(s_, g, tables_ref, lens_ref):
+        def tile_index(s_, i, g, tables_ref, lens_ref, last_ref):
+            return (0, s_, i, 0)
+
+    def row_index(p):
+        def index(s_, i, g, tables_ref, lens_ref, last_ref):
             # a dead page is the last live page again: in a dead GROUP
             # every index repeats the step before and no DMA is issued
-            jj = jnp.minimum(g * P + i, lens_ref[s_] // ps)
-            return (tables_ref[s_, jj], 0, 0)
+            # (a tile with no live row stays on the slot's first page)
+            last = jnp.maximum(last_ref[s_ * n_tiles + i], 0) // ps
+            return (tables_ref[s_, jnp.minimum(g * P + p, last)], 0, 0)
         return index
 
-    kernel = functools.partial(_latent_decode_kernel, page_size=ps,
-                               n_groups=n_groups, pages=P, v_width=v_width,
-                               scale=scale)
+    kernel = functools.partial(
+        _latent_kernel, page_size=ps, n_tiles=n_tiles, n_groups=n_groups,
+        pages=P, heads=h, tile=tile, sub=sub, v_width=v_width, scale=scale)
+    size = jnp.dtype(pool.dtype).itemsize
+    # the pipeline's two buffers of a query and an output tile, the
+    # scratch, and room for a block's scores and products
+    vmem = (tile * h * (2 * w * size + 2 * v_width * q.dtype.itemsize
+                        + 4 * (v_width + 2 * _LANES))
+            + sub * h * 4 * (2 * v_width + 4 * P * ps) + (4 << 20))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(b, n_groups),
-            in_specs=[pl.BlockSpec((1, h, w), q_index)]
-            + [pl.BlockSpec((1, ps, w), row_index(i)) for i in range(P)],
-            out_specs=pl.BlockSpec((1, h, v_width), q_index),
-            scratch_shapes=[pltpu.VMEM((h, v_width), jnp.float32),
-                            pltpu.VMEM((h, _LANES), jnp.float32),
-                            pltpu.VMEM((h, _LANES), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+            num_scalar_prefetch=3,
+            grid=(b, n_tiles, n_groups),
+            in_specs=[pl.BlockSpec(q_block, tile_index)]
+            + [pl.BlockSpec((1, ps, w), row_index(p)) for p in range(P)],
+            out_specs=pl.BlockSpec(o_block, tile_index),
+            scratch_shapes=[pltpu.VMEM((tile * h, v_width), jnp.float32),
+                            pltpu.VMEM((tile * h, _LANES), jnp.float32),
+                            pltpu.VMEM((tile * h, _LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=None if _interpret() else pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
+            dimension_semantics=("arbitrary",) * 3,
+            vmem_limit_bytes=vmem if vmem > _VMEM_DEFAULT else None),
         interpret=_interpret(),
-        name=LATENT_KERNEL_NAME,
-    )(tables, lens, q3, *([pool] * P))
-    return out.reshape(b, 1, h, v_width)
+        name=LATENT_KERNEL_NAME if t == 1 else LATENT_ROWS_KERNEL_NAME,
+    )(tables, lens, last, qk, *([pool] * P))
+    if t == 1:
+        return out.reshape(b, 1, h, v_width)
+    return out.transpose(1, 2, 0, 3)

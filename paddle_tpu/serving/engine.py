@@ -76,6 +76,7 @@ import numpy as np
 
 from ..distributed import fault as _fault
 from ..observability.trace import PROFILE_TRACER
+from ..ops.pallas.paged_attention import latent_grid_steps
 from .errors import (AdmissionShedError, EngineDrainingError,
                      LatentCacheError, QueueFullError, RecurrentStateError,
                      RequestTooLargeError, SchedulerStalledError)
@@ -2097,6 +2098,17 @@ class ServingEngine:
                 n_live[slot] = 1 + len(d)
                 n_drafted[slot] = len(d)
                 counts[slot] = len(req.tokens)
+        if tr.enabled and self._latent:
+            # the attention core's padding, counted where the work is
+            # handed over: the grid steps a layer that the rows kernel
+            # walks over these lanes, against those that compute (the
+            # kernel's own predicate, by the module that holds it)
+            live, dispatched = latent_grid_steps(
+                seq_lens, n_live, rows=K, max_pages=M,
+                heads=self.model.config.num_attention_heads,
+                page_size=self.page_size)
+            tr.bump("latent_steps_live", live)
+            tr.bump("latent_steps_dispatched", dispatched)
         lanes = (jnp.asarray(toks), jnp.asarray(tables),
                  jnp.asarray(seq_lens), jnp.asarray(active),
                  jnp.asarray(n_live), jnp.asarray(forced),

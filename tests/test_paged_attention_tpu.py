@@ -9,7 +9,8 @@ what the interpreter computed. Each case runs ``paged_attention_tpu`` and
 the XLA gather path (``_grouped_decode_attn`` over ``pool[tables]``) on
 the same pool, ragged lengths included (one token, a page boundary, a
 full table), at the serving widths (Llama-3-8B heads 32/8 and the bench
-shape 16/8, page 16, bf16 and int8 pools).
+shape 16/8, page 16, bf16 and int8 pools); the latent kernel against
+``_latent_attend`` at one row a slot and at a chunk of 64.
 """
 
 import json
@@ -120,6 +121,42 @@ def test_latent_kernel_matches_gather_path_on_chip():
     )(q, pool, tables, lens))
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+
+
+def test_latent_rows_kernel_matches_gather_path_on_chip():
+    """``paged_latent_attention_rows`` at the widths of
+    ``openpangu_ultra_moe_serve``'s mixed program (64 rows x 128 heads a
+    slot, rows of 640, V the first 512, page 16, tables of 257 pages)
+    against ``_latent_attend`` over the gathered table: whole chunks
+    that start on and off a page and a group of pages, a chunk's short
+    tail, a decode lane (one live row), an inactive slot, a full table;
+    table entries past a slot's live pages point at page 0. Live rows
+    agree as the decode kernel's do, dead rows are exactly zero."""
+    rng = np.random.default_rng(2)
+    b, t, h, w, vw, ps, M = 8, 64, 128, 640, 512, 16, 257
+    pool = jnp.asarray(rng.standard_normal((b * M + 1, ps, w)) * 0.5,
+                       jnp.bfloat16).at[..., 576:].set(0)
+    q = jnp.asarray(rng.standard_normal((b, t, h, w)), jnp.bfloat16)
+    lens = np.array([0, 8 * ps - 3, 1000, 1500, 2047, 777,
+                     ps * M - t, ps * M - 1], np.int32)
+    live = np.array([t, t, 37, 1, 9, 0, t, 1], np.int32)
+    tables = 1 + rng.permutation(b * M).reshape(b, M).astype(np.int32)
+    for s in range(b):
+        tables[s, (lens[s] + max(live[s], 1) - 1) // ps + 1:] = 0
+    assert latent_kernel_applicable(q.shape, pool.shape, vw)
+    scale = 192 ** -0.5
+    got = np.asarray(jax.jit(
+        lambda q, p, tb, n, nl: paged_latent_attention_tpu(
+            q, p, tb, n, vw, scale, nl)
+    )(q, pool, tables, lens, live).astype(jnp.float32))
+    want = np.asarray(jax.jit(
+        lambda q, p, tb, n: _latent_attend(
+            q, p[tb].reshape(b, -1, w), n, vw, scale)
+    )(q, pool, tables, lens))
+    assert np.isfinite(got).all()
+    rows = np.arange(t)[None, :] < live[:, None]
+    assert not got[~rows].any()
+    np.testing.assert_allclose(got[rows], want[rows], rtol=2e-2, atol=2e-2)
 
 
 def test_report_kernel_and_gather_host_times():

@@ -194,10 +194,103 @@ def test_the_decode_kernel_is_the_xla_path_at_ragged_lengths(dtype):
     assert got.shape == (b, 1, h, vw) and got.dtype == dtype
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     assert np.abs(np.asarray(got, np.float32) - np.asarray(want)).max() < tol
+    assert paged_attention.latent_kernel_applicable(
+        (b, 16, h, w), pool.shape, vw)              # more rows a slot too
     assert not paged_attention.latent_kernel_applicable(
-        (b, 2, h, w), pool.shape, vw)               # one row a slot only
+        (b, 2, h, w), pool.shape, vw)   # in blocks that fill a bf16 tile
     assert not paged_attention.latent_kernel_applicable(
         (b, 1, h, 192), (8, ps, 192), vw)           # rows fill the lanes
+
+
+def _rows_case(t, dtype):
+    """Lanes of the latent kernel with ``t`` rows a slot: lengths that
+    start a page, end one, cross a page and a group of 8 pages inside
+    the slot's rows, a full table; live rows from 0 (an inactive slot)
+    to ``t``; the table entries past a slot's live pages point at page
+    0, which holds NaN: a step that read one would show."""
+    rng = np.random.default_rng(5)
+    b, h, w, vw, ps, M = 7, 16, 256, 128, 16, 19
+    pool = jnp.asarray(rng.standard_normal((b * M + 1, ps, w)), dtype)
+    q = jnp.asarray(rng.standard_normal((b, t, h, w)), dtype)
+    lens = np.array([0, 15, 16, 8 * ps - (t + 1) // 2, M * ps - t, 77, 40],
+                    np.int32)
+    live = np.array([t, max(t - 1, 1), 1, t, t, 0, (t + 1) // 2], np.int32)
+    tables = 1 + rng.permutation(b * M).reshape(b, M).astype(np.int32)
+    for s in range(b):
+        tables[s, (lens[s] + max(live[s], 1) - 1) // ps + 1:] = 0
+    return q, pool.at[0].set(jnp.nan), tables, lens, live, vw
+
+
+@pytest.fixture(params=[(8192, 2048), (256, 64)], ids=["whole", "cut"])
+def tiling(request, monkeypatch):
+    """The kernel's tile and block of rows at the toy's 16 heads: whole
+    (one tile, one block) and cut small (tiles of 16 rows, blocks of 4)."""
+    monkeypatch.setattr(paged_attention, "_LATENT_TILE_ROWS",
+                        request.param[0])
+    monkeypatch.setattr(paged_attention, "_LATENT_SUB_ROWS",
+                        request.param[1])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("t", [1, 3, 16, 64])
+def test_the_rows_kernel_is_the_xla_path_on_live_rows_and_zero_on_dead(
+        t, dtype, tiling):
+    """``paged_latent_attention_tpu`` with ``t`` query rows a slot, in
+    interpret mode, against ``_latent_attend`` over the gathered table:
+    live rows agree within the decode kernel's tolerance, rows ``>=
+    n_live`` and every row of an inactive slot are exactly zero, and
+    nothing is NaN although every page that is not live is."""
+    q, pool, tables, lens, live, vw = _rows_case(t, dtype)
+    b, _, h, w = q.shape
+    got = paged_attention.paged_latent_attention_tpu(
+        q, pool, jnp.asarray(tables), jnp.asarray(lens), vw, 0.07,
+        jnp.asarray(live))
+    want = attention._latent_attend(
+        q, pool.at[0].set(0)[tables].reshape(b, -1, w), jnp.asarray(lens),
+        vw, 0.07)
+    assert got.shape == (b, t, h, vw) and got.dtype == dtype
+    got = np.asarray(got, np.float32)
+    rows = np.arange(t)[None, :] < live[:, None]
+    assert not np.isnan(got).any()
+    assert not got[~rows].any()
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    assert np.abs(got[rows] - np.asarray(want)[rows]).max() < tol
+
+
+@pytest.mark.parametrize("t", [1, 3, 16, 64])
+def test_the_count_of_grid_steps_is_the_kernels_own(monkeypatch, t, tiling):
+    """``latent_grid_steps`` (what the engine bumps as
+    ``latent_steps_live`` / ``latent_steps_dispatched``) against the
+    kernel itself: every grid step of an interpreted call reports what
+    its ``pl.when`` was handed; and against the definition, counted row
+    by row: a step is live when some live row of its tile attends some
+    position of its page group."""
+    q, pool, tables, lens, live, vw = _rows_case(t, jnp.float32)
+    h, (ps, M) = q.shape[2], (pool.shape[1], tables.shape[1])
+    counted = paged_attention.latent_grid_steps(
+        lens, live, rows=t, heads=h, max_pages=M, page_size=ps)
+    tile, _ = paged_attention.latent_rows_tile(t, h)
+    span = paged_attention._LATENT_PAGES * ps
+    by_rows = sum(
+        any(i * tile <= j < min((i + 1) * tile, n) and g * span <= L + j
+            for j in range(t))
+        for L, n in zip(lens, live) for i in range(t // tile)
+        for g in range(-(-M // paged_attention._LATENT_PAGES)))
+    assert counted == (by_rows, len(lens) * (t // tile) * -(-M // 8))
+    seen, predicate = [], paged_attention.latent_step_live
+
+    def reporting(last_pos, g, span):
+        computes = predicate(last_pos, g, span)
+        if isinstance(computes, jax.Array):     # inside the kernel
+            jax.debug.callback(lambda c: seen.append(bool(c)), computes)
+        return computes
+
+    monkeypatch.setattr(paged_attention, "latent_step_live", reporting)
+    jax.block_until_ready(paged_attention.paged_latent_attention_tpu(
+        q, pool, jnp.asarray(tables), jnp.asarray(lens), vw, 0.07,
+        jnp.asarray(live)))
+    jax.effects_barrier()
+    assert (sum(seen), len(seen)) == counted
 
 
 def test_the_xla_path_never_holds_all_heads_rows_and_keys(monkeypatch):
@@ -269,6 +362,31 @@ def test_engine_serves_chunked_prefill_and_decode(cfg, model):
     assert eng.step_program_counts() == {"decode": 1, "mixed": 1}
     assert eng.stats()["latent_cache"] and eng.stats()["prefix_cache"]
     eng.audit_pool()
+
+
+def test_a_traced_engine_counts_the_rows_kernels_grid_steps(model):
+    """Every traced mixed dispatch bumps ``latent_steps_dispatched`` by
+    the grid of one call of the rows kernel over the engine's lanes
+    (every slot, idle or not) and ``latent_steps_live`` by the steps of
+    it that compute: a slot with a chunk of a short prompt has one live
+    page group of two, a slot that sits out has none."""
+    from paddle_tpu.observability.trace import Tracer
+    tr = Tracer()
+    eng = engine(model, tracer=tr)
+    eng.add_request(prompt(37, 5), 4)
+    eng.add_request(prompt(5, 4), 4)
+    while eng.scheduler.running or eng.scheduler.queue_depth:
+        eng.step()
+    c = tr.counters
+    tile, _ = paged_attention.latent_rows_tile(
+        16, model.config.num_attention_heads)
+    groups = -(-eng.max_pages_per_slot // paged_attention._LATENT_PAGES)
+    assert c["latent_steps_dispatched"] == (
+        c["mixed_steps"] * eng.max_slots * (16 // tile) * groups)
+    # 37 + 5 prompt tokens in chunks of 16, then the decode lanes that
+    # share a mixed step with a chunk: at least a live step a chunk, and
+    # never more than the two busy slots' single live group each
+    assert c["chunks"] <= c["latent_steps_live"] <= 2 * c["mixed_steps"]
 
 
 def test_prefix_cache_over_latent_pages(model):

@@ -285,6 +285,52 @@ def test_a_donated_pool_is_written_in_place(mosaic, monkeypatch, one_chip,
             == 2 * layers * pages * page * kv_heads * d * 2)
 
 
+def _latent_kernels(text: str) -> dict:
+    """How often a program's text calls the latent kernel under each
+    of its two names."""
+    return {"decode": _kernels(text, paged_attention.LATENT_KERNEL_NAME),
+            "rows": _kernels(text, paged_attention.LATENT_ROWS_KERNEL_NAME)}
+
+
+def _float_shapes(text: str, kinds: str = "f32|bf16") -> set:
+    return {tuple(int(d) for d in m.split(","))
+            for m in re.findall(rf"(?:{kinds})\[([\d,]+)\]", text)}
+
+
+def test_the_latent_rows_kernel_compiles_at_the_cell_shapes(mosaic,
+                                                            one_chip):
+    """``paged_latent_attention_rows`` alone at the shapes of
+    ``openpangu_ultra_moe_serve``'s mixed program (64 rows x 128 heads a
+    slot, rows of 640, V the first 512, tables ``[32, 257]`` over
+    ``bf16[8225,16,640]``): Mosaic takes the tile that
+    ``latent_rows_tile`` picks, its heads-major blocks, its loops over
+    the live blocks and its request for VMEM; the pool is an operand,
+    never a copy."""
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    heads_major = S((128, 32, 64, 640), BF16)
+    pool = S((8225, 16, 640), BF16)
+    lane = S((32,), I32)
+    assert paged_attention.latent_kernel_applicable((32, 64, 128, 640),
+                                                    pool.shape, 512)
+    assert paged_attention.latent_rows_tile(64, 128) == (64, 16)
+    # the kernel reads its queries and writes its result heads-major,
+    # as the per-head products on either side of it hold them: from
+    # and to such arrays both transposes are free and nothing is copied
+    compiled = jax.jit(
+        lambda q, p, t, n, live: paged_attention.paged_latent_attention_tpu(
+            q.transpose(1, 2, 0, 3), p, t, n, 512, 192 ** -0.5, live
+        ).transpose(2, 0, 1, 3)
+    ).lower(heads_major, pool, S((32, 257), I32), lane, lane).compile()
+    text = compiled.as_text()
+    assert _latent_kernels(text) == {"decode": 0, "rows": 1}
+    assert not re.findall(
+        r"= \(?bf16\[8225,16,640\][^=]*? (?:copy|copy-start|slice-start)\(",
+        text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("rows", [1, 64], ids=["decode", "mixed"])
 def test_a_latent_pool_is_written_in_place_and_stays_compressed(
         mosaic, monkeypatch, one_chip, rows):
@@ -292,13 +338,14 @@ def test_a_latent_pool_is_written_in_place_and_stays_compressed(
     layers at the shapes of ``openpangu_ultra_moe_serve`` (32 slots, 128
     heads, rows of 512 + 64 in a pool ``bf16[8225,16,640]``, tables
     ``[32, 257]``), the pool donated: ``paged_latent_write_attend`` with
-    one row a slot takes the kernel ``paged_latent_attention_decode``,
-    with 64 rows the XLA path over blocks of heads. Either way the pool
-    is aliased to its result and never copied (a row of 576 values, not
-    padded to whole lanes, gets a layout in which a page is not one
-    piece of memory and the kernel's operand is a copy of the whole
-    pool: PR 35), and no array holds scores for all of slots, rows,
-    heads and keys."""
+    one row a slot takes the kernel under the name
+    ``paged_latent_attention_decode``, with 64 rows under
+    ``paged_latent_attention_rows``. Either way the pool is aliased to
+    its result and never copied (a row of 576 values, not padded to
+    whole lanes, gets a layout in which a page is not one piece of
+    memory and the kernel's operand is a copy of the whole pool: PR 35),
+    and no array at all has an axis of a slot's whole table of keys:
+    the scores stay in VMEM and the keys come by block table."""
     from paddle_tpu.nn.functional import attention
     monkeypatch.setattr(attention, "_flash_backend_ok", lambda: True)
     layers, slots, heads, width, v = 2, 32, 128, 576, 512
@@ -324,39 +371,40 @@ def test_a_latent_pool_is_written_in_place_and_stays_compressed(
         S((slots, rows, width), BF16), S((slots, table), I32), lane,
         S((slots,), jnp.bool_), lane).compile()
     text = compiled.as_text()
-    assert _kernels(text, paged_attention.LATENT_KERNEL_NAME) == (
-        layers if rows == 1 else 0)
+    assert _latent_kernels(text) == (
+        {"decode": layers, "rows": 0} if rows == 1
+        else {"decode": 0, "rows": layers})
     assert not re.findall(
         r"= \(?bf16\[8225,16,640\][^=]*? (?:copy|copy-start|slice-start)\(",
         text)
     assert (compiled.memory_analysis().alias_size_in_bytes
             == layers * pages * page * 640 * 2)
-    keys = table * page
-    shapes = {tuple(int(d) for d in m.split(","))
-              for m in re.findall(r"(?:f32|bf16)\[([\d,]+)\]", text)}
-    full = sorted(d for d in (slots, rows, heads, keys) if d > 1)
-    assert not [s for s in shapes if sorted(d for d in s if d > 1) == full]
-    if rows > 1:
-        assert [s for s in shapes if s[-1] == keys and heads not in s]
+    assert not [s for s in _float_shapes(text) if table * page in s]
 
 
 def test_the_engine_decode_program_holds_the_latent_kernel(
         mosaic, monkeypatch, one_chip):
     """``ServingEngine``'s own two step programs for a toy of the
-    latent-attention family (a lane-wide latent, so that the kernel's
-    shape gate passes), lowered for the described chip from the engine's
-    ``_warm_args``: the decode program calls the kernel once a layer,
-    the mixed program not at all, and both alias the donated pool."""
+    latent-attention family (a lane-wide latent and 16 heads, so that
+    the kernel's shape gate passes), lowered for the described chip
+    from the engine's ``_warm_args``: the decode program calls the
+    kernel once a layer under the name ``paged_latent_attention_decode``
+    and the mixed program once a layer under
+    ``paged_latent_attention_rows``, neither calls the other's, both
+    alias the donated pool, and the mixed program has no float32 array
+    with an axis of a slot's whole table of keys (10 pages of 16: no
+    other size of the toy is 160)."""
     from paddle_tpu.models.pangu_moe import (PanguMoEForCausalLM,
                                              pangu_moe_tiny)
     from paddle_tpu.nn.functional import attention
     from paddle_tpu.serving import ServingEngine
     monkeypatch.setattr(attention, "_flash_backend_ok", lambda: True)
     model = PanguMoEForCausalLM(pangu_moe_tiny(
-        kv_lora_rank=128, dtype="bfloat16"))
+        kv_lora_rank=128, num_attention_heads=16, dtype="bfloat16"))
     model.eval()
     eng = ServingEngine(model, num_pages=64, page_size=16, max_slots=8,
-                        max_pages_per_slot=8, prefill_chunk=16)
+                        max_pages_per_slot=10, prefill_chunk=16)
+    layers = model.config.num_hidden_layers
     for name, step in (("decode", eng._decode_step),
                        ("mixed", eng._mixed_step)):
         args = jax.tree_util.tree_map(
@@ -364,11 +412,15 @@ def test_the_engine_decode_program_holds_the_latent_kernel(
                                            sharding=one_chip),
             eng._warm_args(name))
         compiled = step.lower(*args).compile()
-        assert _kernels(compiled.as_text(),
-                        paged_attention.LATENT_KERNEL_NAME) == (
-            model.config.num_hidden_layers if name == "decode" else 0)
+        text = compiled.as_text()
+        assert _latent_kernels(text) == (
+            {"decode": layers, "rows": 0} if name == "decode"
+            else {"decode": 0, "rows": layers})
         assert (compiled.memory_analysis().alias_size_in_bytes
                 >= sum(a.nbytes for e in eng.pool.pools for a in e))
+        if name == "mixed":
+            keys = eng.max_pages_per_slot * eng.page_size
+            assert not [s for s in _float_shapes(text, "f32") if keys in s]
 
 
 def test_the_scrub_zeroes_pages_of_a_donated_pool_in_place(one_chip):
