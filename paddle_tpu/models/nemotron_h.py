@@ -36,6 +36,7 @@ from ..core.dtypes import scoped_dtype_init
 from ..distributed.moe import HeldExpertsMoE, relu2
 from ..nn import functional as F
 from ..nn import initializer as I
+from ..nn.functional.ssm import fresh_slots
 from ..nn.module import Layer, Parameter
 from .llama import LlamaConfig, _rope_cache, apply_rotary_pos_emb
 
@@ -125,15 +126,6 @@ class NemotronHConfig:
         pages = ("pages", self.num_key_value_heads, self.head_dim)
         return [{MAMBA: state, ATTENTION: pages, MOE: None}[c]
                 for c in self.hybrid_override_pattern]
-
-
-def fresh_slots(seq_lens, active):
-    """The slots whose recurrent state starts from zero in this step:
-    those that start at position 0. A request is always (re)admitted at
-    position 0 (the engine keeps the prefix cache off for a model with
-    recurrent state), so whatever the slot's last tenant left is never
-    read."""
-    return active & (seq_lens == 0)
 
 
 class NemotronHMamba2(Layer):

@@ -1,14 +1,22 @@
-"""State-space (Mamba-2 / SSD) primitives, in the two forms a serving
-engine needs: a chunked scan over the rows a step brings, which starts
-from a carried state and returns the state after the last LIVE row, and
-the one-row recurrence of a decode step. Plain XLA; the state and every
-sum over it are float32.
+"""State-space primitives, in the two forms a serving engine needs: a
+scan over the rows a step brings, which starts from a carried state and
+returns the state after the last LIVE row, and the one-row recurrence of
+a decode step. The state and every sum over it are float32.
 
-Per head, with ``a_t = dt_t * A`` (``A < 0``): ``S_t = exp(a_t) S_{t-1}
-+ dt_t x_t B_t^T`` and ``y_t = S_t C_t + D x_t``. A row with ``dt = 0``
-leaves ``S`` exactly as it was (``exp(0) * S + 0``): that is how a dead
-row (padding beyond a slot's ``n_live``, or every row of an inactive
-slot) is kept out of the state.
+Mamba-2 / SSD (``ssd_chunk_scan``, ``ssd_step``; plain XLA). Per head,
+with ``a_t = dt_t * A`` (``A < 0``): ``S_t = exp(a_t) S_{t-1} + dt_t x_t
+B_t^T`` and ``y_t = S_t C_t + D x_t``. A row with ``dt = 0`` leaves
+``S`` exactly as it was (``exp(0) * S + 0``): that is how a dead row
+(padding beyond a slot's ``n_live``, or every row of an inactive slot)
+is kept out of the state.
+
+Mamba-1 (``selective_scan_rows``, ``selective_scan_step``): a decay for
+every channel ``c`` and state index ``j``, ``h_t[j, c] = exp(dt_t[c]
+A[c, j]) h_{t-1}[j, c] + dt_t[c] x_t[c] B_t[j]`` and ``y_t[c] = sum_j
+h_t[j, c] C_t[j] + D[c] x_t[c]``. It has no matrix form: the rows are
+walked one by one, on the TPU by the Pallas kernel of
+``ops/pallas/selective_scan.py``, elsewhere by a ``lax.scan``. The state
+is kept ``[slots, d_state, d_inner]``: the channels fill the lanes.
 """
 
 from __future__ import annotations
@@ -16,9 +24,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["causal_conv1d_window", "ssd_chunk_scan", "ssd_step"]
+__all__ = ["causal_conv1d_window", "fresh_slots", "selective_scan_rows",
+           "selective_scan_step", "ssd_chunk_scan", "ssd_step"]
 
 _HI = jax.lax.Precision.HIGHEST
+
+
+def fresh_slots(seq_lens, active):
+    """The slots whose recurrent state starts from zero in this step:
+    those that start at position 0. A request is always (re)admitted at
+    position 0 (the engine keeps the prefix cache off for a model with
+    recurrent state), so whatever the slot's last tenant left is never
+    read."""
+    return active & (seq_lens == 0)
 
 
 def causal_conv1d_window(x, window, weight, bias, n_live):
@@ -90,3 +108,59 @@ def ssd_step(x, dt, A, B, C, D, state):
     y = jnp.sum(new * C[:, :, None, None, :], axis=-1)         # [b, g, r, p]
     return (y.reshape(b, h, p) + x * D[None, :, None],
             new.reshape(b, h, p, n))
+
+
+def _kernel_backend_ok() -> bool:
+    """The Pallas route needs a real TPU backend; apart so that a test
+    can steer it (as ``attention._flash_backend_ok``)."""
+    return jax.default_backend() == "tpu"
+
+
+def selective_scan_step(x, dt, A, B, C, D, state):
+    """One row of the Mamba-1 recurrence. x, dt [b, d] (``dt = 0``
+    leaves the state as it was), A [d, n] (negative), B, C [b, n], D
+    [d], state [b, n, d]; float32. Returns y [b, d] and the new state.
+    One elementwise pass over a donated state, which XLA fuses with the
+    sum over ``n``; a Pallas kernel of the same pass made the decode
+    program slower (PERF.md, PR 37)."""
+    new = (jnp.exp(dt[:, None, :] * A.T[None]) * state
+           + (dt * x)[:, None, :] * B[:, :, None])
+    return jnp.sum(new * C[:, :, None], axis=1) + D * x, new
+
+
+def _selective_scan_rows_xla(x, dt, A, B, C, D, state, n_live):
+    """``selective_scan_rows`` as a ``lax.scan`` over the rows: never
+    the ``[b, k, d, n]`` tensors of the closed form."""
+    k = x.shape[1]
+    live = jnp.arange(k)[:, None] < n_live[None, :]             # [k, b]
+
+    def row(h, t):
+        x_t, dt_t, B_t, C_t, live_t = t
+        y, new = selective_scan_step(x_t, dt_t, A, B_t, C_t, D, h)
+        return (jnp.where(live_t[:, None, None], new, h),
+                jnp.where(live_t[:, None], y, 0.0))
+
+    h, y = jax.lax.scan(row, state, (
+        *(jnp.moveaxis(a, 1, 0) for a in (x, dt, B, C)), live))
+    return jnp.moveaxis(y, 0, 1), h
+
+
+def selective_scan_rows(x, dt, A, B, C, D, state, n_live):
+    """The Mamba-1 recurrence over the ``k`` rows a step brings, from a
+    carried state.
+
+    x, dt [b, k, d], A [d, n] (negative), B, C [b, k, n], D [d], state
+    [b, n, d] float32, n_live [b] int32: the first ``n_live`` rows of a
+    slot are live. Returns y [b, k, d] float32, exact zeros on dead
+    rows, and the state after each slot's last live row; a slot with no
+    live row keeps its state bit for bit. On the TPU the Pallas kernel
+    (``selective_scan_rows`` in a trace), elsewhere the ``lax.scan``."""
+    from ...ops.pallas import selective_scan as kernel
+    f32 = jnp.float32
+    args = (x.astype(f32), dt.astype(f32), A.astype(f32), B.astype(f32),
+            C.astype(f32), D.astype(f32), state.astype(f32),
+            n_live.astype(jnp.int32))
+    if _kernel_backend_ok() and kernel.kernel_applicable(x.shape,
+                                                         state.shape):
+        return kernel.selective_scan_tpu(*args)
+    return _selective_scan_rows_xla(*args)

@@ -106,7 +106,12 @@ def _interpret() -> bool:
 def kernel_applicable(q_shape, pool_shape) -> bool:
     """Shape gate for the kernel route (the caller falls back to the XLA
     gather path otherwise): head_dim must fill the lanes, the page the
-    sublanes, and q heads must group evenly over the cache kv heads."""
+    sublanes, and q heads must group evenly over the cache kv heads.
+    Nothing is asked of the size of a query group: its ``[g, d]`` tile
+    is the whole of the last two dimensions of the query block, which
+    Mosaic takes at any ``g`` (20 heads on ONE kv head compile and run:
+    multi-query attention; the wrapper hands that pool over as
+    ``[pages, page_size, d]``, see ``paged_attention_tpu``)."""
     b, s, h, d = q_shape
     _, ps, kvh, _ = pool_shape
     return (s == 1 and d % _LANES == 0 and ps % 8 == 0
@@ -140,8 +145,10 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
             jnp.int32, (1, page_size), 1)
         for n in range(kv_heads):
             q = q_ref[0, n].astype(jnp.float32)            # [g, d]
-            k = k_ref[0, :, n, :].astype(jnp.float32)      # [page_size, d]
-            v = v_ref[0, :, n, :].astype(jnp.float32)
+            # [page_size, d]; a one-head pool comes without its head axis
+            head = (0,) if len(k_ref.shape) == 3 else (0, slice(None), n)
+            k = k_ref[head].astype(jnp.float32)
+            v = v_ref[head].astype(jnp.float32)
             if quant:
                 # dequantize inside the page loop: int8 codes stream from
                 # HBM, the fp32 page materializes only in VMEM
@@ -207,10 +214,22 @@ def paged_attention_tpu(q, pool_k, pool_v, block_tables, seq_lens,
 
     kernel = functools.partial(_decode_kernel, page_size=ps, n_pages=M,
                                kv_heads=kvh, scale=scale, quant=quant)
+    page = (1, ps, kvh, d)
+    page_index = kv_index
+    if kvh == 1 and not quant:
+        # ONE kv head (multi-query attention): the compiler lays
+        # ``[pages, page_size, 1, d]`` out with the size-1 axis outside
+        # the tiles, a page being ``page_size x d`` in one piece, while a
+        # block whose last two dimensions are (1, d) asks for rows tiled
+        # alone: the operand would be a copy of the whole pool, every
+        # call. Without the axis the reshape is a bitcast and the block
+        # a whole page.
+        pool_k, pool_v = (p.reshape(-1, ps, d) for p in (pool_k, pool_v))
+        page, page_index = (1, ps, d), scale_index
     in_specs = [
         pl.BlockSpec((1, kvh, g, d), q_index),
-        pl.BlockSpec((1, ps, kvh, d), kv_index),
-        pl.BlockSpec((1, ps, kvh, d), kv_index),
+        pl.BlockSpec(page, page_index),
+        pl.BlockSpec(page, page_index),
     ]
     operands = [tables, lens, q4, pool_k, pool_v]
     if quant:

@@ -2109,6 +2109,14 @@ class ServingEngine:
                 page_size=self.page_size)
             tr.bump("latent_steps_live", live)
             tr.bump("latent_steps_dispatched", dispatched)
+        if tr.enabled and self._recurrent:
+            # the recurrent layers' padding: the rows of these lanes that
+            # carry a token (what a scan over the live rows walks)
+            # against the rows of the rectangle, in every layer that
+            # keeps a state
+            layers = len(self.pool.state)
+            tr.bump("scan_rows_live", int(n_live.sum()) * layers)
+            tr.bump("scan_rows_dispatched", S * K * layers)
         lanes = (jnp.asarray(toks), jnp.asarray(tables),
                  jnp.asarray(seq_lens), jnp.asarray(active),
                  jnp.asarray(n_live), jnp.asarray(forced),

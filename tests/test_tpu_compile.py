@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.pallas import (flash_attention, fused_norm, moe_routing,
-                                   paged_attention)
+                                   paged_attention, selective_scan)
 
 BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -66,7 +66,8 @@ def no_persistent_cache():
 def mosaic(monkeypatch, no_persistent_cache):
     """Steer every kernel module off interpret mode IN THE TEST (the
     default backend here is the CPU; each module binds its own name)."""
-    for mod in (flash_attention, paged_attention, fused_norm, moe_routing):
+    for mod in (flash_attention, paged_attention, fused_norm, moe_routing,
+                selective_scan):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
 
@@ -84,8 +85,10 @@ def _kernels(text: str, name: str) -> int:
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("heads,kv_heads", [(32, 8), (16, 8), (32, 2)],
-                         ids=["llama3_8b", "serving_bench", "nemotron_h"])
+@pytest.mark.parametrize("heads,kv_heads",
+                         [(32, 8), (16, 8), (32, 2), (20, 1)],
+                         ids=["llama3_8b", "serving_bench", "nemotron_h",
+                              "jamba"])
 def test_paged_attention_compiles(mosaic, one_chip, heads, kv_heads, quant):
     slots, page, d, pages, table = 8, 16, 128, 512, 32
 
@@ -441,3 +444,95 @@ def test_the_scrub_zeroes_pages_of_a_donated_pool_in_place(one_chip):
         compiled.as_text())
     assert (compiled.memory_analysis().alias_size_in_bytes
             == 2 * layers * 2 * int(np.prod(shape)))
+
+
+def test_multi_query_pages_reach_the_kernel_without_a_copy(mosaic, one_chip):
+    """``paged_attention_decode`` at the shapes of ``jamba2_3b_serve``: 20
+    query heads on ONE K/V head, pool ``bf16[8257,16,1,128]``. Mosaic
+    takes the 20-row query tile as it is. The compiler keeps a size-1
+    head axis outside the tiles (a page is 16 x 128 in one piece, the
+    pool unpadded), and a block whose last two dimensions are (1, 128)
+    asked for another tiling: the operand was a copy of the whole pool
+    (68 MB of temporaries a call) until the wrapper handed the pool over
+    as ``[pages, 16, 128]``, a bitcast."""
+    def S(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = S((8257, 16, 1, 128), BF16)
+    q = S((64, 1, 20, 128), BF16)
+    assert paged_attention.kernel_applicable(q.shape, pool.shape)
+    compiled = jax.jit(paged_attention.paged_attention_tpu).lower(
+        q, pool, pool, S((64, 129), I32), S((64,), I32)).compile()
+    text = compiled.as_text()
+    assert _kernels(text, paged_attention.KERNEL_NAME) == 1
+    assert not re.findall(
+        r"= \(?bf16\[8257,16,1,128\][^=]*? (?:copy|copy-start|slice-start)\(",
+        text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_the_selective_scan_kernel_compiles_at_the_cell_shapes(mosaic,
+                                                               one_chip):
+    """``selective_scan_rows`` alone at the shapes of
+    ``jamba2_3b_serve``'s mixed program (64 slots x 64 rows x 5120
+    channels, 16 state indices): Mosaic takes its dynamic row loads, its
+    loops over the live rows and its request for VMEM; the donated state
+    is aliased to the result and nothing the size of ``[rows, channels,
+    state]`` exists."""
+    def S(shape, dt=F32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    b, k, d, n = 64, 64, 5120, 16
+    assert selective_scan.kernel_applicable((b, k, d), (b, n, d))
+    compiled = jax.jit(selective_scan.selective_scan_tpu,
+                       donate_argnums=(6,)).lower(
+        S((b, k, d)), S((b, k, d)), S((d, n)), S((b, k, n)), S((b, k, n)),
+        S((d,)), S((b, n, d)), S((b,), I32)).compile()
+    text = compiled.as_text()
+    assert _kernels(text, selective_scan.KERNEL_NAME) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes == b * n * d * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    assert not [s for s in _float_shapes(text) if len(s) == 4]
+
+
+def test_the_engine_s_programs_for_a_jamba_hold_both_kernels(
+        mosaic, monkeypatch, one_chip):
+    """``ServingEngine``'s own two step programs for a toy of the Jamba
+    family, lowered for the described chip from the engine's
+    ``_warm_args``: the decode program calls ``paged_attention_decode``
+    once an attention layer (2 query heads on ONE K/V head here, 20 in
+    the cell: no fall back to the XLA gather goes unnoticed) and no scan
+    kernel (its one-row recurrence is a fused XLA pass); the mixed
+    program calls ``selective_scan_rows`` once a Mamba layer and holds
+    no float array with both a channel and a state axis beside the rows;
+    both alias the donated pages and state."""
+    from paddle_tpu.models.jamba import JambaForCausalLM, jamba_tiny
+    from paddle_tpu.nn.functional import attention, ssm
+    from paddle_tpu.serving import ServingEngine
+    monkeypatch.setattr(attention, "_flash_backend_ok", lambda: True)
+    monkeypatch.setattr(ssm, "_kernel_backend_ok", lambda: True)
+    cfg = jamba_tiny(dtype="bfloat16")
+    model = JambaForCausalLM(cfg)
+    model.eval()
+    eng = ServingEngine(model, num_pages=64, page_size=16, max_slots=8,
+                        max_pages_per_slot=10, prefill_chunk=16)
+    kinds = [cfg.is_attention(i) for i in range(cfg.num_hidden_layers)]
+    d, n = cfg.mamba_d_inner, cfg.mamba_d_state
+    for name, step in (("decode", eng._decode_step),
+                       ("mixed", eng._mixed_step)):
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            eng._warm_args(name))
+        compiled = step.lower(*args).compile()
+        text = compiled.as_text()
+        assert _kernels(text, paged_attention.KERNEL_NAME) == (
+            kinds.count(True) if name == "decode" else 0)
+        assert _kernels(text, selective_scan.KERNEL_NAME) == (
+            kinds.count(False) if name == "mixed" else 0)
+        held = (sum(a.nbytes for e in eng.pool.pools for a in e)
+                + sum(a.nbytes for e in eng.pool.state for a in e))
+        assert compiled.memory_analysis().alias_size_in_bytes >= held
+        if name == "mixed":
+            assert not [s for s in _float_shapes(text, "f32")
+                        if len(s) == 4 and d in s and n in s]
