@@ -29,6 +29,14 @@ d_inner]``: the channels fill the lanes. A slot that starts at position
 0 starts from zero state (``fresh_slots``); a mixed step advances a
 slot's state by its ``n_live`` rows and no more; an inactive slot's
 state is untouched. No training path.
+
+The config declares ``packed_rows``: the mixed step may call
+``forward(..., rows=(T, sel))``, and the row-wise layers (embedding,
+norms, MLP, the Mamba ``in_proj`` and gated ``out_proj``) then run on
+the ``T`` live rows packed (``F.packed_rows``) instead of the ``[slots,
+k]`` rectangle; the conv, ``x_proj``, ``dt_proj``, the scan and the
+attention layers still take the rectangle. The head scores only the
+rows ``sel`` [slots, R] names (SERVING.md "Packed rows").
 """
 
 from __future__ import annotations
@@ -112,6 +120,11 @@ class JambaConfig:
     def is_attention(self, i: int) -> bool:
         return i % self.attn_layer_period == self.attn_layer_offset
 
+    # the mixed step may hand ``JambaForCausalLM.forward`` its live rows
+    # packed (``rows=``): every layer that is not row-wise takes the
+    # rectangle back from ``F.unpack_rows``
+    packed_rows = True
+
     def cache_layers(self) -> list:
         """What each layer keeps for a request being served, in layer
         order (``serving.kv_cache`` builds the pool from it): ``("pages",
@@ -148,18 +161,23 @@ class JambaMambaMixer(Layer):
         self.D = Parameter(I.Constant(1.0)((d,), jnp.float32))
         self.out_proj = nn.Linear(d, c.hidden_size, bias_attr=bias)
 
-    def forward(self, u, state=None, n_live=None, fresh=None):
-        """u [b, k, hidden]. ``state`` = (conv window [b, w - 1, d], SSM
-        state [b, n, d]) or None (a sequence from its start); ``n_live``
-        [b] the rows of each slot that count (0 for an inactive slot),
-        ``fresh`` [b] the slots that start from zero. Returns the
-        mixer's output, and the new state where one came."""
+    def forward(self, u, state=None, n_live=None, fresh=None, rows=None):
+        """u [b, k, hidden], or [T, hidden] packed where ``rows`` (a
+        ``F.PackedRows`` over the ``[b, k]`` rectangle) is given.
+        ``state`` = (conv window [b, w - 1, d], SSM state [b, n, d]) or
+        None (a sequence from its start); ``n_live`` [b] the rows of each
+        slot that count (0 for an inactive slot), ``fresh`` [b] the slots
+        that start from zero. Returns the mixer's output, shaped as
+        ``u``, and the new state where one came."""
         c = self.config
-        b, k, _ = u.shape
+        b, k = u.shape[:2] if rows is None else rows.shape[1:]
         d, n, r = c.mamba_d_inner, c.mamba_d_state, c.mamba_dt_rank
         f32 = jnp.float32
         with jax.named_scope("in_proj"):
             x, z = jnp.split(self.in_proj(u), 2, axis=-1)
+        if rows is not None:
+            # the conv and the scan walk a slot's rows in order
+            x = F.unpack_rows(x, rows)
         if state is None:
             window = jnp.zeros((b, c.mamba_d_conv - 1, d), x.dtype)
             ssm = jnp.zeros((b, n, d), f32)
@@ -192,6 +210,8 @@ class JambaMambaMixer(Layer):
             else:
                 y, new_ssm = F.selective_scan_rows(
                     x, dt, A, B, C, self.D, ssm, n_live)
+        if rows is not None:
+            y = F.pack_rows(y, rows)
         with jax.named_scope("out_proj"):
             out = self.out_proj(
                 (y * jax.nn.silu(z.astype(f32))).astype(u.dtype))
@@ -278,19 +298,23 @@ class JambaForCausalLM(Layer):
                                      bias_attr=False)
 
     def forward(self, input_ids, attn_mask=None, cache=None,
-                position_offset=0, paged=None, lora=None):
+                position_offset=0, paged=None, lora=None, rows=None):
         """Cache-free: ``forward(input_ids)`` -> logits [b, s, vocab].
         Served: ``forward(toks, None, cache, 0, (tables, seq_lens,
         active[, n_live]))`` -> (logits, the new ``HybridCache``; the
-        model has no expert layer, so its ``counts`` stay zero)."""
+        model has no expert layer, so its ``counts`` stay zero).
+
+        ``rows=(T, sel)`` (the mixed step, where ``sum(n_live) <= T``):
+        the row-wise layers run on the live rows packed into ``T``, and
+        the first result is ``(logits [b, R, vocab] of the rows sel [b,
+        R] names, ok [b])``: ``ok`` is False for a slot with a
+        non-finite live row after the final norm."""
         if attn_mask is not None or lora is not None:
             raise NotImplementedError(
                 "JambaForCausalLM takes no attention mask and no LoRA")
         m = self.model
-        with jax.named_scope("embed"):
-            x = m.embed_tokens(input_ids)
         b, k = input_ids.shape
-        n_live = fresh = None
+        n_live = fresh = pack = None
         kv, states = [], []
         if cache is not None:
             _, seq_lens, active = paged[:3]
@@ -299,6 +323,11 @@ class JambaForCausalLM(Layer):
             ).astype(jnp.int32)
             fresh = fresh_slots(seq_lens, active)
             kv_in, state_in = iter(cache.kv), iter(cache.state)
+            if rows is not None:
+                pack = F.packed_rows(n_live, k, rows[0])
+                input_ids = F.pack_rows(input_ids, pack)
+        with jax.named_scope("embed"):
+            x = m.embed_tokens(input_ids)
         for block in m.layers:
             with jax.named_scope("norm"):
                 u = block.input_layernorm(x)
@@ -306,8 +335,13 @@ class JambaForCausalLM(Layer):
                 with jax.named_scope("attn"):
                     if cache is None:
                         out = block.self_attn(u)
-                    else:
+                    elif pack is None:
                         out, pair = block.self_attn(u, next(kv_in), paged)
+                        kv.append(pair)
+                    else:
+                        out, pair = block.self_attn(
+                            F.unpack_rows(u, pack), next(kv_in), paged)
+                        out = F.pack_rows(out, pack)
                         kv.append(pair)
             else:
                 with jax.named_scope("ssm"):
@@ -315,7 +349,7 @@ class JambaForCausalLM(Layer):
                         out = block.mamba(u)
                     else:
                         out, st = block.mamba(u, next(state_in), n_live,
-                                              fresh)
+                                              fresh, pack)
                         states.append(st)
             x = x + out
             with jax.named_scope("norm"):
@@ -324,12 +358,21 @@ class JambaForCausalLM(Layer):
                 x = x + block.feed_forward(u)
         with jax.named_scope("norm"):
             x = m.final_layernorm(x)
+        if pack is not None:
+            # the finite sentinel over every live row, before the head (a
+            # finite normed row times finite weights stays finite); the
+            # head then scores the sampled rows alone
+            bad = ~jnp.all(jnp.isfinite(x.astype(jnp.float32)), axis=-1)
+            ok = ~jnp.any(F.unpack_rows(bad, pack), axis=1)
+            x = x[jnp.minimum(pack.start[:, None] + rows[1], rows[0] - 1)]
         with jax.named_scope("lm_head"):
             logits = (x @ m.embed_tokens.weight.T
                       if self.config.tie_word_embeddings
                       else self.lm_head(x))
         if cache is None:
             return logits
+        if pack is not None:
+            logits = (logits, ok)
         return logits, cache._replace(
             kv=kv, state=states, counts=jnp.zeros((3,), jnp.int32))
 

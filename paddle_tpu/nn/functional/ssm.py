@@ -17,15 +17,26 @@ h_t[j, c] C_t[j] + D[c] x_t[c]``. It has no matrix form: the rows are
 walked one by one, on the TPU by the Pallas kernel of
 ``ops/pallas/selective_scan.py``, elsewhere by a ``lax.scan``. The state
 is kept ``[slots, d_state, d_inner]``: the channels fill the lanes.
+
+A step program that hands ``max_slots x k`` rows to its layers can run
+the row-wise ones (embedding, norms, projections, MLP) on the live rows
+alone, packed: ``packed_rows`` maps the rows of each slot's ``n_live``
+to a ``[T, ...]`` array and back (``pack_rows``, ``unpack_rows``); the
+layers that see a slot's rows in order (the conv window, the scan,
+attention) still take the rectangle.
 """
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
-__all__ = ["causal_conv1d_window", "fresh_slots", "selective_scan_rows",
-           "selective_scan_step", "ssd_chunk_scan", "ssd_step"]
+__all__ = ["PackedRows", "causal_conv1d_window", "fresh_slots",
+           "pack_rows", "packed_rows", "selective_scan_rows",
+           "selective_scan_step", "ssd_chunk_scan", "ssd_step",
+           "unpack_rows"]
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -37,6 +48,61 @@ def fresh_slots(seq_lens, active):
     recurrent state), so whatever the slot's last tenant left is never
     read."""
     return active & (seq_lens == 0)
+
+
+class PackedRows(NamedTuple):
+    """Where each live row of a ``[slots, k]`` rectangle sits in a
+    packed ``[T, ...]`` array (``packed_rows``). ``src`` [T] is the flat
+    rectangle row ``slot * k + column`` of packed row ``t`` (``slots *
+    k``, out of range, past the live rows), ``dst`` [slots * k] the
+    packed row of each rectangle row (``T`` where the row is dead),
+    ``start`` [slots] a slot's first packed row."""
+    src: jax.Array
+    dst: jax.Array
+    start: jax.Array
+
+    @property
+    def shape(self) -> tuple[int, int, int]:
+        """(T, slots, k): the packed rows and the rectangle's two axes."""
+        return (self.src.shape[0], self.start.shape[0],
+                self.dst.shape[0] // self.start.shape[0])
+
+
+def packed_rows(n_live, k: int, rows: int) -> PackedRows:
+    """The row map of a step whose slot ``s`` brings its first
+    ``n_live[s]`` rows of ``k``, packed slot after slot into ``rows``
+    rows (``sum(n_live) <= rows``, which the caller guarantees: a live
+    row past ``rows`` is lost). Built on the device from ``n_live``
+    alone: slot ``s`` starts at ``cumsum(n_live) - n_live``, packed row
+    ``t`` belongs to ``searchsorted(cumsum(n_live), t, "right")``."""
+    S = n_live.shape[0]
+    n_live = n_live.astype(jnp.int32)
+    end = jnp.cumsum(n_live)
+    start = end - n_live
+    t = jnp.arange(rows, dtype=jnp.int32)
+    slot = jnp.searchsorted(end, t, side="right").astype(jnp.int32)
+    col = t - start[jnp.minimum(slot, S - 1)]
+    src = jnp.where(t < end[-1], slot * k + col, S * k)
+    j = jnp.arange(k, dtype=jnp.int32)
+    dst = jnp.where(j[None, :] < n_live[:, None],
+                    start[:, None] + j[None, :], rows).reshape(-1)
+    return PackedRows(src, dst, start)
+
+
+def pack_rows(a, rows: PackedRows):
+    """``a`` [slots, k, ...] -> [T, ...]: the live rows, slot after
+    slot; the rows past them are zero."""
+    _, S, k = rows.shape
+    flat = a.reshape((S * k,) + a.shape[2:])
+    return flat.at[rows.src].get(mode="fill", fill_value=0)
+
+
+def unpack_rows(p, rows: PackedRows):
+    """``p`` [T, ...] -> [slots, k, ...]: each live row back at its
+    slot and column, the dead rows zero."""
+    _, S, k = rows.shape
+    return p.at[rows.dst].get(mode="fill", fill_value=0).reshape(
+        (S, k) + p.shape[1:])
 
 
 def causal_conv1d_window(x, window, weight, bias, n_live):

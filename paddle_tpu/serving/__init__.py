@@ -22,7 +22,7 @@ streams.
 from .engine import BrownoutConfig, ServingEngine
 from .errors import (AdmissionShedError, EngineDrainingError,
                      FleetOverloadedError, LatentCacheError,
-                     QueueFullError,
+                     PackedRowsError, QueueFullError,
                      RecurrentStateError, ReplicaSpawnError,
                      RequestTooLargeError,
                      SchedulerStalledError, ServingError, StaleEpochError,
@@ -68,7 +68,7 @@ __all__ = [
     "ServingError", "QueueFullError", "RequestTooLargeError",
     "SchedulerStalledError", "EngineDrainingError", "FleetOverloadedError",
     "TPConfigError", "AdmissionShedError", "RecurrentStateError",
-    "LatentCacheError",
+    "LatentCacheError", "PackedRowsError",
     "TransportError", "StaleEpochError", "ReplicaSpawnError",
     "Transport", "LoopbackTransport", "ChaosTransport", "EngineServer",
     "Message", "deterministic_jitter",

@@ -78,8 +78,9 @@ from ..distributed import fault as _fault
 from ..observability.trace import PROFILE_TRACER
 from ..ops.pallas.paged_attention import latent_grid_steps
 from .errors import (AdmissionShedError, EngineDrainingError,
-                     LatentCacheError, QueueFullError, RecurrentStateError,
-                     RequestTooLargeError, SchedulerStalledError)
+                     LatentCacheError, PackedRowsError, QueueFullError,
+                     RecurrentStateError, RequestTooLargeError,
+                     SchedulerStalledError)
 from .kv_cache import HybridCache, KVCachePool, declared_cache_layers
 from .metrics import ServingMetrics
 from .scheduler import FINISHED, Request, SamplingParams, Scheduler
@@ -1698,6 +1699,16 @@ class ServingEngine:
         model = self.model
         ps = self.page_size
         R = self.scheduler.spec_k
+        # the rows the dense layers compute a dispatch: the rectangle, or
+        # T packed live rows where the model takes them (its config's
+        # ``packed_rows``, honoured by the hybrid body) and the engine's
+        # per-step token bound is narrower (SERVING.md "Packed rows")
+        S, K = self.max_slots, self._chunk
+        T = _packed_width(S, K, R, self.scheduler.prefill_token_budget)
+        if not (self._hybrid and T < S * K
+                and getattr(model.config, "packed_rows", False)):
+            T = None
+        self._mixed_rows = S * K if T is None else T
 
         def mixed_step(state, pools, toks, tables, seq_lens, active,
                        n_live, forced, temps, top_ps, greedy, seeds,
@@ -1715,9 +1726,14 @@ class ServingEngine:
             def mixed_step_state(state, pools, rstate, toks, tables,
                                  seq_lens, active, n_live, forced, temps,
                                  top_ps, greedy, seeds, counts):
+                # packed: the model scores only the rows the tail samples
+                # and hands back (their logits, the per-slot sentinel)
+                kw = ({} if T is None else
+                      {"rows": (T, _mixed_sel(n_live, forced, R, K))})
                 (logits, cache), _ = functional_call(
                     model, state, toks, None, HybridCache(pools, rstate),
-                    0, (tables, seq_lens, active, n_live), training=False)
+                    0, (tables, seq_lens, active, n_live), training=False,
+                    **kw)
                 # no row is ever rejected (speculation is refused), so
                 # the tail's rollback zeroes nothing: of the pages only
                 samp, m, ok, kv = _mixed_tail(
@@ -2098,6 +2114,19 @@ class ServingEngine:
                 n_live[slot] = 1 + len(d)
                 n_drafted[slot] = len(d)
                 counts[slot] = len(req.tokens)
+        live_rows = int(n_live.sum())
+        if live_rows > self._mixed_rows:
+            raise PackedRowsError(
+                f"a mixed dispatch of {live_rows} live rows where the "
+                f"program computes {self._mixed_rows}: the width follows "
+                f"prefill_token_budget as it was when the engine was "
+                f"built, and the budget is now "
+                f"{sched.prefill_token_budget}")
+        if tr.enabled:
+            # the dense layers' padding: rows that carry a token against
+            # the rows they compute (the rectangle, or T packed)
+            tr.bump("mixed_rows_live", live_rows)
+            tr.bump("mixed_rows_computed", self._mixed_rows)
         if tr.enabled and self._latent:
             # the attention core's padding, counted where the work is
             # handed over: the grid steps a layer that the rows kernel
@@ -2370,35 +2399,56 @@ class ServingEngine:
                        "finish_reason": reason})
 
 
+def _packed_width(S, K, R, budget) -> int:
+    """The most rows a mixed dispatch can carry, rounded up to a
+    multiple of 8: ``R`` (``spec_k``) rows for each decoding slot and
+    ``max(1, budget)`` chunk rows (``_admit`` hands ``_run_batch`` at most
+    the prefill budget; brownout only shrinks it, and the verify reserve
+    is taken out of it), or one whole single-slot pass of the unchunked
+    prefill; never more than the rectangle."""
+    T = min(S * K, max(K, S * R + max(1, budget)))
+    return -(-T // 8) * 8
+
+
+def _mixed_sel(n_live, forced, R, K):
+    """The ``R`` rows ``[S, R]`` of each slot the mixed program samples:
+    the host can emit, per slot, only a chunk's last live row (on its
+    final chunk) or a verify slot's rows 0..m, m <= R - 1 (drafts are
+    capped at spec_k - 1); the chunk's in column 0."""
+    return jnp.clip(jnp.where(forced, n_live - 1, 0)[:, None]
+                    + jnp.arange(R)[None, :], 0, K - 1)
+
+
 def _mixed_tail(logits, pools, toks, tables, seq_lens, active, n_live,
                 forced, temps, top_ps, greedy, seeds, counts, R, ps,
                 stacked):
-    """Everything the mixed program does after the logits ``[S, K, V]``:
-    the finite sentinel, the samples ``[S, R]``, the accepted count and
-    the in-program rollback. ``R`` is the engine's ``spec_k``;
-    ``stacked`` addresses the pipeline pool's ``[L, pages, ...]``
-    layout."""
-    S, K, V = logits.shape
+    """Everything the mixed program does after the logits ``[S, K, V]``
+    (or, from a model that took its rows packed, the pair of the sampled
+    rows' logits ``[S, R, V]`` and the per-slot sentinel): the finite
+    sentinel, the samples ``[S, R]``, the accepted count and the
+    in-program rollback. ``R`` is the engine's ``spec_k``; ``stacked``
+    addresses the pipeline pool's ``[L, pages, ...]`` layout."""
+    S, K = toks.shape
     rows = jnp.arange(K)
     live = rows[None, :] < n_live[:, None]                    # [S, K]
-    # per-slot poison sentinel over the LIVE rows only (padded rows read
-    # scratch and may be anything)
-    ok = jnp.all(jnp.where(live[..., None],
-                           jnp.isfinite(logits.astype(jnp.float32)),
-                           True), axis=(1, 2))
+    if isinstance(logits, tuple):
+        picked, ok = logits
+    else:
+        # per-slot poison sentinel over the LIVE rows only (padded rows
+        # read scratch and may be anything)
+        ok = jnp.all(jnp.where(live[..., None],
+                               jnp.isfinite(logits.astype(jnp.float32)),
+                               True), axis=(1, 2))
     with jax.named_scope("sampler"):
-        # the host can emit, per slot, only a chunk's last live row (on
-        # its final chunk) or a verify slot's rows 0..m, m <= R - 1
-        # (drafts are capped at spec_k - 1): gather those R rows, the
-        # chunk's in column 0, and sample them alone. Each keeps its
-        # ORIGINAL row index in its key; logits stay in the model dtype
-        # so argmax/softmax see the same bits the 1-token decode step
-        # would
-        sel = jnp.clip(jnp.where(forced, n_live - 1, 0)[:, None]
-                       + jnp.arange(R)[None, :], 0, K - 1)    # [S, R]
-        picked = jnp.take_along_axis(logits, sel[:, :, None], axis=1)
+        # gather the R rows the host can emit and sample them alone.
+        # Each keeps its ORIGINAL row index in its key; logits stay in
+        # the model dtype so argmax/softmax see the same bits the
+        # 1-token decode step would
+        sel = _mixed_sel(n_live, forced, R, K)                # [S, R]
+        if not isinstance(logits, tuple):
+            picked = jnp.take_along_axis(logits, sel[:, :, None], axis=1)
         samp = _sample_rows(
-            picked.reshape(S * R, V),
+            picked.reshape(S * R, -1),
             jnp.repeat(temps, R), jnp.repeat(top_ps, R),
             jnp.repeat(greedy, R), jnp.repeat(seeds, R),
             (counts[:, None] + sel).reshape(-1),

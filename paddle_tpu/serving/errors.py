@@ -66,6 +66,12 @@ router that acts on it is :class:`paddle_tpu.serving.fleet.FleetRouter`
   hand-off, speculation, LoRA, a sharded pool) was asked of a pool or
   an engine whose model keeps a latent cache (SERVING.md "Models with a
   latent cache"). NOT retryable.
+- :class:`PackedRowsError` — a mixed dispatch would carry more live
+  rows than the width its program packs them into (SERVING.md "Packed
+  rows"): the width follows ``prefill_token_budget`` as it was when the
+  engine was built, so only a budget raised since can trip it. Raised
+  on the host before the dispatch; no row is ever dropped. NOT
+  retryable: the engine's own configuration is at fault.
 - :class:`TransportError` — a fleet wire message failed its blake2b
   digest re-verify at receive (``serving/transport.py``): the payload
   was corrupted in flight. The message is dropped and counted, never
@@ -91,7 +97,7 @@ from __future__ import annotations
 __all__ = ["ServingError", "QueueFullError", "RequestTooLargeError",
            "SchedulerStalledError", "EngineDrainingError",
            "FleetOverloadedError", "TPConfigError", "AdmissionShedError",
-           "RecurrentStateError", "LatentCacheError",
+           "RecurrentStateError", "LatentCacheError", "PackedRowsError",
            "TransportError", "StaleEpochError", "ReplicaSpawnError"]
 
 
@@ -177,6 +183,16 @@ class LatentCacheError(ServingError, ValueError):
     heads. Raised at construction or at the call that asks.
 
     Not retryable: homogeneous replicas all refuse identically."""
+
+    retryable = False
+
+
+class PackedRowsError(ServingError, ValueError):
+    """A mixed dispatch's live rows outnumber the rows its program packs
+    them into: ``prefill_token_budget`` was raised after the engine was
+    built. Raised before the dispatch, never a row silently dropped.
+
+    Not retryable: the engine's configuration is at fault."""
 
     retryable = False
 

@@ -323,6 +323,167 @@ def test_a_traced_engine_counts_the_scan_s_live_rows(model):
     assert c["state_slots_live"] > 0 and not c.get("expert_rows_routed")
 
 
+# -- packed rows (SERVING.md "Packed rows") ------------------------------------
+
+# 8 slots of 16 rows under a prefill budget of 32: T = 8 + 32 = 40 of 128
+PACKED = dict(max_slots=8, num_pages=128, prefill_token_budget=32)
+
+
+def test_the_row_map_packs_the_live_rows_slot_after_slot():
+    n_live = jnp.asarray([3, 0, 1, 4], jnp.int32)
+    rows = F.packed_rows(n_live, 4, 10)
+    assert rows.shape == (10, 4, 4)
+    assert np.asarray(rows.start).tolist() == [0, 3, 3, 4]
+    assert np.asarray(rows.src).tolist() == [0, 1, 2, 8, 12, 13, 14, 15,
+                                             16, 16]
+    assert np.asarray(rows.dst).tolist() == [0, 1, 2, 10, 10, 10, 10, 10,
+                                             3, 10, 10, 10, 4, 5, 6, 7]
+    a = jnp.arange(1, 17, dtype=jnp.float32).reshape(4, 4)
+    packed = F.pack_rows(a, rows)
+    assert np.asarray(packed).tolist() == [1, 2, 3, 9, 13, 14, 15, 16, 0, 0]
+    back = np.asarray(F.unpack_rows(packed, rows))
+    live = np.arange(4)[None, :] < np.asarray(n_live)[:, None]
+    assert np.array_equal(back, np.where(live, np.asarray(a), 0))
+
+
+def test_packed_rows_give_the_rectangle_s_logits_states_and_pages(model):
+    """One mixed call two ways over the same pools: chunk slots (a fresh
+    one, a continued one, a short fresh one), decode slots and inactive
+    slots side by side, 34 live rows of 128 packed into 40. The sampled
+    rows' logits, the new states and the K/V rows written agree to 1e-5
+    (the rows reach an attention layer through products over 40 rows
+    instead of 128: another order of sums on the CPU); every page this
+    step did not write keeps its bits (page 0 is scratch: dead rows
+    write there)."""
+    from paddle_tpu.serving.engine import _mixed_sel
+    a, b = (Slots(model, engine(model, **PACKED)) for _ in range(2))
+    history = {1: prompt(14, 30), 3: prompt(9, 31), 4: prompt(16, 32),
+               6: prompt(3, 33)}
+    for sl in (a, b):
+        sl.run(history, width=16)
+    rows = {0: prompt(16, 34), 1: prompt(1, 35), 3: prompt(10, 36),
+            4: prompt(1, 37), 6: prompt(1, 38), 7: prompt(5, 39)}
+    S, K, T = 8, 16, 40
+    toks = np.zeros((S, K), np.int32)
+    active = np.zeros((S,), bool)
+    n_live = np.zeros((S,), np.int32)
+    for s, t in rows.items():
+        toks[s, :len(t)] = t
+        active[s], n_live[s] = True, len(t)
+    forced = jnp.asarray([s in (0, 3, 7) for s in range(S)])
+    sel = _mixed_sel(jnp.asarray(n_live), forced, 1, K)
+    out = []
+    for sl, kw in ((a, {}), (b, {"rows": (T, sel)})):
+        logits, cache = model(
+            jnp.asarray(toks), None, HybridCache(sl.pool.pools, sl.pool.state),
+            0, (jnp.asarray(sl.tables), jnp.asarray(sl.lens),
+                jnp.asarray(active), jnp.asarray(n_live)), **kw)
+        out.append((logits, cache))
+    (rect, ca), ((picked, ok), cb) = out
+    assert picked.shape == (S, 1, 256) and np.asarray(ok).all()
+    want = np.take_along_axis(np.asarray(rect), np.asarray(sel)[:, :, None],
+                              axis=1)
+    for s in rows:
+        assert np.abs(np.asarray(picked[s]) - want[s]).max() < 1e-5
+    assert np.abs(want).max() > 0.1
+    for la, lb in zip(ca.state, cb.state):
+        for x, y in zip(la, lb):
+            assert np.abs(np.asarray(x) - np.asarray(y)).max() < 1e-5
+    written = {int(b.tables[s, (b.lens[s] + j) // 16])
+               for s, t in rows.items() for j in range(len(t))}
+    kept = [p for p in range(1, b.pool.pools[0][0].shape[0])
+            if p not in written]
+    for pa, pb in zip(ca.kv, cb.kv):
+        for x, y in zip(pa, pb):
+            x, y = np.asarray(x), np.asarray(y)
+            assert np.abs(x[1:] - y[1:]).max() < 1e-5
+            assert np.array_equal(x[kept], y[kept])
+
+
+def _served(model, budget, tracer=None):
+    eng = engine(model, **dict(PACKED, prefill_token_budget=budget),
+                 tracer=tracer)
+    reqs = [(prompt(37 - 3 * i, 40 + i), 4 + i) for i in range(10)]
+    rids = [eng.add_request(p, n) for p, n in reqs]
+    out = eng.run_to_completion()
+    return eng, reqs, [out[r] for r in rids]
+
+
+def test_packed_and_rectangle_engines_serve_the_reference_s_streams(cfg,
+                                                                    model):
+    """Ten requests on 8 slots: an engine that packs (budget 32, T 40)
+    and one that computes the rectangle (budget 128 = 8 x 16) hand out
+    the same greedy streams, the reference's best tokens, from one
+    mixed program each."""
+    packed, reqs, a = _served(model, 32)
+    rect, _, b = _served(model, 128)
+    assert (packed._mixed_rows, rect._mixed_rows) == (40, 128)
+    assert a == b
+    gaps, _ = ref.serve_gaps(SEED, cfg, [(p, o) for (p, _), o in zip(reqs, a)])
+    assert max(float(g.max()) for g in gaps) < TOL
+    for eng in (packed, rect):
+        assert eng.mixed_program_count() == 1
+        assert eng.step_program_counts() == {"decode": 1, "mixed": 1}
+
+
+@pytest.mark.parametrize("budget,rows", [(32, 40), (128, 128)],
+                         ids=["packed", "rectangle"])
+def test_a_traced_engine_counts_the_rows_the_dense_layers_compute(
+        model, budget, rows):
+    """``mixed_rows_computed`` grows by T a mixed dispatch where the
+    program packs and by max_slots x prefill_chunk where it does not;
+    ``mixed_rows_live`` by the rows that carry a token, the same rows
+    ``scan_rows_live`` counts once a recurrent layer."""
+    from paddle_tpu.observability.trace import Tracer
+    tr = Tracer()
+    eng, _, _ = _served(model, budget, tr)
+    c = tr.counters
+    assert c["mixed_steps"] > 0
+    assert c["mixed_rows_computed"] == c["mixed_steps"] * rows
+    assert c["mixed_rows_live"] * len(eng.pool.state) == c["scan_rows_live"]
+    assert 0 < c["mixed_rows_live"] <= c["mixed_rows_computed"]
+
+
+def test_a_budget_raised_after_construction_trips_the_guard(model):
+    """T follows the budget the engine was built with: live rows beyond
+    it raise before the dispatch, and no row is dropped in silence."""
+    from paddle_tpu.serving.errors import PackedRowsError
+    eng = engine(model, **PACKED)
+    eng.scheduler.prefill_token_budget = 128
+    for i in range(4):
+        eng.add_request(prompt(37, 50 + i), 4)
+    with pytest.raises(PackedRowsError, match="64 live rows.*computes 40"):
+        eng.step()
+    assert issubclass(PackedRowsError, ValueError)
+    assert not PackedRowsError.retryable
+
+
+@pytest.mark.parametrize("budget", [32, 128], ids=["packed", "rectangle"])
+def test_a_nan_in_a_live_row_that_is_not_sampled_quarantines_its_slot(
+        model, budget):
+    """A NaN planted after the final norm in column 3 of slot 1's first
+    chunk (16 live rows; the program samples column 15, which stays
+    finite): that slot is quarantined as ``nonfinite``, slot 0 beside it
+    goes on."""
+    # the mixed program's rows, and slot 1's column 3 among them
+    rows, at = {32: ((40,), (16 + 3,)), 128: ((8, 16), (1, 3))}[budget]
+
+    def plant(layer, args, y):
+        return y.at[at].set(jnp.nan) if y.shape[:-1] == rows else None
+
+    hook = model.model.final_layernorm.register_forward_post_hook(plant)
+    try:
+        eng = engine(model, **dict(PACKED, prefill_token_budget=budget))
+        rids = [eng.add_request(prompt(37, 60 + i), 4) for i in range(2)]
+        events = eng.step()
+    finally:
+        hook.remove()
+    assert [e["rid"] for e in events if e["finish_reason"] == "nonfinite"] \
+        == [rids[1]]
+    assert eng.request(rids[0]).slot == 0
+    assert eng.request(rids[0]).context_len == 16
+
+
 def test_routed_experts_are_refused_by_name():
     with pytest.raises(JambaExpertsError, match="num_experts=16"):
         jamba_tiny(num_experts=16, num_experts_per_tok=2)

@@ -495,8 +495,19 @@ def test_the_selective_scan_kernel_compiles_at_the_cell_shapes(mosaic,
     assert not [s for s in _float_shapes(text) if len(s) == 4]
 
 
+def _dot_shapes(text: str) -> dict:
+    """Result shape -> count of the dots (``dot`` / ``convolution``) in a
+    compiled program's text."""
+    from collections import Counter
+    return Counter(
+        tuple(int(d) for d in m.split(","))
+        for m in re.findall(r"= \w+\[([\d,]+)\]\S* (?:dot|convolution)\(",
+                            text))
+
+
+@pytest.mark.parametrize("budget", [2048, 32], ids=["rectangle", "packed"])
 def test_the_engine_s_programs_for_a_jamba_hold_both_kernels(
-        mosaic, monkeypatch, one_chip):
+        mosaic, monkeypatch, one_chip, budget):
     """``ServingEngine``'s own two step programs for a toy of the Jamba
     family, lowered for the described chip from the engine's
     ``_warm_args``: the decode program calls ``paged_attention_decode``
@@ -505,19 +516,29 @@ def test_the_engine_s_programs_for_a_jamba_hold_both_kernels(
     kernel (its one-row recurrence is a fused XLA pass); the mixed
     program calls ``selective_scan_rows`` once a Mamba layer and holds
     no float array with both a channel and a state axis beside the rows;
-    both alias the donated pages and state."""
+    both alias the donated pages and state. At a prefill budget of 32
+    the mixed program packs its live rows into T = 6 + 32 = 38 -> 40 of
+    the 6 x 16 rectangle's 96: no dot of the MLP, of ``in_proj``,
+    ``out_proj`` or the head has the rectangle's rows, and the head
+    scores the 6 sampled rows alone (the widths are chosen apart:
+    intermediate 384, vocabulary 640)."""
     from paddle_tpu.models.jamba import JambaForCausalLM, jamba_tiny
     from paddle_tpu.nn.functional import attention, ssm
     from paddle_tpu.serving import ServingEngine
     monkeypatch.setattr(attention, "_flash_backend_ok", lambda: True)
     monkeypatch.setattr(ssm, "_kernel_backend_ok", lambda: True)
-    cfg = jamba_tiny(dtype="bfloat16")
+    cfg = jamba_tiny(dtype="bfloat16", intermediate_size=384,
+                     vocab_size=640)
     model = JambaForCausalLM(cfg)
     model.eval()
-    eng = ServingEngine(model, num_pages=64, page_size=16, max_slots=8,
-                        max_pages_per_slot=10, prefill_chunk=16)
+    S, K = 6, 16
+    eng = ServingEngine(model, num_pages=64, page_size=16, max_slots=S,
+                        max_pages_per_slot=10, prefill_chunk=K,
+                        prefill_token_budget=budget)
     kinds = [cfg.is_attention(i) for i in range(cfg.num_hidden_layers)]
     d, n = cfg.mamba_d_inner, cfg.mamba_d_state
+    T = eng._mixed_rows
+    assert T == (S * K if budget == 2048 else 40)
     for name, step in (("decode", eng._decode_step),
                        ("mixed", eng._mixed_step)):
         args = jax.tree_util.tree_map(
@@ -536,3 +557,17 @@ def test_the_engine_s_programs_for_a_jamba_hold_both_kernels(
         if name == "mixed":
             assert not [s for s in _float_shapes(text, "f32")
                         if len(s) == 4 and d in s and n in s]
+            dots = _dot_shapes(text)
+            widths = (cfg.intermediate_size, 2 * d, cfg.vocab_size)
+            on_rect = sum(dots[(S * K, w)] + dots[(S, K, w)]
+                          for w in widths)
+            if T == S * K:
+                assert on_rect == 2 * len(kinds) + kinds.count(False) + 1
+                continue
+            assert on_rect == 0
+            assert dots[(T, cfg.intermediate_size)] == 2 * len(kinds)
+            assert dots[(T, 2 * d)] == kinds.count(False)
+            # down_proj in every layer, out_proj in every Mamba layer
+            assert dots[(T, cfg.hidden_size)] == (
+                len(kinds) + kinds.count(False))
+            assert dots[(S, cfg.vocab_size)] == 1
