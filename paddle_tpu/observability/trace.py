@@ -34,7 +34,11 @@ enters it; ``paddle_tpu.profiler.RecordEvent`` goes through it too.
 (built without a tracer) and ``jit.TrainStep`` hold: it is enabled
 exactly while a profiler session is on (``jax.profiler.start_trace``,
 ``paddle_tpu.profiler.Profiler``, a capture from a live server) and
-costs one ``TraceAnnotation.is_enabled()`` call per hook otherwise. Its
+costs one ``TraceAnnotation.is_enabled()`` call per hook otherwise.
+It also holds the process's one ``gc.callbacks`` entry: while a session
+is on, every Python garbage collection becomes a ``gc`` span on the
+``host`` track (and a ``host.gc`` annotation in the profiler's trace),
+whose ``parent`` and ``step`` are those of the span it interrupted. Its
 clock is ``time.perf_counter``; its buffer is a ring (the oldest events
 fall off) and its counters run on from session to session, so a reader
 takes a window's events by their ``ts`` and a counter's growth over it
@@ -55,6 +59,7 @@ engine holds. Sinks (``add_sink``) observe every recorded event — the
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import os
 import threading
@@ -99,7 +104,7 @@ def profiler_annotation(name: str, **stats):
 
 
 def _annotation_name(name: str, track: str) -> str:
-    return ("train." if track == "train" else "serve.") + name
+    return (track + "." if track in ("train", "host") else "serve.") + name
 
 
 class _Span:
@@ -154,6 +159,8 @@ class Tracer:
         self.counters: dict[str, int] = {}
         self._sinks: list = []
         self._local = threading.local()
+        # the collection in progress: (start, annotation, parent, step)
+        self._gc_open = None
 
     @property
     def enabled(self) -> bool:
@@ -228,6 +235,37 @@ class Tracer:
         ev["n"] = n
         self._emit(ev)
 
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """A ``gc.callbacks`` entry: one ``gc`` span on the ``host``
+        track per collection. The span stack is only read (the
+        collection's ``parent`` and ``step`` are those of the span it
+        interrupted), never pushed: a collection can start inside any
+        frame, on any thread."""
+        if not self.enabled:
+            return
+        if phase == "start":
+            stack = self._stack()
+            top = stack[-1] if stack else None
+            ann = profiler_annotation(_annotation_name("gc", "host"),
+                                      generation=info["generation"])
+            ann.__enter__()
+            self._gc_open = (self.now(), ann,
+                             top.name if top is not None else None,
+                             top.step if top is not None else None)
+            return
+        opened, self._gc_open = self._gc_open, None
+        if opened is None:      # the session began during the collection
+            return
+        t0, ann, parent, step = opened
+        t1 = self.now()
+        ann.__exit__(None, None, None)
+        self._emit({"name": "gc", "ph": "X", "ts": t0, "dur": t1 - t0,
+                    "track": "host",
+                    "args": {"generation": info["generation"],
+                             "collected": info["collected"],
+                             "uncollectable": info["uncollectable"]},
+                    "parent": parent, "step": step})
+
     # ---- export ----
 
     def chrome_trace(self) -> dict:
@@ -281,3 +319,5 @@ NULL_TRACER = Tracer(enabled=False)
 # profiler session is on, and only then
 PROFILE_TRACER = Tracer(clock=time.perf_counter, follow_profiler=True,
                         capacity=PROFILE_RING_EVENTS)
+# every collection of the process, timed while a session is on
+gc.callbacks.append(PROFILE_TRACER._on_gc)

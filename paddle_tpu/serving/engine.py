@@ -75,6 +75,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..distributed import fault as _fault
+from ..distributed.moe import HeldExpertsMoE
 from ..observability.trace import PROFILE_TRACER
 from ..ops.pallas.paged_attention import latent_grid_steps
 from .errors import (AdmissionShedError, EngineDrainingError,
@@ -185,6 +186,11 @@ class ServingEngine:
         # either kind is handed a ``HybridCache`` by the step programs
         # and hands its own counters back with it
         self._hybrid = self._recurrent or self._latent
+        # ... whose counters are those of its expert layers: a model
+        # without one (Jamba) hands back zeros, which a traced step
+        # leaves unread
+        self._experts = self._hybrid and any(
+            isinstance(m, HeldExpertsMoE) for m in model.sublayers())
         if self._hybrid:
             int8_kv = kv_quant or (kv_dtype is not None
                                    and jnp.dtype(kv_dtype) == jnp.int8)
@@ -760,6 +766,8 @@ class ServingEngine:
                     break
                 req = batch[0]
                 first = False
+                if req.first_admit_step < 0:
+                    req.first_admit_step = self._steps
                 self.metrics.on_admit(req.rid)
                 self.metrics.on_prefill(req.cached_len, req.prefill_target,
                                         req.restored_len)
@@ -1804,6 +1812,7 @@ class ServingEngine:
         sp = req.sampling
         tok = 0
         ok_all = True
+        req.chunk_steps += 1    # the whole suffix runs in this one step
         with tr.span("prefill", track=req.rid, cached=cached,
                      suffix=n_sfx, chunks=-(-n_sfx // K)):
             start = cached
@@ -2011,7 +2020,7 @@ class ServingEngine:
         with tr.span("decode_dispatch", slots=len(self.scheduler.running)):
             nt, ok = self._call_step(self._decode_step, lanes)
         nt, ok = self._watched_sync(nt, ok)
-        if self._hybrid and tr.enabled:
+        if tr.enabled and (self._latent or self._experts):
             self._bump_hybrid_counters()
         with tr.span("sample_emit"):
             for slot, req in list(self.scheduler.running.items()):
@@ -2055,7 +2064,7 @@ class ServingEngine:
                      drafts=sum(n_drafted.values())):
             samp, acc, ok = self._call_step(self._mixed_step, lanes)
         samp, acc, ok = self._watched_sync(samp, acc, ok)
-        if self._hybrid and tr.enabled:
+        if tr.enabled and (self._latent or self._experts):
             self._bump_hybrid_counters()
         with tr.span("sample_emit"):
             self._mixed_emit(events, plan, n_drafted, samp, acc, ok)
@@ -2082,6 +2091,7 @@ class ServingEngine:
         chunk_tokens = 0
         for slot, req in sched.running.items():
             if req.prefilling and slot not in plan:
+                req.wait_steps += 1
                 continue  # out of budget this step: the slot sits out
             sp = req.sampling
             tables[slot, :len(req.pages)] = req.pages
@@ -2102,6 +2112,7 @@ class ServingEngine:
                 # on this request's next token index
                 counts[slot] = len(req.tokens) - (n - 1)
                 chunk_tokens += n
+                req.chunk_steps += 1
                 if tr.enabled:
                     tr.instant("chunk", track=req.rid,
                                start=int(req.context_len), n=n)
@@ -2279,22 +2290,20 @@ class ServingEngine:
         return out
 
     def _bump_hybrid_counters(self) -> None:
-        """A traced step of a model that takes a ``HybridCache``: the
-        slots and bytes of recurrent state it held, or the tokens and
-        bytes of latent rows its running requests hold, and what the
-        program itself counted in its expert layers (summed over them):
-        assignments routed (live rows x top_k), those that landed on a
-        held expert, and held experts with at least one row."""
+        """A traced step of a model with a latent cache or expert
+        layers: the tokens and bytes of latent rows its running requests
+        hold, and what the program itself counted in its expert layers
+        (summed over them): assignments routed (live rows x top_k), those
+        that landed on a held expert, and held experts with at least one
+        row."""
         tr, pool = self.tracer, self.pool
-        if self._recurrent:
-            live = len(pool.state_slots)
-            tr.bump("state_slots_live", live)
-            tr.bump("state_bytes_live", live * pool.state_bytes_per_slot)
         if self._latent:
             tokens = sum(r.context_len
                          for r in self.scheduler.running.values())
             tr.bump("latent_tokens_live", tokens)
             tr.bump("latent_bytes_live", tokens * pool.kv_bytes_per_token())
+        if not self._experts:
+            return
         routed, held, touched = (int(v) for v in
                                  np.asarray(self._step_counts))
         tr.bump("expert_rows_routed", routed)
@@ -2381,7 +2390,18 @@ class ServingEngine:
     def _emit(self, req: Request, token: int, events: list[dict]) -> None:
         req.tokens.append(token)
         self.metrics.on_token(req.rid)
-        self.tracer.bump("tokens")
+        tr = self.tracer
+        tr.bump("tokens")
+        if len(req.tokens) == 1 and tr.enabled:
+            # what the first token waited on (OBSERVABILITY.md "Request
+            # track"): steps since its first admission, this one
+            # included, of which ``chunks`` ran a chunk of its prompt
+            # and ``waits`` left it out of the plan; the rest were lost
+            # to preemptions
+            tr.instant("first_token", track=req.rid,
+                       steps=self._steps - req.first_admit_step + 1,
+                       chunks=req.chunk_steps, waits=req.wait_steps,
+                       preemptions=req.preemptions, cached=req.cached_len)
         reason = None
         if req.eos_token_id is not None and token == req.eos_token_id:
             reason = "stop"

@@ -86,6 +86,14 @@ class Request:
     tokens: list[int] = field(default_factory=list)   # generated so far
     finish_reason: str | None = None
     preemptions: int = 0
+    # what its first token waited on, in engine steps (the engine's
+    # ``first_token`` trace point reads them; kept whether or not a
+    # tracer is on): the step of its first admission, the steps in which
+    # a chunk of its prompt ran, and the steps in which it was
+    # prefilling and the plan gave it no chunk
+    first_admit_step: int = -1
+    chunk_steps: int = 0
+    wait_steps: int = 0
 
     # robustness (SERVING.md "Serving failure modes"): deadlines are
     # measured from arrival on the engine's injectable metrics clock and

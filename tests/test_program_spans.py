@@ -7,7 +7,9 @@ outside a session nothing is recorded. The step programs carry the
 names of their layer parts in every operation's ``op_name``.
 """
 
+import gc
 import glob
+import math
 import os
 import re
 
@@ -108,7 +110,8 @@ def untraced(model):
     eng, streams = _scripted_run(model)
     return {"streams": streams,
             "events": len(PROFILE_TRACER.events) - before,
-            "enabled": eng.stats()["tracing"]}
+            "enabled": eng.stats()["tracing"],
+            "programs": eng.step_program_counts()}
 
 
 @pytest.fixture(scope="module")
@@ -118,7 +121,42 @@ def traced(model, untraced, tmp_path_factory):
         eng, streams = _scripted_run(model)
     return {"streams": streams, "events": ses.events,
             "counters": ses.counters,
-            "host_names": ses.host_event_names(), "steps": eng._steps}
+            "host_names": ses.host_event_names(), "steps": eng._steps,
+            "programs": eng.step_program_counts()}
+
+
+@pytest.fixture(scope="module")
+def traced_waits(model, tmp_path_factory):
+    """Two runs in one session whose first tokens wait. A budget of one
+    chunk and two prompts of two chunks each: the younger prompt sits
+    out both steps of the older. A pool of six pages: the younger
+    prompt, left out of step 0, is preempted in step 1 when the older
+    request's decode needs a page, and waits in the queue until the
+    older one finishes."""
+    rng = np.random.default_rng(9)
+    with _session(tmp_path_factory.mktemp("serve_trace_waits")) as ses:
+        tight = ServingEngine(model, num_pages=64, page_size=PAGE,
+                              max_slots=SLOTS,
+                              max_pages_per_slot=PAGES_PER_SLOT,
+                              prefill_chunk=CHUNK,
+                              prefill_token_budget=CHUNK)
+        rids = [tight.add_request(list(rng.integers(0, 512, 2 * CHUNK)),
+                                  MAX_NEW) for _ in range(2)]
+        tight.run_to_completion(max_steps=200)
+        small = ServingEngine(model, num_pages=7, page_size=PAGE,
+                              max_slots=SLOTS,
+                              max_pages_per_slot=PAGES_PER_SLOT,
+                              prefill_chunk=CHUNK,
+                              prefill_token_budget=CHUNK)
+        old = small.add_request(list(rng.integers(0, 512, CHUNK)), 8,
+                                rid="old")
+        young = small.add_request(list(rng.integers(0, 512, 2 * CHUNK)), 4,
+                                  rid="young")
+        small.run_to_completion(max_steps=200)
+    points = {e["track"]: e for e in _first_tokens(ses.events)}
+    return {"tight": [points[r] for r in rids],
+            "old": points[old], "young": points[young],
+            "preemptions": small.scheduler.num_preemptions}
 
 
 @pytest.fixture(scope="module")
@@ -166,6 +204,10 @@ def _spans(events, name, track=None):
             and (track is None or e["track"] == track)]
 
 
+def _first_tokens(events):
+    return [e for e in events if e["ph"] == "i" and e["name"] == "first_token"]
+
+
 # ---------------------------------------------------------------------------
 # off outside a session
 # ---------------------------------------------------------------------------
@@ -185,6 +227,9 @@ def test_outside_a_session_nothing_is_recorded(untraced):
 def test_token_streams_are_bitwise_the_same_in_a_session(untraced, traced):
     assert traced["streams"] == untraced["streams"]
     assert all(len(t) == MAX_NEW for t in traced["streams"].values())
+    # and the session compiled nothing: one program of each shape
+    assert traced["programs"] == untraced["programs"] == {"decode": 1,
+                                                          "mixed": 1}
 
 
 def test_the_buffer_is_a_ring_and_the_counters_run_on(traced, tmp_path):
@@ -197,7 +242,9 @@ def test_the_buffer_is_a_ring_and_the_counters_run_on(traced, tmp_path):
     assert not PROFILE_TRACER.enabled
     assert PROFILE_TRACER.counters["tokens"] == tokens + 3
     # a window's share of a counter: the increments of its events
-    assert ses.counters == {"tokens": 3} and len(ses.events) == 1
+    # (a collection the session's own set-up set off is on the host track)
+    assert ses.counters == {"tokens": 3}
+    assert len([e for e in ses.events if e["track"] != "host"]) == 1
     from paddle_tpu.observability import Tracer
     ring = Tracer(clock=lambda: 0.0, capacity=4)
     for i in range(10):
@@ -336,6 +383,68 @@ def test_block_table_entries_dispatched_and_live(traced):
     n_rows = sum(s["args"]["slots"] for s in decode)
     assert 2 * n_rows <= c["table_entries_live"] <= 5 * n_rows
     assert c["table_entries_live"] < c["table_entries_dispatched"]
+
+
+# ---------------------------------------------------------------------------
+# what a first token waited on, and the host's collections
+# ---------------------------------------------------------------------------
+
+def test_one_first_token_per_request_with_the_steps_it_took(untraced, traced):
+    """Once per request, at the step that emits it, and never outside a
+    session. No budget or preemption holds these prompts back: every
+    step from admission to first token ran a chunk of the prompt."""
+    assert untraced["events"] == 0
+    ev = traced["events"]
+    points = _first_tokens(ev)
+    assert sorted(p["track"] for p in points) == sorted(traced["streams"])
+    for p in points:
+        rid, a = p["track"], p["args"]
+        prompt = next(e["args"]["prompt"] for e in ev if e["track"] == rid
+                      and e["name"] == "queued" and e["ph"] == "B")
+        admit = next(e for e in ev if e["track"] == rid
+                     and e["name"] == "admit")
+        assert a["chunks"] == math.ceil((prompt - a["cached"]) / CHUNK)
+        assert (a["waits"], a["preemptions"]) == (0, 0)
+        assert a["steps"] == a["chunks"] + a["waits"]
+        assert a["steps"] == p["step"] - admit["step"] + 1
+        assert p["parent"] == "sample_emit"
+
+
+def test_a_budget_of_one_chunk_makes_the_younger_prompt_wait(traced_waits):
+    older, younger = (p["args"] for p in traced_waits["tight"])
+    assert (older["steps"], older["chunks"], older["waits"]) == (2, 2, 0)
+    assert (younger["steps"], younger["chunks"], younger["waits"]) == (4, 2, 2)
+    assert younger["preemptions"] == 0
+
+
+def test_a_preempted_prompt_counts_its_preemption(traced_waits):
+    old, young = traced_waits["old"]["args"], traced_waits["young"]["args"]
+    assert traced_waits["preemptions"] == 1
+    assert (old["steps"], old["chunks"], old["waits"]) == (1, 1, 0)
+    assert (young["chunks"], young["waits"], young["preemptions"]) == (2, 1, 1)
+    # the step it was preempted in and those it spent queued again
+    # ran no chunk and were no wait of a prefilling slot
+    assert young["steps"] > young["chunks"] + young["waits"] + 1
+
+
+def test_a_collection_in_a_session_is_a_gc_span_of_the_open_phase(tmp_path):
+    t0 = PROFILE_TRACER.now()
+    gc.collect()                                 # outside a session
+    assert not [e for e in PROFILE_TRACER.events if e["ts"] >= t0]
+    with _session(tmp_path) as ses:
+        with PROFILE_TRACER.span("bookkeeping", step=7):
+            gc.collect()
+    forced = [e for e in _spans(ses.events, "gc", "host")
+              if e["args"]["generation"] == 2]
+    assert len(forced) == 1, forced
+    (f,) = forced
+    assert (f["parent"], f["step"]) == ("bookkeeping", 7) and f["dur"] > 0
+    assert set(f["args"]) == {"generation", "collected", "uncollectable"}
+    assert "host.gc" in ses.host_event_names()
+    # the phase was neither pushed over nor closed early by the callback
+    (phase,) = _spans(ses.events, "bookkeeping")
+    assert phase["ts"] <= f["ts"]
+    assert f["ts"] + f["dur"] <= phase["ts"] + phase["dur"]
 
 
 # ---------------------------------------------------------------------------
