@@ -23,11 +23,15 @@ class LLMPredictor:
     the longest prompt), requests here share the paged pool: no padding
     waste, arrivals can be staggered, and greedy outputs are bitwise
     identical to per-request ``model.generate`` (SERVING.md).
+
+    ``prefill_token_budget`` (the prompt tokens one step may prefill) is
+    handed to the engine as given; ``None`` lets the engine derive it
+    from the model and ``max_slots`` (SERVING.md "Budget metering").
     """
 
     def __init__(self, model, num_pages: int = 128, page_size: int = 16,
                  max_slots: int = 8, max_pages_per_slot: int | None = None,
-                 prefill_token_budget: int = 2048, kv_dtype=None,
+                 prefill_token_budget: int | None = None, kv_dtype=None,
                  clock=None, max_queue_depth: int | None = None,
                  max_preemptions: int | None = None,
                  step_timeout_s: float | None = None,

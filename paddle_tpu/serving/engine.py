@@ -146,7 +146,7 @@ class BrownoutConfig:
 class ServingEngine:
     def __init__(self, model, num_pages: int, page_size: int,
                  max_slots: int = 4, max_pages_per_slot: int | None = None,
-                 prefill_token_budget: int = 2048, kv_dtype=None,
+                 prefill_token_budget: int | None = None, kv_dtype=None,
                  clock=None, max_queue_depth: int | None = None,
                  max_preemptions: int | None = None,
                  step_timeout_s: float | None = None,
@@ -265,7 +265,8 @@ class ServingEngine:
         # tenant_max_live / tenant_max_queued_tokens are per-tenant
         # admission quotas; shed_infeasible arms the deadline-
         # infeasibility gate; brownout takes a BrownoutConfig (or True
-        # for defaults) to arm the staged-degradation ladder.
+        # for defaults) to arm the staged-degradation ladder. A budget
+        # of None is derived below, once the chunk is known.
         self.scheduler = Scheduler(
             max_slots, prefill_token_budget,
             max_queue_depth=max_queue_depth,
@@ -341,6 +342,18 @@ class ServingEngine:
             self._chunk = -(-self._chunk // self._pp_waves) * self._pp_waves
         self.scheduler.chunked = self.chunked
         self.scheduler.pp_waves = self._pp_waves
+        # the per-step prefill token budget where none is given
+        # (SERVING.md "Budget metering"): a model whose mixed program
+        # packs its live rows (its config's ``packed_rows``) computes
+        # ``max_slots x spec_k + budget`` rows a dispatch
+        # (``_packed_width``), so it gets one chunk lane for every 16
+        # slots: the chunk tokens a step carries grow with the slots
+        # that turn requests over. Every other model computes the
+        # ``[max_slots, chunk]`` rectangle whatever the budget: 2048.
+        self._packs = self._hybrid and getattr(cfg, "packed_rows", False)
+        if prefill_token_budget is None:
+            self.scheduler.prefill_token_budget = (
+                self._chunk * -(-max_slots // 16) if self._packs else 2048)
         # crash-consistent snapshots (serving/snapshot.py; RESILIENCE.md
         # "Serving recovery playbook"): with a SnapshotStore attached,
         # every snapshot_interval steps the engine captures each live
@@ -1713,8 +1726,7 @@ class ServingEngine:
         # per-step token bound is narrower (SERVING.md "Packed rows")
         S, K = self.max_slots, self._chunk
         T = _packed_width(S, K, R, self.scheduler.prefill_token_budget)
-        if not (self._hybrid and T < S * K
-                and getattr(model.config, "packed_rows", False)):
+        if not (self._packs and T < S * K):
             T = None
         self._mixed_rows = S * K if T is None else T
 
@@ -2088,10 +2100,11 @@ class ServingEngine:
         seeds = np.zeros((S,), np.int32)
         counts = np.zeros((S,), np.int32)
         n_drafted: dict[int, int] = {}
-        chunk_tokens = 0
+        chunk_tokens = waits = 0
         for slot, req in sched.running.items():
             if req.prefilling and slot not in plan:
                 req.wait_steps += 1
+                waits += 1
                 continue  # out of budget this step: the slot sits out
             sp = req.sampling
             tables[slot, :len(req.pages)] = req.pages
@@ -2138,6 +2151,9 @@ class ServingEngine:
             # the rows they compute (the rectangle, or T packed)
             tr.bump("mixed_rows_live", live_rows)
             tr.bump("mixed_rows_computed", self._mixed_rows)
+            # prefilling slots the budget left out, bumped on every
+            # dispatch (0 included), so that none is told from no counter
+            tr.bump("chunk_waits", waits)
         if tr.enabled and self._latent:
             # the attention core's padding, counted where the work is
             # handed over: the grid steps a layer that the rows kernel

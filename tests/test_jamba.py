@@ -463,6 +463,61 @@ def test_a_budget_raised_after_construction_trips_the_guard(model):
     assert not PackedRowsError.retryable
 
 
+@pytest.mark.parametrize("slots,budget,rows", [(4, 16, 24), (17, 32, 56),
+                                               (40, 48, 88)])
+def test_an_engine_without_a_budget_derives_one_chunk_lane_a_16_slots(
+        model, slots, budget, rows):
+    """Built with no ``prefill_token_budget``, a packing engine meters
+    one chunk of 16 for every 16 slots, and its mixed program computes
+    T = slots x spec_k + that budget rows, rounded up to 8: far below the
+    ``slots x 16`` rectangle."""
+    eng = engine(model, max_slots=slots)
+    assert eng.scheduler.prefill_token_budget == budget
+    assert eng._mixed_rows == rows == -(-(slots + budget) // 8) * 8
+    assert rows < slots * 16
+
+
+@pytest.mark.parametrize("budget,rows", [(1, 16), (32, 40), (100, 112),
+                                         (2048, 128)])
+def test_an_explicit_budget_keeps_the_width_it_always_gave(model, budget,
+                                                           rows):
+    """``prefill_token_budget=`` is taken as given: T = min(8 x 16,
+    max(16, 8 + budget)) rounded up to 8, the rectangle from 120 on."""
+    eng = engine(model, **dict(PACKED, prefill_token_budget=budget))
+    assert eng.scheduler.prefill_token_budget == budget
+    assert eng._mixed_rows == rows
+
+
+def test_a_burst_beyond_the_derived_lane_waits_and_serves_the_reference(
+        cfg, model):
+    """Six prompts on 4 slots under the derived budget of one chunk of
+    16 a step: prompts behind the oldest sit out, each such step bumps
+    ``chunk_waits`` and shows in the request's ``first_token`` point as
+    a wait, and every stream is still the reference's, from a T of 24
+    rows that no dispatch overflows."""
+    from paddle_tpu.observability.trace import Tracer
+    tr = Tracer()
+    eng = engine(model, tracer=tr)
+    reqs = [(prompt(37 - 4 * i, 70 + i), 4 + i) for i in range(6)]
+    rids = [eng.add_request(p, n) for p, n in reqs]
+    out = eng.run_to_completion()
+    assert eng._mixed_rows == 24
+    waits = [eng.request(r).wait_steps for r in rids]
+    assert tr.counters["chunk_waits"] == sum(waits) > 0
+    points = {e["track"]: e["args"] for e in tr.events
+              if e["name"] == "first_token"}
+    assert [points[r]["waits"] for r in rids] == waits
+    assert max(waits) > 0 and waits[0] == 0
+    for a in points.values():
+        assert a["steps"] == a["chunks"] + a["waits"]
+    served = [out[r] for r in rids]
+    assert [len(t) for t in served] == [n for _, n in reqs]
+    gaps, _ = ref.serve_gaps(SEED, cfg, [(p, o) for (p, _), o in
+                                         zip(reqs, served)])
+    assert max(float(g.max()) for g in gaps) < TOL
+    assert eng.step_program_counts() == {"decode": 1, "mixed": 1}
+
+
 @pytest.mark.parametrize("budget", [32, 128], ids=["packed", "rectangle"])
 def test_a_nan_in_a_live_row_that_is_not_sampled_quarantines_its_slot(
         model, budget):

@@ -1,9 +1,9 @@
 """``mixed_rows_live_share`` (``benchmarks/layer_metrics``): the reader
 of the program's counters ``mixed_rows_live`` / ``mixed_rows_computed``,
 in a traced rehearsal of the Jamba cell at a tiny size on the CPU (the
-tiny engine's 4 slots x 16 rows under the default budget compute the
-rectangle), and silent where the program has no such counter, as the
-parent of PR 38 has not."""
+tiny engine's 4 slots x 16 rows under the budget it derives, one chunk
+of 16, pack into T = 4 + 16 -> 24 rows), and silent where the program
+has no such counter (an engine from before the counters)."""
 
 import importlib.util
 import json
@@ -58,7 +58,8 @@ def test_a_traced_rehearsal_reads_the_share_of_the_rectangle(tmp_path):
     assert r["correct"]
     m = r["metrics"]
     share = m["mixed_rows_live_share"]["value"]
-    # 4 slots x 16 rows a mixed dispatch, a few of them live: the rows
-    # the scan walks are the same rows
+    # 24 packed rows a mixed dispatch, some of them live: the same live
+    # rows the scan walks, over T and not over the 4 x 16 rectangle
     assert 0 < share < 100
-    assert share == pytest.approx(m["scan_rows_live_share"]["value"])
+    assert share == pytest.approx(
+        m["scan_rows_live_share"]["value"] * 64 / 24)

@@ -571,3 +571,45 @@ def test_the_engine_s_programs_for_a_jamba_hold_both_kernels(
             assert dots[(T, cfg.hidden_size)] == (
                 len(kinds) + kinds.count(False))
             assert dots[(S, cfg.vocab_size)] == 1
+
+
+@pytest.mark.parametrize("family", ["llama", "nemotron_h", "pangu_moe"])
+def test_an_engine_that_does_not_pack_keeps_its_budget_and_programs(
+        mosaic, monkeypatch, one_chip, family):
+    """A model whose config declares no ``packed_rows`` keeps the budget
+    of 2048 when the engine is built without one, at 32 slots of 16
+    rows, where a packing model would derive 32 and compute 64 of 512
+    rows: the mixed program computes the rectangle, and both step
+    programs lower for the described chip to the text of an engine given
+    ``prefill_token_budget=2048``, the default before budgets were
+    derived."""
+    from paddle_tpu.models import llama, nemotron_h, pangu_moe
+    from paddle_tpu.nn.functional import attention, ssm
+    from paddle_tpu.serving import ServingEngine
+    monkeypatch.setattr(attention, "_flash_backend_ok", lambda: True)
+    monkeypatch.setattr(ssm, "_kernel_backend_ok", lambda: True)
+    build = {"llama": lambda: llama.LlamaForCausalLM(
+                 llama.llama_tiny(dtype="bfloat16")),
+             "nemotron_h": lambda: nemotron_h.NemotronHForCausalLM(
+                 nemotron_h.nemotron_h_tiny(dtype="bfloat16")),
+             "pangu_moe": lambda: pangu_moe.PanguMoEForCausalLM(
+                 pangu_moe.pangu_moe_tiny(dtype="bfloat16"))}[family]
+    model = build()
+    model.eval()
+    assert not getattr(model.config, "packed_rows", False)
+    S, K = 32, 16
+    texts = []
+    for budget in ({}, {"prefill_token_budget": 2048}):
+        eng = ServingEngine(model, num_pages=64, page_size=16, max_slots=S,
+                            max_pages_per_slot=10, prefill_chunk=K,
+                            **budget)
+        assert eng.scheduler.prefill_token_budget == 2048
+        assert eng._mixed_rows == S * K
+        texts.append([
+            step.lower(*jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip),
+                eng._warm_args(name))).as_text()
+            for name, step in (("decode", eng._decode_step),
+                               ("mixed", eng._mixed_step))])
+    assert texts[0] == texts[1]
